@@ -36,7 +36,7 @@ CHILD = textwrap.dedent("""
     import time
     import jax, jax.numpy as jnp
     from repro.config import OptimizerConfig, PrismConfig
-    from repro.launch.mesh import compat_make_mesh
+    from jax.sharding import AxisType
     from repro.optim import bucketing
     from repro.sharding_ctx import activation_sharding
 
@@ -62,7 +62,8 @@ CHILD = textwrap.dedent("""
 
     rep_c, rep_t = bench(lambda vs: bucketing.polar_bucketed(vs, cfg,
                                                              key))
-    mesh = compat_make_mesh((4, 2), ("data", "model"))
+    mesh = jax.make_mesh((4, 2), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     with mesh, activation_sharding(
             mesh, {"opt_layers": "model", "opt_rows": "data"}):
         sh_c, sh_t = bench(lambda vs: bucketing.polar_bucketed(vs, cfg,

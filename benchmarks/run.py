@@ -37,6 +37,7 @@ def main() -> None:
 
     print("name,us_per_call,derived")
     t0 = time.time()
+    failed = []
     for mod in [fig1_sigma_sweep, fig3_gaussian, fig4_htmp, figd3_sqrt,
                 figd5_newton, fig5_shampoo, fig6_muon_lm, roofline_table,
                 bench_batched_matfn, bench_sharded_precond,
@@ -49,7 +50,10 @@ def main() -> None:
         except Exception as e:  # noqa: BLE001
             print(f"{name},0.0,status=ERROR;err={type(e).__name__}:{e}",
                   flush=True)
+            failed.append(name)
     print(f"# total {time.time() - t0:.1f}s")
+    if failed:
+        sys.exit(f"benchmark modules failed: {', '.join(failed)}")
 
     if args.smoke:
         # smoke also gates the COMMITTED baselines on their schema, so a

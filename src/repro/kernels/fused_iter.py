@@ -62,6 +62,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import mxu
+
 _SUBLANE = 16   # covers the bf16 (16, 128) min tile; fp32 needs only 8
 _LANE = 128
 
@@ -83,11 +85,14 @@ def _residual32(x, y, family: str):
     fp32 MXU accumulator; callers round once to the compute dtype."""
     if family == "polar":
         g = jax.lax.dot_general(x, x, (((0,), (0,)), ((), ())),
+                                precision=mxu.precision(x),
                                 preferred_element_type=jnp.float32)
     elif family == "sign":
-        g = jnp.dot(x, x, preferred_element_type=jnp.float32)
+        g = jnp.dot(x, x, precision=mxu.precision(x),
+                    preferred_element_type=jnp.float32)
     else:  # coupled sqrt: R = I - Y X, re-symmetrized for stability
-        g = jnp.dot(y, x, preferred_element_type=jnp.float32)
+        g = jnp.dot(y, x, precision=mxu.precision(y, x),
+                    preferred_element_type=jnp.float32)
     r32 = _eye(g.shape[0]) - g
     if family == "sqrt":
         r32 = 0.5 * (r32 + r32.T)
@@ -101,9 +106,12 @@ def _horner32(x, x32, r, alpha32, coeffs, side: str):
     acc = alpha32 * x32
     for j in range(len(coeffs) - 1, -1, -1):
         lo = acc.astype(x.dtype)
-        prod = (jnp.dot(lo, r, preferred_element_type=jnp.float32)
+        prec = mxu.precision(lo, r)
+        prod = (jnp.dot(lo, r, precision=prec,
+                        preferred_element_type=jnp.float32)
                 if side == "right"
-                else jnp.dot(r, lo, preferred_element_type=jnp.float32))
+                else jnp.dot(r, lo, precision=prec,
+                             preferred_element_type=jnp.float32))
         acc = prod + coeffs[j] * x32
     return acc
 
@@ -128,7 +136,8 @@ def _res_chain_kernel(*refs, family, max_power, coupled):
     st32 = st.astype(jnp.float32)
     v = st
     for i in range(max_power):
-        vacc = jnp.dot(r, v, preferred_element_type=jnp.float32)
+        vacc = jnp.dot(r, v, precision=mxu.precision(r, v),
+                       preferred_element_type=jnp.float32)
         t_ref[b, i] = jnp.sum(st32 * vacc)
         v = vacc.astype(st.dtype)
 

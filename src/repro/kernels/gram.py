@@ -29,6 +29,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import mxu
+
 
 def _unrank_upper(t, nb: int):
     """Row-major upper-triangle unranking: t -> (i, j), i <= j < nb.
@@ -60,7 +62,8 @@ def _kernel(x1_ref, x2_ref, out_ref, acc_ref, *, alpha, beta, n_k, bn, nb):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += jnp.dot(x1_ref[0].T, x2_ref[0],
+    x1, x2 = x1_ref[0], x2_ref[0]
+    acc_ref[...] += jnp.dot(x1.T, x2, precision=mxu.precision(x1, x2),
                             preferred_element_type=jnp.float32)
 
     @pl.when(k == n_k - 1)

@@ -31,6 +31,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import mxu
+
 
 def _kernel(a_ref, b_ref, c_ref, d_ref, acc_ref, *, alpha, beta, n_k):
     k = pl.program_id(3)
@@ -39,7 +41,8 @@ def _kernel(a_ref, b_ref, c_ref, d_ref, acc_ref, *, alpha, beta, n_k):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += jnp.dot(a_ref[0], b_ref[0],
+    a, b = a_ref[0], b_ref[0]
+    acc_ref[...] += jnp.dot(a, b, precision=mxu.precision(a, b),
                             preferred_element_type=jnp.float32)
 
     @pl.when(k == n_k - 1)

@@ -1,8 +1,9 @@
 """jit'd public wrappers around the Pallas kernels.
 
 Dispatch policy:
-  * on TPU backends the compiled Mosaic kernels run natively;
-  * on CPU (this container) ``interpret=True`` executes the kernel bodies
+  * on TPU backends the compiled Mosaic kernels run natively, always: a
+    ``REPRO_KERNEL_MODE`` other than auto/native raises there;
+  * on CPU ``interpret=True`` executes the kernel bodies
     in Python for correctness validation, or — when ``REPRO_KERNEL_MODE=ref``
     or the shapes are large — the pure-jnp oracle in ref.py is used so CPU
     benchmarks aren't dominated by the interpreter.  "Large" is a per-call
@@ -160,8 +161,15 @@ def _interpret_cutoff() -> int:
 
 def _mode(*operands) -> str:
     env = os.environ.get("REPRO_KERNEL_MODE", "auto")
-    mode = env if env != "auto" else \
-        ("native" if jax.default_backend() == "tpu" else "ref")
+    if jax.default_backend() == "tpu":
+        # never run the oracle or the interpreter on the chip: a stray
+        # environment variable must not turn the kernel path into jnp
+        if env not in ("auto", "native"):
+            raise RuntimeError(
+                f"REPRO_KERNEL_MODE={env!r} on a TPU backend: the Pallas "
+                f"kernels run natively there; unset it or use 'native'")
+        return "native"
+    mode = "ref" if env == "auto" else env
     if mode == "interpret":
         cutoff = _interpret_cutoff()
         if cutoff > 0 and any(a is not None and a.size > cutoff

@@ -37,6 +37,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import mxu
+
 
 def _kernel(r_ref, v_ref, st_ref, vout_ref, t_ref, acc_ref, *, n_k):
     b, i, k = pl.program_id(0), pl.program_id(1), pl.program_id(2)
@@ -49,7 +51,8 @@ def _kernel(r_ref, v_ref, st_ref, vout_ref, t_ref, acc_ref, *, n_k):
     def _init_acc():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += jnp.dot(r_ref[0], v_ref[0],
+    r, v = r_ref[0], v_ref[0]
+    acc_ref[...] += jnp.dot(r, v, precision=mxu.precision(r, v),
                             preferred_element_type=jnp.float32)
 
     @pl.when(k == n_k - 1)
@@ -138,7 +141,8 @@ def _chain_kernel(r_ref, st_ref, t_ref, v0_ref, v1_ref, acc_ref,
     v_prev = jnp.where((pw % 2) == 1, v0_ref[pl.ds(ks, bn), :],
                        v1_ref[pl.ds(ks, bn), :])
     v_in = jnp.where(pw == 0, st_k, v_prev)
-    acc_ref[...] += jnp.dot(r_ref[0], v_in,
+    r = r_ref[0]
+    acc_ref[...] += jnp.dot(r, v_in, precision=mxu.precision(r, v_in),
                             preferred_element_type=jnp.float32)
 
     last = k == n_k - 1

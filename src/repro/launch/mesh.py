@@ -1,37 +1,32 @@
-"""Production mesh construction (single-pod 16x16, multi-pod 2x16x16).
+"""Mesh construction: the devices present, plus the fixed production and
+debug shapes.
 
-``make_production_mesh`` is a FUNCTION (never module-level state) so that
-importing this module does not touch jax device state — required because
-the dry-run process must set XLA_FLAGS before any jax initialization.
+Every builder is a FUNCTION (never module-level state) so that importing
+this module does not touch jax device state — required because the
+dry-run process must set XLA_FLAGS before any jax initialization.
 """
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
-def compat_make_mesh(shape, axes):
-    """jax.make_mesh across jax versions: axis_types/AxisType only exist
-    on newer releases (this container ships 0.4.37, where every mesh axis
-    is implicitly Auto — the semantics the newer call spells out)."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+def device_mesh_shape(n_devices: int) -> tuple:
+    """(data, model) for ``n_devices`` chips: the model axis is the largest
+    divisor d of n with d * d <= n (1 chip -> (1, 1), a v5e 2x2 host ->
+    (2, 2), 8 devices -> (4, 2))."""
+    model = max(d for d in range(1, n_devices + 1)
+                if n_devices % d == 0 and d * d <= n_devices)
+    return (n_devices // model, model)
 
 
-def compat_abstract_mesh(shape, axes):
-    """jax.sharding.AbstractMesh across jax versions: 0.4.x takes a
-    ((name, size), ...) tuple, newer releases take (shape, names[,
-    axis_types]).  Metadata-only — for sharding-rule tests that need the
-    production mesh shape without 256 devices."""
-    from jax.sharding import AbstractMesh
-
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return AbstractMesh(shape, axes,
-                            axis_types=(axis_type.Auto,) * len(axes))
-    return AbstractMesh(tuple(zip(axes, shape)))
+def make_device_mesh(devices=None):
+    """(data, model) mesh over the devices that are present (default
+    ``jax.devices()``) — the mesh ``launch/train.py --mesh devices`` runs
+    the sharded engine on."""
+    devices = list(jax.devices() if devices is None else devices)
+    return jax.make_mesh(device_mesh_shape(len(devices)), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2, devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -39,7 +34,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     variant adds a leading pure-DP "pod" axis (2 pods = 512 chips)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat_make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_debug_mesh(data: int = 2, model: int = 2, *,
@@ -48,4 +43,4 @@ def make_debug_mesh(data: int = 2, model: int = 2, *,
     caller via XLA_FLAGS before jax init)."""
     shape = (pods, data, model) if multi_pod else (data, model)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat_make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))

@@ -40,7 +40,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.sharding_ctx import compat_shard_map, suspend_activation_sharding
+from repro.sharding_ctx import suspend_activation_sharding
 
 
 def n_ticks_1f1b(n_stages: int, n_micro: int) -> int:
@@ -122,11 +122,12 @@ def pipeline_apply(mesh, stage_fn: Callable, stage_params, x,
     x_micro = x.reshape((n_micro, mb) + x.shape[1:])
     run = gpipe_forward(stage_fn, axis, n_stages, n_micro)
 
-    mapped = compat_shard_map(
+    mapped = jax.shard_map(
         run, mesh=mesh,
         in_specs=(P(axis), P()),
         out_specs=P(axis),  # per-stage outputs stacked; last stage valid
-        axis_names={axis})  # other mesh axes stay "auto"
+        axis_names={axis},  # other mesh axes stay "auto"
+        check_vma=False)
     outs = mapped(stage_params, x_micro)
     # outs [n_stages * n_micro, mb, ...]: only the last stage's block is
     # the real output (earlier stages contributed zeros)
@@ -340,10 +341,10 @@ def pipeline_grads(mesh, stage_fn: Callable, shared, lay_stacked,
             act)
     run = one_f_one_b(stage_fn, axis, n_stages, n_micro, act,
                       use_ppermute=True, dp_axes=dp_axes, dp_size=dp_size)
-    mapped = compat_shard_map(
+    mapped = jax.shard_map(
         run, mesh=mesh,
         in_specs=(P(), P(axis), inp_spec, P(axis)),
         out_specs=(P(), P(), P(axis)),
-        axis_names=None)
+        check_vma=False)
     return mapped(shared, lay_stacked, inp_micro,
                   jnp.arange(n_stages, dtype=jnp.int32))

@@ -31,6 +31,7 @@ import jax.numpy as jnp
 
 from repro.checkpoint import restore_params
 from repro.configs import get_config, get_smoke_config
+from repro.launch import compile_cache
 from repro.launch import sharding as sh
 from repro.launch.mesh import make_production_mesh
 from repro.models import build
@@ -62,6 +63,7 @@ def main():
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
+    compile_cache.enable()
     cfg = (get_smoke_config if args.smoke else get_config)(args.arch)
     model = build(cfg)
     key = jax.random.PRNGKey(0)

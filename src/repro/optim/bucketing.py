@@ -346,11 +346,13 @@ def shard_over_batch(fn: Callable, mesh, axes: Tuple[str, ...],
 
     out_specs = (P(*((None,) * stacked.ndim)) if out_ranks is None else
                  tuple(P(*((None,) * r)) for r in out_ranks))
-    out = sharding_ctx.compat_shard_map(
+    # replication checking off: the all-gathered chain outputs are
+    # replicated, which the checker cannot always infer
+    out = jax.shard_map(
         local, mesh=mesh,
         in_specs=tuple(batch_spec(a.ndim)
                        for a in [stacked, *slice_args]),
-        out_specs=out_specs)(stacked, *slice_args)
+        out_specs=out_specs, check_vma=False)(stacked, *slice_args)
     return jax.tree.map(lambda o: o[:B], out) if pad else out
 
 

@@ -72,6 +72,21 @@ def _flatten_with_axes(params, axes_tree):
     return flat_p, flat_a, treedef
 
 
+def _view_shape(shape, axes) -> tuple:
+    """Shape of a leaf's matrix view, computed abstractly."""
+    return jax.eval_shape(lambda x: base.to_matrix_view(x, axes)[0],
+                          jax.ShapeDtypeStruct(shape, jnp.float32)).shape
+
+
+def matrix_view_shapes(param_shapes, axes_tree,
+                       allow_embed: bool = False) -> list:
+    """[.., m, n] shapes of the momentum views Muon orthogonalizes — the
+    shapes the bucketing planner groups (optim/bucketing.py)."""
+    flat_p, flat_a, _ = _flatten_with_axes(param_shapes, axes_tree)
+    return [_view_shape(p.shape, a) for p, a in zip(flat_p, flat_a)
+            if base.is_matrix_param(a, p.shape, allow_embed)]
+
+
 def make_muon(cfg: OptimizerConfig, axes_tree) -> base.Optimizer:
     # §11 telemetry: with an adaptive tol the realized per-matrix
     # iteration counts ("iters", the view's lead shape, int32) ride in
@@ -98,9 +113,7 @@ def make_muon(cfg: OptimizerConfig, axes_tree) -> base.Optimizer:
                 # entries: eval_shape runs the view reshape abstractly,
                 # so init of an embedding-bearing tree never
                 # materializes a throwaway full-size zeros view
-                vshape = jax.eval_shape(
-                    lambda x, _a=a: base.to_matrix_view(x, _a)[0],
-                    jax.ShapeDtypeStruct(p.shape, jnp.float32)).shape
+                vshape = _view_shape(p.shape, a)
                 if telemetry:
                     s["iters"] = jnp.zeros(vshape[:-2], jnp.int32)
                     s["status"] = jnp.zeros(vshape[:-2], jnp.int8)

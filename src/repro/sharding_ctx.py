@@ -21,35 +21,6 @@ import jax
 _CTX: list = []
 
 
-def compat_shard_map(f, mesh, in_specs, out_specs, axis_names=None):
-    """shard_map across jax versions: jax.shard_map (new; check_vma,
-    axis_names = mapped axes) vs jax.experimental.shard_map (0.4.x;
-    check_rep, auto = UNmapped axes).  Replication checking is disabled
-    either way: the bodies we map (matrix-function chains, int8 psum)
-    return all-gathered results whose replication the checker cannot
-    always infer.  ``axis_names`` restricts manual mode to those mesh
-    axes (None = all)."""
-    sm = getattr(jax, "shard_map", None)
-    kw: dict = {}
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm
-        kw["check_rep"] = False
-        if axis_names is not None:
-            kw["auto"] = frozenset(mesh.axis_names) - frozenset(axis_names)
-    else:
-        kw["check_vma"] = False
-        if axis_names is not None:
-            kw["axis_names"] = frozenset(axis_names)
-    try:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  **kw)
-    except TypeError:  # intermediate releases: jax.shard_map + check_rep
-        kw.pop("check_vma", None)
-        kw["check_rep"] = False
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  **kw)
-
-
 @contextlib.contextmanager
 def activation_sharding(mesh, rules: Dict[str, Any]):
     _CTX.append((mesh, rules))

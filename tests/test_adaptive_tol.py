@@ -259,8 +259,16 @@ def test_tol_none_bit_matches_pre_adaptive(key):
                                 cfg=_cfg(1e-30, iters=4), key=key,
                                 return_iters=True)
     assert int(used) == 4
+    # the unrolled chain and the while_loop body compile to different XLA
+    # programs, which may associate each fp32 GEMM and epilogue
+    # differently.  Per iteration an entry of the iterate (|x| <= 1 after
+    # Frobenius normalization) passes through 4 rounded ops — residual
+    # GEMM, Horner GEMM, alpha scale, apply GEMM — each worth <= 1 ulp,
+    # and the cubic NS map's slope is <= 2 on [0, 1]: <= 8 eps per
+    # iteration, accumulated over the 4 iterations.
+    atol = 4 * 8 * float(np.finfo(np.float32).eps)
     np.testing.assert_allclose(np.asarray(X_static), np.asarray(X_adapt),
-                               rtol=0, atol=1e-6)
+                               rtol=0, atol=atol)
 
 
 # -------------------------- (c) launch contracts: tol- and dtype-blind (§10)

@@ -281,7 +281,7 @@ def test_pending_twin_shardings_match_active():
     """opt_state_shardings gives every pending twin the SAME sharding as
     its active buffer (the swap is then a local per-shard select) and
     replicates the pending_at scalar."""
-    from repro.launch.mesh import compat_make_mesh
+    from jax.sharding import AxisType
     from repro.launch.sharding import replicated
     from repro.train.state import opt_state_shardings
 
@@ -289,7 +289,8 @@ def test_pending_twin_shardings_match_active():
     params, axes = _tree(key)
     cfg = _async_cfg("muon", matfn_tol=1e-2, precond_drift_slack=2.0)
     opt = make_optimizer(cfg, axes)
-    mesh = compat_make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     shapes = jax.eval_shape(lambda: params)
     pshard = jax.tree.map(lambda _: replicated(mesh), params)
     sh = opt_state_shardings(mesh, opt, shapes, pshard)
@@ -313,7 +314,7 @@ SHARDED_SCRIPT = textwrap.dedent("""
     import jax, jax.numpy as jnp
     import numpy as np
     from repro.config import OptimizerConfig, PrismConfig
-    from repro.launch.mesh import compat_make_mesh
+    from jax.sharding import AxisType
     from repro.optim import base, make_optimizer
     from repro.sharding_ctx import activation_sharding
 
@@ -354,7 +355,8 @@ SHARDED_SCRIPT = textwrap.dedent("""
         yield
 
     p_ref, _ = run(None)
-    mesh = compat_make_mesh((4, 2), ("data", "model"))
+    mesh = jax.make_mesh((4, 2), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
     @contextmanager
     def sharded():

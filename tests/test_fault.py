@@ -59,17 +59,14 @@ INT8_PSUM_SCRIPT = textwrap.dedent("""
     sys.path.insert(0, "src")
     import jax, jax.numpy as jnp
     import numpy as np
-    from jax.sharding import PartitionSpec as P
+    from jax.sharding import AxisType, PartitionSpec as P
     from repro.optim.compression import int8_psum
 
-    from repro.launch.mesh import compat_make_mesh
-    from repro.sharding_ctx import compat_shard_map
-
-    mesh = compat_make_mesh((4,), ("pod",))
+    mesh = jax.make_mesh((4,), ("pod",), axis_types=(AxisType.Auto,))
     x = jnp.asarray(np.random.RandomState(0).randn(4, 1000), jnp.float32)
 
-    f = compat_shard_map(lambda a: int8_psum(a[0], "pod"), mesh=mesh,
-                         in_specs=P("pod"), out_specs=P())
+    f = jax.shard_map(lambda a: int8_psum(a[0], "pod"), mesh=mesh,
+                      in_specs=P("pod"), out_specs=P(), check_vma=False)
     with mesh:
         got = f(x)
     want = np.sum(np.asarray(x), axis=0)

@@ -133,7 +133,12 @@ def test_fuzz_warm_tail(key, B, m, n, family, dtype):
     alphas = (1.45, 1.2, 0.9)
     if family == "polar":
         m, n = max(m, n), min(m, n)
-        X = (jax.random.normal(key, (B, m, n)) / np.sqrt(m)).astype(dtype)
+        # Frobenius-normalized like the chains' X_0: every singular value
+        # <= 1 keeps the (1.45, 1.2, 0.9) schedule convergent (randn /
+        # sqrt(m) reaches sigma ~ 2 on square shapes, where NS diverges)
+        X = jax.random.normal(key, (B, m, n))
+        X = (X / jnp.linalg.norm(X, axis=(-2, -1), keepdims=True)
+             ).astype(dtype)
         Y = None
     else:
         X = _sym(key, B, n, dtype, scale=2 * np.sqrt(n))

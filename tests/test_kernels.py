@@ -309,3 +309,15 @@ def test_prism_with_interpret_kernels_end_to_end(key, monkeypatch):
                      key=key, iters=6)
     np.testing.assert_allclose(np.asarray(Xk), np.asarray(Xr),
                                rtol=5e-3, atol=5e-3)
+
+
+@pytest.mark.parametrize("dtypes,want", [
+    ((jnp.float32, jnp.float32), jax.lax.Precision.HIGHEST),
+    ((jnp.bfloat16, jnp.float32), jax.lax.Precision.HIGHEST),
+    ((jnp.bfloat16, jnp.bfloat16), None)])
+def test_mxu_precision_keeps_fp32_contract(dtypes, want):
+    """fp32 operands contract at full precision inside the kernels (Mosaic
+    would round them to bf16 by default); bf16 ones need no override."""
+    from repro.kernels import mxu
+
+    assert mxu.precision(*[jnp.zeros((8, 128), d) for d in dtypes]) == want

@@ -57,9 +57,9 @@ SCRIPT = textwrap.dedent("""
     import jax, jax.numpy as jnp
     import numpy as np
     from repro.launch.pipeline import pipeline_apply
-    from repro.launch.mesh import compat_make_mesh
+    from jax.sharding import AxisType
 
-    mesh = compat_make_mesh((4,), ("pod",))
+    mesh = jax.make_mesh((4,), ("pod",), axis_types=(AxisType.Auto,))
     S, L_PER, D = 4, 2, 16
 
     def stage_fn(params, x):  # params [L_PER, D, D]
@@ -93,12 +93,10 @@ def test_gpipe_matches_sequential():
 ONE_F_ONE_B_SCRIPT = textwrap.dedent("""
     import jax, jax.numpy as jnp
     import numpy as np
-    from jax.sharding import PartitionSpec as P
+    from jax.sharding import AxisType, PartitionSpec as P
     from repro.launch.pipeline import n_ticks_1f1b, one_f_one_b
-    from repro.launch.mesh import compat_make_mesh
-    from repro.sharding_ctx import compat_shard_map
 
-    mesh = compat_make_mesh((4,), ("pod",))
+    mesh = jax.make_mesh((4,), ("pod",), axis_types=(AxisType.Auto,))
     S, L_PER, D, MB = 4, 2, 8, 4
 
     def stage_fn(shared, lay, inp, x, is_first, is_last):
@@ -142,11 +140,10 @@ ONE_F_ONE_B_SCRIPT = textwrap.dedent("""
     for use_ppermute in (True, False):
         run = one_f_one_b(stage_fn, "pod", S, M, act,
                           use_ppermute=use_ppermute)
-        mapped = compat_shard_map(
+        mapped = jax.shard_map(
             run, mesh=mesh,
             in_specs=(P(), P("pod"), P(), P("pod")),
-            out_specs=(P(), P(), P("pod")),
-            axis_names=None)
+            out_specs=(P(), P(), P("pod")), check_vma=False)
         with mesh:
             loss, g_sh, g_lay = jax.jit(mapped)(
                 shared, Ws, inp, jnp.arange(S, dtype=jnp.int32))
@@ -166,10 +163,10 @@ ONE_F_ONE_B_SCRIPT = textwrap.dedent("""
     # n_micro + 2*(n_stages-1) ticks
     for m in (4, 8):
         run = one_f_one_b(stage_fn, "pod", S, m, act)
-        mapped = compat_shard_map(
+        mapped = jax.shard_map(
             run, mesh=mesh,
             in_specs=(P(), P("pod"), P(), P("pod")),
-            out_specs=(P(), P(), P("pod")), axis_names=None)
+            out_specs=(P(), P(), P("pod")), check_vma=False)
         jpr = str(jax.make_jaxpr(mapped)(
             shared, Ws, inp[:1].repeat(m, 0),
             jnp.arange(S, dtype=jnp.int32)))
@@ -192,7 +189,7 @@ MODEL_PARITY_SCRIPT = textwrap.dedent("""
     import numpy as np
     from repro.configs import get_smoke_config
     from repro.data import DataConfig, make_batch_fn
-    from repro.launch.mesh import compat_make_mesh
+    from jax.sharding import AxisType
     from repro.models import build
     from repro.train.state import master_params, pipeline_loss_and_grads
 
@@ -205,7 +202,8 @@ MODEL_PARITY_SCRIPT = textwrap.dedent("""
         batch = make_batch_fn(cfg, DataConfig(
             vocab_size=cfg.vocab_size, seq_len=32, global_batch=8,
             seed=0, markov_rank=8))(jnp.asarray(0))
-        mesh = compat_make_mesh((stages,), ("pod",))
+        mesh = jax.make_mesh((stages,), ("pod",),
+                             axis_types=(AxisType.Auto,))
         lag = pipeline_loss_and_grads(model, mesh, n_micro=4)
         with mesh:
             loss, grads, _ = jax.jit(lag)(params, batch)

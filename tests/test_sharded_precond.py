@@ -29,7 +29,7 @@ SHARDED_SCRIPT = textwrap.dedent("""
     import jax, jax.numpy as jnp
     import numpy as np
     from repro.config import OptimizerConfig, PrismConfig
-    from repro.launch.mesh import compat_make_mesh
+    from jax.sharding import AxisType
     from repro.launch import sharding as sh
     from repro.optim import bucketing, make_optimizer
     from repro.sharding_ctx import activation_sharding
@@ -49,7 +49,8 @@ SHARDED_SCRIPT = textwrap.dedent("""
                           bucket_pad=True)
     # replicated reference: no sharding context installed
     ref = bucketing.polar_bucketed(views, cfg, key)
-    mesh = compat_make_mesh((4, 2), ("data", "model"))
+    mesh = jax.make_mesh((4, 2), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     with mesh, activation_sharding(
             mesh, {"opt_layers": "model", "opt_rows": "data"}):
         mm, ax = bucketing.mesh_batch_axes(cfg)
@@ -190,12 +191,10 @@ INT8_PSUM_SCRIPT = textwrap.dedent("""
     sys.path.insert(0, "src")
     import jax, jax.numpy as jnp
     import numpy as np
-    from repro.launch.mesh import compat_make_mesh
+    from jax.sharding import AxisType, PartitionSpec as P
     from repro.optim import compression
-    from repro.sharding_ctx import compat_shard_map
-    from jax.sharding import PartitionSpec as P
 
-    mesh = compat_make_mesh((8,), ("data",))
+    mesh = jax.make_mesh((8,), ("data",), axis_types=(AxisType.Auto,))
     key = jax.random.PRNGKey(3)
     # per-shard distinct magnitudes: exercises the shared-max-scale
     # renormalization (ratio < 1 on 7 of 8 shards), odd length 1000
@@ -209,9 +208,9 @@ INT8_PSUM_SCRIPT = textwrap.dedent("""
     def exact(xs):
         return jax.lax.psum(xs, "data")
 
-    run_q, run_f = [compat_shard_map(
+    run_q, run_f = [jax.shard_map(
         f, mesh=mesh, in_specs=(P("data", None),),
-        out_specs=P("data", None))(x)
+        out_specs=P("data", None), check_vma=False)(x)
         for f in (quantized, exact)]
     # every shard returns the same reduced vector; quantization error is
     # bounded by half an int8 step of the LARGEST shard's block scale,
@@ -238,9 +237,9 @@ INT8_PSUM_SCRIPT = textwrap.dedent("""
                         collectives(j, out)
         return out
 
-    jaxpr = jax.make_jaxpr(compat_shard_map(
+    jaxpr = jax.make_jaxpr(jax.shard_map(
         quantized, mesh=mesh, in_specs=(P("data", None),),
-        out_specs=P("data", None)))(x)
+        out_specs=P("data", None), check_vma=False))(x)
     seen = collectives(jaxpr.jaxpr, [])
     psums = [d for (n, d) in seen if n.startswith("psum")]
     pmaxs = [d for (n, d) in seen if n == "pmax"]

@@ -29,8 +29,9 @@ SCRIPT = textwrap.dedent("""
     from repro import checkpoint as ckpt
 
     def run_steps(mesh_shape, strategy, ckpt_dir, resume, grads_dtype):
-        from repro.launch.mesh import compat_make_mesh
-        mesh = compat_make_mesh(mesh_shape, ("data", "model"))
+        from jax.sharding import AxisType
+        mesh = jax.make_mesh(mesh_shape, ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
         cfg = get_smoke_config("qwen3-14b").replace(
             d_model=64, num_heads=4, num_kv_heads=2, head_dim=16)
         model = build(cfg)

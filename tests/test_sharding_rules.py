@@ -12,9 +12,10 @@ from repro.launch import sharding as sh
 def mesh():
     # metadata-only usage: a 1-device mesh can't express 16x16, so build
     # an abstract mesh with the production shape
-    from repro.launch.mesh import compat_abstract_mesh
+    from jax.sharding import AbstractMesh, AxisType
 
-    return compat_abstract_mesh((16, 16), ("data", "model"))
+    return AbstractMesh((16, 16), ("data", "model"),
+                        axis_types=(AxisType.Auto,) * 2)
 
 
 def test_constrain_spec_drops_nondivisible(mesh):

@@ -5,6 +5,7 @@ import os
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.config import OptimizerConfig, PrismConfig, TrainConfig
 from repro.configs import get_smoke_config
@@ -58,9 +59,9 @@ def test_elastic_restore_to_sharded(tmp_path):
 
     from repro import checkpoint as ckpt
 
-    from repro.launch.mesh import compat_make_mesh
+    from jax.sharding import AxisType
 
-    mesh = compat_make_mesh((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",), axis_types=(AxisType.Auto,))
     tree = {"w": jnp.arange(16.0).reshape(4, 4)}
     ckpt.save(str(tmp_path), 3, tree)
     sh = {"w": NamedSharding(mesh, P("data", None))}
@@ -69,3 +70,34 @@ def test_elastic_restore_to_sharded(tmp_path):
     assert restored["w"].sharding == sh["w"]
     np.testing.assert_array_equal(np.asarray(restored["w"]),
                                   np.asarray(tree["w"]))
+
+
+def test_build_trainer_one_device(tmp_path):
+    """The launcher's shared construction (launch/train.py, used by
+    chip_smoke.py) trains with and without the kernel path."""
+    from repro.launch.train import build_trainer
+
+    cfg = get_smoke_config("gpt2-paper")
+    runs = []
+    for use_kernels in (False, True):
+        ck = tmp_path / str(use_kernels)
+        tr, ctx = build_trainer(cfg, ckpt_dir=str(ck), steps=3, seq=32,
+                                batch=4, ckpt_every=3,
+                                use_kernels=use_kernels)
+        assert tr.ocfg.prism.use_kernels is use_kernels
+        with ctx:
+            _, _, losses = tr.run()
+        runs.append(losses)
+        assert os.path.exists(ck / "step_00000003")
+    assert all(np.isfinite(runs[0])) and runs[0][-1] < runs[0][0]
+    # on CPU the kernels dispatch to the ref.py oracles, which the jnp
+    # chain matches op for op
+    np.testing.assert_allclose(runs[1], runs[0], rtol=1e-6)
+
+
+@pytest.mark.parametrize("n,shape", [(1, (1, 1)), (2, (2, 1)),
+                                     (4, (2, 2)), (8, (4, 2))])
+def test_device_mesh_shape(n, shape):
+    from repro.launch.mesh import device_mesh_shape
+
+    assert device_mesh_shape(n) == shape
