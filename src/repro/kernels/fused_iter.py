@@ -188,6 +188,7 @@ def residual_chain(X: jax.Array, St: jax.Array, max_power: int,
             jax.ShapeDtypeStruct((nb, max_power), jnp.float32),
         ],
         interpret=interpret,
+        name="prism_residual_chain",
     )(*operands)
     return R[:, :n, :n], t
 
@@ -251,6 +252,7 @@ def apply_g(X: jax.Array, R: jax.Array, alpha: jax.Array,
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=interpret,
+        name="prism_apply_g",
     )(*operands)
     if coupled:
         return outs[0][:, :m, :n], outs[1][:, :n, :n]
@@ -349,6 +351,7 @@ def warm_tail(X: jax.Array, alphas: jax.Array, n_iters: int,
         out_shape=out_shape,
         scratch_shapes=scratch,
         interpret=interpret,
+        name="prism_warm_tail",
     )(*operands)
     if coupled:
         return outs[0][:, :n, :n], outs[1][:, :n, :n]

@@ -125,6 +125,7 @@ def gram_upper(X: jax.Array, *, alpha: float = 1.0, beta: float = -1.0,
         out_shape=jax.ShapeDtypeStruct((nbatch, N, N), X.dtype),
         scratch_shapes=[pltpu.VMEM((bn, bn), jnp.float32)],
         interpret=interpret,
+        name="prism_gram",
     )(Xp, Xp)
     out = out[:, :n, :n]
     return out[0] if squeeze else out

@@ -95,6 +95,7 @@ def matmul_add(A: jax.Array, B: jax.Array, C: jax.Array | None = None,
         out_shape=jax.ShapeDtypeStruct((nb, M, N), A.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
+        name="prism_matmul_add",
     )(Ap, Bp, Cp)
     out = out[:, :m, :n]
     return out[0] if squeeze else out

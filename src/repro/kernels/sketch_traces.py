@@ -107,6 +107,7 @@ def sketch_step(R: jax.Array, V: jax.Array, St: jax.Array,
         ],
         scratch_shapes=[pltpu.VMEM((bm, p), jnp.float32)],
         interpret=interpret,
+        name="prism_sketch_step",
     )(Rp, Vp, Stp)
     vout = vout[:, :n]
     return (vout[0], t[0]) if squeeze else (vout, t)
@@ -200,5 +201,6 @@ def sketch_chain(R: jax.Array, St: jax.Array, max_power: int,
             pltpu.VMEM((bn, p), jnp.float32),
         ],
         interpret=interpret,
+        name="prism_sketch_chain",
     )(Rp, Stp)
     return t[0] if squeeze else t

@@ -35,6 +35,7 @@ from repro.models import layers as L
 from repro.models import moe as moe_lib
 from repro.models import rglru as rglru_lib
 from repro.models import ssm as ssm_lib
+from repro.scopes import scoped
 from repro.sharding_ctx import shard_activation
 
 INT_SENTINEL = np.iinfo(np.int32).max
@@ -265,6 +266,7 @@ class Model:
 
     # ------------------------------------------------------------- embed
 
+    @scoped("embed")
     def _embed_tokens(self, params, tokens):
         cfg = self.cfg
         if cfg.family == "audio":
@@ -296,6 +298,7 @@ class Model:
 
     # ------------------------------------------------------------- forward
 
+    @scoped("layers")
     def _backbone(self, params, x, positions):
         """x [B, S, D] -> (x, aux_loss)."""
         cfg = self.cfg
@@ -412,6 +415,7 @@ class Model:
         return loss, {"ce": ce, "aux": aux,
                       "ppl_proxy": jnp.exp(jnp.minimum(ce, 20.0))}
 
+    @scoped("ce")
     def _ce_from_hidden(self, params, x, tokens,
                         chunk: Optional[int] = None) -> jax.Array:
         """Chunked next-token CE given backbone output x [B, S, D].

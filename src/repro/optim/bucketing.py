@@ -58,6 +58,7 @@ import jax.numpy as jnp
 from repro import sharding_ctx
 from repro.config import OptimizerConfig, PrismConfig
 from repro.core import matfn
+from repro.scopes import scoped
 
 
 class Entry(NamedTuple):
@@ -356,6 +357,7 @@ def shard_over_batch(fn: Callable, mesh, axes: Tuple[str, ...],
     return jax.tree.map(lambda o: o[:B], out) if pad else out
 
 
+@scoped("polar")
 def polar_bucketed(views: Sequence[jax.Array], cfg: OptimizerConfig,
                    key: Optional[jax.Array],
                    with_iters: bool = False):
@@ -388,69 +390,71 @@ def polar_bucketed(views: Sequence[jax.Array], cfg: OptimizerConfig,
     iters: List[Optional[jax.Array]] = [None] * len(views)
     statuses: List[Optional[jax.Array]] = [None] * len(views)
     for bi, b in enumerate(buckets):
-        stacked = gather_bucket(b, views, dtype=compute)
-        local_reshard = (cfg.muon_local_reshard
-                         and all(e.lead for e in b.entries))
-        if local_reshard:
-            # layers -> model, rows -> data (see make_muon): the batched NS
-            # iterations then need only one [n, n] R-psum per step.  Like
-            # the per-leaf path (which resharded only M.ndim >= 3 views),
-            # this applies only to buckets built purely from scanned-layer
-            # stacks — plain 2-D leaves keep their layout, and a mixed
-            # bucket is not co-sharded unevenly over opt_layers.  Takes
-            # precedence over the batch-dim shard_map engine: the two are
-            # alternative distribution strategies for the same bucket.
-            stacked = sharding_ctx.shard_activation(
-                stacked, ("opt_layers", "opt_rows", None))
-        kk = (jax.random.fold_in(key, bi) if key is not None else None)
-        lowrank_l = resolve_lowrank_tier(cfg, b.shape)
-        if lowrank_l is not None:
-            # §14 lowrank tier: the projected chains live on the l side,
-            # which is never padded (plan_buckets pads the Gram side of
-            # the FULL view only, and zero pad rows/cols stay zero
-            # through the sketch -> project -> lift composition), so no
-            # n_real correction is threaded.  The §10 fuse choice
-            # resolves inside the inner polar calls from the SMALL
-            # [m, l] / [l, n] shapes (newton_schulz._fused_tier), not
-            # from the full bucket shape.
-            from repro.core import lowrank as lr
+        # one scope per bucket around its gather, chain and scatter
+        with jax.named_scope("bucket_%dx%dx%d" % ((b.size,) + b.shape)):
+            stacked = gather_bucket(b, views, dtype=compute)
+            local_reshard = (cfg.muon_local_reshard
+                             and all(e.lead for e in b.entries))
+            if local_reshard:
+                # layers -> model, rows -> data (see make_muon): the batched NS
+                # iterations then need only one [n, n] R-psum per step.  Like
+                # the per-leaf path (which resharded only M.ndim >= 3 views),
+                # this applies only to buckets built purely from scanned-layer
+                # stacks — plain 2-D leaves keep their layout, and a mixed
+                # bucket is not co-sharded unevenly over opt_layers.  Takes
+                # precedence over the batch-dim shard_map engine: the two are
+                # alternative distribution strategies for the same bucket.
+                stacked = sharding_ctx.shard_activation(
+                    stacked, ("opt_layers", "opt_rows", None))
+            kk = (jax.random.fold_in(key, bi) if key is not None else None)
+            lowrank_l = resolve_lowrank_tier(cfg, b.shape)
+            if lowrank_l is not None:
+                # §14 lowrank tier: the projected chains live on the l side,
+                # which is never padded (plan_buckets pads the Gram side of
+                # the FULL view only, and zero pad rows/cols stay zero
+                # through the sketch -> project -> lift composition), so no
+                # n_real correction is threaded.  The §10 fuse choice
+                # resolves inside the inner polar calls from the SMALL
+                # [m, l] / [l, n] shapes (newton_schulz._fused_tier), not
+                # from the full bucket shape.
+                from repro.core import lowrank as lr
 
-            def run(x, _kk=kk, _l=lowrank_l):
-                return lr.polar_lowrank(
-                    x, cfg.lowrank_rank, cfg.lowrank_oversample,
-                    cfg=pcfg, key=_kk, method=method,
-                    return_iters=with_iters, return_status=with_iters)
+                def run(x, _kk=kk, _l=lowrank_l):
+                    return lr.polar_lowrank(
+                        x, cfg.lowrank_rank, cfg.lowrank_oversample,
+                        cfg=pcfg, key=_kk, method=method,
+                        return_iters=with_iters, return_status=with_iters)
 
-            n_real = None
-        else:
-            n_real = (_gram_real_dims(b)
-                      if b.padded and method == "prism" else None)
-            pcfg_b = resolve_fused_tier(pcfg, b)
+                n_real = None
+            else:
+                n_real = (_gram_real_dims(b)
+                          if b.padded and method == "prism" else None)
+                pcfg_b = resolve_fused_tier(pcfg, b)
 
-            def run(x, *nr, _kk=kk, _pcfg=pcfg_b):
-                if method == "svd":
-                    return matfn.polar(x, method="svd")
-                kw = {"n_real": nr[0]} if nr else {}
-                if with_iters:  # NS family only (asserted above)
-                    kw["return_iters"] = True
-                    kw["return_status"] = True
-                return matfn.polar(x, method=method, cfg=_pcfg, key=_kk,
-                                   **kw)
+                def run(x, *nr, _kk=kk, _pcfg=pcfg_b):
+                    if method == "svd":
+                        return matfn.polar(x, method="svd")
+                    kw = {"n_real": nr[0]} if nr else {}
+                    if with_iters:  # NS family only (asserted above)
+                        kw["return_iters"] = True
+                        kw["return_status"] = True
+                    return matfn.polar(x, method=method, cfg=_pcfg, key=_kk,
+                                       **kw)
 
-        if mesh is not None and not local_reshard:
-            gram_full = min(b.shape)  # pad slices carry no intra-slice pad
-            O = shard_over_batch(
-                run, mesh, mesh_axes, stacked,
-                slice_args=() if n_real is None else (n_real,),
-                slice_pads=() if n_real is None else (gram_full,),
-                out_ranks=(3, 1, 1) if with_iters else None)
-        else:
-            O = run(stacked) if n_real is None else run(stacked, n_real)
-        if with_iters:
-            O, it, st = O
-            scatter_bucket_aux(b, it, iters)
-            scatter_bucket_aux(b, st, statuses)
-        scatter_bucket(b, O, outs)
+            if mesh is not None and not local_reshard:
+                gram_full = min(b.shape)  # pad slices carry no intra-slice pad
+                O = shard_over_batch(
+                    run, mesh, mesh_axes, stacked,
+                    slice_args=() if n_real is None else (n_real,),
+                    slice_pads=() if n_real is None else (gram_full,),
+                    out_ranks=(3, 1, 1) if with_iters else None)
+            else:
+                O = run(stacked) if n_real is None else run(stacked, n_real)
+            if with_iters:
+                O, it, st = O
+                scatter_bucket_aux(b, it, iters)
+                scatter_bucket_aux(b, st, statuses)
+            scatter_bucket(b, O, outs)
     if with_iters:
         return outs, iters, statuses
     return outs  # type: ignore[return-value]

@@ -5,6 +5,11 @@ model dtype (bf16 matrices, fp32 norms/ssm constants).  The PRISM sketch
 key is derived from the step counter inside the jitted step, so the step
 signature stays (params, opt_state, batch, step) — clean to lower and to
 checkpoint.
+
+The step's device work sits under three named scopes, in the op names
+the profiler shows: ``fwd_bwd`` (the casts, loss and gradient),
+``clip`` (clipping and compression) and ``optimizer`` (the update).
+Scopes are metadata only: they change no compiled operation.
 """
 from __future__ import annotations
 
@@ -40,26 +45,42 @@ def make_train_step(model: Model, opt: base.Optimizer,
     cast_tree = model.param_dtypes()
 
     def train_step(params, opt_state, batch, step, refresh=None):
-        if ocfg.grads_dtype == "bfloat16":
-            # differentiate wrt the bf16 compute params: the DP gradient
-            # reduce-scatter then moves bf16 (half the wire bytes); the
-            # fp32 master update converts afterwards.
-            pc = jax.tree.map(lambda x, dt: x.astype(dt), params, cast_tree)
-            (loss, metrics), grads = jax.value_and_grad(
-                lambda q: model.loss(q, batch), has_aux=True)(pc)
-            grads = jax.tree.map(lambda g: g.astype(jnp.float32), grads)
-        else:
-            def loss_fn(p):
-                pc = jax.tree.map(lambda x, dt: x.astype(dt), p, cast_tree)
-                return model.loss(pc, batch)
+        with jax.named_scope("fwd_bwd"):
+            if ocfg.grads_dtype == "bfloat16":
+                # differentiate wrt the bf16 compute params: the DP
+                # gradient reduce-scatter then moves bf16 (half the wire
+                # bytes); the fp32 master update converts afterwards.
+                pc = jax.tree.map(lambda x, dt: x.astype(dt), params,
+                                  cast_tree)
+                (loss, metrics), grads = jax.value_and_grad(
+                    lambda q: model.loss(q, batch), has_aux=True)(pc)
+                grads = jax.tree.map(lambda g: g.astype(jnp.float32), grads)
+            else:
+                def loss_fn(p):
+                    pc = jax.tree.map(lambda x, dt: x.astype(dt), p,
+                                      cast_tree)
+                    return model.loss(pc, batch)
 
-            (loss, metrics), grads = jax.value_and_grad(
-                loss_fn, has_aux=True)(params)
+                (loss, metrics), grads = jax.value_and_grad(
+                    loss_fn, has_aux=True)(params)
+        return _clip_and_update(opt, ocfg, inject, params, opt_state, loss,
+                                grads, metrics, step, refresh)
+
+    return train_step
+
+
+def _clip_and_update(opt: base.Optimizer, ocfg: OptimizerConfig, inject,
+                     params, opt_state, loss, grads, metrics, step, refresh):
+    """The part of a train step after the gradients, shared by the plain
+    and the pipelined step: the chaos hook, clipping and compression
+    (scope ``clip``), and the optimizer update (scope ``optimizer``)."""
+    with jax.named_scope("clip"):
         if inject is not None:
             grads = inject(grads, step)
         grads, gnorm = base.clip_by_global_norm(grads, ocfg.grad_clip_norm)
         if ocfg.gradient_compression == "int8":
             grads = compression.int8_roundtrip(grads)
+    with jax.named_scope("optimizer"):
         key = jax.random.fold_in(jax.random.PRNGKey(0), step)
         params, opt_state = opt.update(grads, opt_state, params, step, key,
                                        refresh=refresh)
@@ -69,9 +90,7 @@ def make_train_step(model: Model, opt: base.Optimizer,
             # host-side AsyncPrecondService can trigger refreshes (a few
             # scalars — free next to the loss readback)
             metrics["precond_drift"] = base.precond_drift(opt_state)
-        return params, opt_state, metrics
-
-    return train_step
+    return params, opt_state, metrics
 
 
 PIPELINE_FAMILIES = ("dense", "moe", "ssm")
@@ -184,19 +203,10 @@ def make_pipeline_train_step(model: Model, opt: base.Optimizer,
                                              axis=axis)
 
     def train_step(params, opt_state, batch, step, refresh=None):
-        loss, grads, metrics = loss_and_grads(params, batch)
-        if inject is not None:
-            grads = inject(grads, step)
-        grads, gnorm = base.clip_by_global_norm(grads, ocfg.grad_clip_norm)
-        if ocfg.gradient_compression == "int8":
-            grads = compression.int8_roundtrip(grads)
-        key = jax.random.fold_in(jax.random.PRNGKey(0), step)
-        params, opt_state = opt.update(grads, opt_state, params, step, key,
-                                       refresh=refresh)
-        metrics = dict(metrics, loss=loss, grad_norm=gnorm)
-        if ocfg.precond_async:
-            metrics["precond_drift"] = base.precond_drift(opt_state)
-        return params, opt_state, metrics
+        with jax.named_scope("fwd_bwd"):
+            loss, grads, metrics = loss_and_grads(params, batch)
+        return _clip_and_update(opt, ocfg, inject, params, opt_state, loss,
+                                grads, metrics, step, refresh)
 
     return train_step
 

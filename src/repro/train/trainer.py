@@ -6,15 +6,23 @@ Features (DESIGN.md §4):
     checkpoint and re-shards it onto the *current* mesh (elastic scaling);
   * deterministic stateless data: batch_for_step(step) is pure, so resume
     replays the exact token stream with no iterator state;
-  * straggler / hang detection: per-step wall times vs a running median;
-    steps slower than ``straggler_slack`` x median are flagged (on a real
-    fleet this feeds the slow-host eviction hook) and a heartbeat file is
-    touched every step for external watchdogs;
+  * straggler / hang detection: per-step wall times vs the median of
+    the last ``STRAGGLER_WINDOW`` steps; steps slower than
+    ``straggler_slack`` x median are flagged (on a real fleet this feeds
+    the slow-host eviction hook) and a heartbeat file is touched every
+    step for external watchdogs;
+  * observability at no cost while the profiler is off: each step is a
+    ``trainer.step`` host span whose children (``trainer.batch``,
+    ``.precond``, ``.dispatch``, ``.sync``, ``.heartbeat``, ``.stats``,
+    ``.callback``, ``.checkpoint``) tile the loop body, and
+    ``counters["compiles_by_step"]`` records, for each step during which
+    JAX compiled a program, how many it compiled;
   * multi-pod: the same code lowers under the production mesh — the
     launcher passes (mesh, shardings); on CPU tests mesh=None runs local.
 """
 from __future__ import annotations
 
+import collections
 import os
 import time
 from typing import Any, Callable, Dict, Optional
@@ -31,6 +39,22 @@ from repro.optim import base, make_optimizer
 from repro.train import fault
 from repro.train.state import (make_pipeline_train_step, make_train_step,
                                master_params)
+
+# straggler detection compares a step with the median of this many
+# recent steps: bounded work per step however long the run
+STRAGGLER_WINDOW = 256
+# the event JAX records around every backend compilation (a persistent
+# cache hit included)
+_BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_compiles = [0]  # backend compilations in this process so far
+
+
+def _count_compile(event: str, duration: float, **kwargs) -> None:
+    if event == _BACKEND_COMPILE_EVENT:
+        _compiles[0] += 1
+
+
+jax.monitoring.register_event_duration_secs_listener(_count_compile)
 
 
 class Trainer:
@@ -57,8 +81,8 @@ class Trainer:
                                                inject=inject)
         else:
             step_fn = make_train_step(model, self.opt, ocfg, inject=inject)
-        # the unjitted step stays reachable for trace-only observability
-        # (benchmarks count its Pallas launches via ops.count_launches)
+        # the unjitted step stays reachable for trace-only observability:
+        # ops.count_launches(raw_step_fn, ...) counts its Pallas launches
         self.raw_step_fn = step_fn
         # refresh (arg 4) is static: with precond_every=K>1 the loop picks
         # the refresh/skip step variant per step in Python (exact at step
@@ -82,8 +106,9 @@ class Trainer:
                         if ocfg.precond_async else None)
         self._last_drift = 0.0
         self._ckpt_thread = None
-        self.step_times: list = []
+        self.step_times = collections.deque(maxlen=STRAGGLER_WINDOW)
         self.straggler_events = 0
+        self.counters: Dict[str, Any] = {"compiles_by_step": {}}
 
     @property
     def matfn_telemetry(self) -> Dict[str, Any]:
@@ -131,7 +156,8 @@ class Trainer:
     def run(self, steps: Optional[int] = None, seed: int = 0,
             on_metrics: Optional[Callable] = None):
         steps = steps or self.tcfg.steps
-        params, opt_state, start = self.restore_or_init(seed)
+        with jax.profiler.TraceAnnotation("trainer.restore_or_init"):
+            params, opt_state, start = self.restore_or_init(seed)
         hb_path = os.path.join(self.tcfg.checkpoint_dir, "HEARTBEAT")
         os.makedirs(self.tcfg.checkpoint_dir, exist_ok=True)
         losses = []
@@ -139,15 +165,34 @@ class Trainer:
         # async service via the single resolve_refresh_period helper)
         K = base.resolve_refresh_period(self.ocfg)
         for t in range(start, steps):
-            t0 = time.perf_counter()
+            compiles = _compiles[0]
+            with jax.profiler.StepTraceAnnotation("trainer.step",
+                                                  step_num=t):
+                params, opt_state, loss = self._step(
+                    t, start, K, params, opt_state, hb_path, on_metrics)
+            losses.append(loss)
+            if _compiles[0] > compiles:
+                self.counters["compiles_by_step"][t] = _compiles[0] - compiles
+        if self._ckpt_thread is not None:
+            self._ckpt_thread.join()
+        return params, opt_state, losses
+
+    def _step(self, t: int, start: int, K: int, params, opt_state,
+              hb_path: str, on_metrics: Optional[Callable]):
+        """One iteration of ``run``'s loop, each part in its own host
+        span; returns the new state and the step's loss."""
+        span = jax.profiler.TraceAnnotation
+        t0 = time.perf_counter()
+        with span("trainer.batch"):
             batch = self.batch_fn(jnp.asarray(t))
-            if self.precond is not None:
-                # §12 two-phase step.  Phase 1: maybe dispatch a refresh
-                # (non-blocking — the chains overlap the step below);
-                # drift is read from the PREVIOUS step's metrics, so no
-                # extra device sync here.  Phase 2: the step itself, with
-                # refresh=False pinned statically — the only compiled
-                # step variant, and it contains zero matfn launches.
+        if self.precond is not None:
+            # §12 two-phase step.  Phase 1: maybe dispatch a refresh
+            # (non-blocking — the chains overlap the step below);
+            # drift is read from the PREVIOUS step's metrics, so no
+            # extra device sync here.  Phase 2: the step itself, with
+            # refresh=False pinned statically — the only compiled
+            # step variant, and it contains zero matfn launches.
+            with span("trainer.precond"):
                 opt_state = self.precond.step_begin(
                     opt_state, t,
                     jax.random.fold_in(jax.random.PRNGKey(1), t),
@@ -160,24 +205,20 @@ class Trainer:
                     # other leaves already match -> no-copy)
                     opt_state = jax.device_put(opt_state,
                                                self.shardings["opt"])
-                refresh = False
-            else:
-                refresh = (t % K == 0) if K > 1 else None
+            refresh = False
+        else:
+            refresh = (t % K == 0) if K > 1 else None
+        with span("trainer.dispatch"):
             params, opt_state, metrics = self.step_fn(
                 params, opt_state, batch, jnp.asarray(t, jnp.int32),
                 refresh)
+        with span("trainer.sync"):
             jax.block_until_ready(metrics["loss"])
             if self.precond is not None:
                 self._last_drift = float(metrics["precond_drift"])
-            dt = time.perf_counter() - t0
-            if t > start:  # exclude compile step from straggler stats
-                self.step_times.append(dt)
-                med = float(np.median(self.step_times))
-                if dt > self.tcfg.straggler_slack * med and \
-                        len(self.step_times) > 5:
-                    self.straggler_events += 1
-                    print(f"[trainer] straggler: step {t} took {dt:.2f}s "
-                          f"(median {med:.2f}s)", flush=True)
+            loss = float(metrics["loss"])
+        dt = time.perf_counter() - t0
+        with span("trainer.heartbeat"):
             now = time.time()
             with open(hb_path, "w") as f:
                 f.write(f"{t} {now}")
@@ -188,17 +229,24 @@ class Trainer:
                 for s in range(self.tcfg.pipeline_stages):
                     with open(f"{hb_path}.stage{s}", "w") as f:
                         f.write(f"{t} {now}")
-            loss = float(metrics["loss"])
-            losses.append(loss)
+        with span("trainer.stats"):
+            if t > start:  # exclude compile step from straggler stats
+                self.step_times.append(dt)
+                med = float(np.median(self.step_times))
+                if dt > self.tcfg.straggler_slack * med and \
+                        len(self.step_times) > 5:
+                    self.straggler_events += 1
+                    print(f"[trainer] straggler: step {t} took {dt:.2f}s "
+                          f"(median {med:.2f}s)", flush=True)
             if t % self.tcfg.log_every == 0:
                 print(f"[trainer] step {t} loss {loss:.4f} "
                       f"gnorm {float(metrics['grad_norm']):.3f} "
                       f"{dt:.2f}s", flush=True)
-            if on_metrics is not None:
+        if on_metrics is not None:
+            with span("trainer.callback"):
                 on_metrics(t, metrics)
-            if self.tcfg.checkpoint_every and \
-                    (t + 1) % self.tcfg.checkpoint_every == 0:
+        if self.tcfg.checkpoint_every and \
+                (t + 1) % self.tcfg.checkpoint_every == 0:
+            with span("trainer.checkpoint"):
                 self._checkpoint(t + 1, params, opt_state)
-        if self._ckpt_thread is not None:
-            self._ckpt_thread.join()
-        return params, opt_state, losses
+        return params, opt_state, loss
