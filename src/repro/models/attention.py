@@ -1,27 +1,52 @@
-"""Grouped-query attention with flash-style chunked softmax.
+"""Grouped-query attention: a fused Pallas kernel or a jnp chunked softmax.
 
 Supports: GQA (num_kv_heads < num_heads), qk-norm (Qwen3), QKV bias
 (Qwen2.5), RoPE, sliding-window (Mixtral) / local (RecurrentGemma)
 attention, and single-token decode against a KV cache.
 
-The train/prefill path never materializes the [S, S] score matrix: it
-scans over KV blocks with an online-softmax running (max, denom, acc)
-carry, so activation memory is O(S * block) — required for prefill_32k.
+The train/prefill path has two implementations of one algorithm
+(causal online-softmax attention, bf16 operands, float32 accumulation),
+chosen in ``attend`` from what the code observes:
+
+* on one TPU, with no window inside the sequence and a sequence the
+  kernel's block divides, a fused Pallas kernel (``_kernel_attend``):
+  scores and probabilities stay in VMEM, forward and backward;
+* everywhere else (the CPU, windows shorter than the sequence, a mesh of
+  several devices, odd shapes) a jnp scan over KV blocks of ``KV_BLOCK``
+  keys with a running (max, denom, acc) carry (``_flash_attend``).  Its
+  activation memory is O(S * block), but at S <= ``KV_BLOCK`` it runs
+  one block and materializes the whole [B, S, H, S] score matrix in
+  float32 in HBM.
+
+``path_counts`` counts, at trace time, the ``attend`` calls each path
+took (``Trainer.counters["attention_path"]`` copies it); the paths run
+under the named scopes ``attn_kernel`` and ``attn_chunked``.
 """
 from __future__ import annotations
 
+import collections
 from typing import Optional
 
 import jax
 import numpy as np
 import jax.numpy as jnp
+from jax.experimental.pallas.ops.tpu import splash_attention as splash
 
+from repro import sharding_ctx
 from repro.config import ModelConfig
 from repro.models import layers as L
+from repro.scopes import scoped
 from repro.sharding_ctx import shard_activation
 
 KV_BLOCK = 1024
+# the fused kernel's q and kv block (forward and fused backward), and the
+# head sizes it is taken for
+KERNEL_BLOCK = 512
+KERNEL_HEAD_DIMS = (64, 128)
 HEAD_PAD = 16  # pad head counts to the model-axis width for clean TP
+
+# trace-time count of ``attend`` calls by path: "kernel" or "chunked"
+path_counts: collections.Counter = collections.Counter()
 
 
 def padded_heads(cfg: ModelConfig):
@@ -117,6 +142,38 @@ def _expand_kv(k, groups: int):
     return jnp.repeat(k, groups, axis=2)
 
 
+def _causal_kernel(H: int, S: int):
+    """The shipped splash-attention kernel for H heads of causal [S, S]
+    masks at the fixed block sizes.  Made anew in every trace: it holds
+    its mask tables as arrays of the trace that made it."""
+    b = min(KERNEL_BLOCK, S)
+    mask = splash.MultiHeadMask([splash.CausalMask((S, S))] * H)
+    blocks = splash.BlockSizes(
+        block_q=b, block_kv=b, block_kv_compute=b,
+        block_q_dkv=b, block_kv_dkv=b, block_kv_dkv_compute=b,
+        use_fused_bwd_kernel=True)
+    return splash.make_splash_mha(mask, block_sizes=blocks, head_shards=1,
+                                  q_seq_shards=1)
+
+
+@scoped("attn_kernel")
+def _kernel_attend(q, k, v):
+    """Causal attention as one fused Pallas kernel, forward and backward;
+    q [B,S,H,Hd], k/v [B,S,KV,Hd] -> [B,S,H,Hd].  q is scaled in float32
+    and cast back as ``_flash_attend`` does; q, k and v stay bf16, the
+    scores, softmax and accumulators float32."""
+    B, S, H, Hd = q.shape
+    k = _expand_kv(k, H // k.shape[2])
+    v = _expand_kv(v, H // v.shape[2])
+    scale = 1.0 / jnp.sqrt(Hd).astype(jnp.float32)
+    qs = (q.astype(jnp.float32) * scale).astype(q.dtype)
+    heads_first = lambda x: x.transpose(0, 2, 1, 3)  # [B, H, S, Hd]
+    out = jax.vmap(_causal_kernel(H, S))(
+        heads_first(qs), heads_first(k), heads_first(v))
+    return heads_first(out)
+
+
+@scoped("attn_chunked")
 def _flash_attend(q, k, v, q_pos, k_pos, window: int, kv_block: int):
     """Online-softmax attention; q [B,S,H,Hd], k/v [B,Sk,KV,Hd]."""
     B, S, H, Hd = q.shape
@@ -167,12 +224,40 @@ def _flash_attend(q, k, v, q_pos, k_pos, window: int, kv_block: int):
     return out.astype(q.dtype)
 
 
+def _one_device() -> bool:
+    """No activation-sharding context and no mesh (a shard_map body's
+    included) spans more than one device: a ``pallas_call`` is not
+    partitioned under GSPMD."""
+    ctx = sharding_ctx.current()
+    mesh = jax.sharding.get_abstract_mesh()
+    return ((ctx is None or ctx[0].size == 1)
+            and (mesh.empty or mesh.size == 1))
+
+
+def _use_kernel(S: int, Sk: int, Hd: int, window: int) -> bool:
+    """Whether ``attend`` takes the fused kernel for these shapes here."""
+    return (jax.default_backend() == "tpu"
+            and (not window or window >= S)
+            and S == Sk and S % KERNEL_BLOCK == 0
+            and Hd in KERNEL_HEAD_DIMS
+            and _one_device())
+
+
 def attend(params, x, positions, cfg: ModelConfig, window: int | None = None,
            kv_block: int = KV_BLOCK):
-    """Self-attention over x [B, S, D] (train / prefill)."""
+    """Self-attention over x [B, S, D] (train / prefill).
+
+    ``positions`` must be ``arange(S)`` on every row, as every caller
+    builds it: the fused kernel masks causally by index, not by
+    position (the jnp loop reads the positions)."""
     q, k, v = _project_qkv(params, x, positions, cfg)
     w = cfg.sliding_window if window is None else window
-    out = _flash_attend(q, k, v, positions, positions, w, kv_block)
+    if _use_kernel(q.shape[1], k.shape[1], q.shape[3], w):
+        path_counts["kernel"] += 1
+        out = _kernel_attend(q, k, v)
+    else:
+        path_counts["chunked"] += 1
+        out = _flash_attend(q, k, v, positions, positions, w, kv_block)
     return jnp.einsum("bshk,hkd->bsd", out, params["wo"]), (k, v)
 
 

@@ -16,7 +16,9 @@ Features (DESIGN.md §4):
     ``.precond``, ``.dispatch``, ``.sync``, ``.heartbeat``, ``.stats``,
     ``.callback``, ``.checkpoint``) tile the loop body, and
     ``counters["compiles_by_step"]`` records, for each step during which
-    JAX compiled a program, how many it compiled;
+    JAX compiled a program, how many it compiled, and
+    ``counters["attention_path"]`` how many ``attend`` calls took each
+    attention path when the step was last traced;
   * multi-pod: the same code lowers under the production mesh — the
     launcher passes (mesh, shardings); on CPU tests mesh=None runs local.
 """
@@ -34,6 +36,7 @@ import numpy as np
 from repro import checkpoint as ckpt
 from repro.config import OptimizerConfig, TrainConfig
 from repro.data import DataConfig, make_batch_fn
+from repro.models import attention
 from repro.models.transformer import Model
 from repro.optim import base, make_optimizer
 from repro.train import fault
@@ -166,6 +169,7 @@ class Trainer:
         K = base.resolve_refresh_period(self.ocfg)
         for t in range(start, steps):
             compiles = _compiles[0]
+            paths = attention.path_counts.copy()
             with jax.profiler.StepTraceAnnotation("trainer.step",
                                                   step_num=t):
                 params, opt_state, loss = self._step(
@@ -173,6 +177,9 @@ class Trainer:
             losses.append(loss)
             if _compiles[0] > compiles:
                 self.counters["compiles_by_step"][t] = _compiles[0] - compiles
+            traced = attention.path_counts - paths
+            if traced:
+                self.counters["attention_path"] = dict(traced)
         if self._ckpt_thread is not None:
             self._ckpt_thread.join()
         return params, opt_state, losses

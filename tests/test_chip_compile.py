@@ -4,9 +4,10 @@ Compiles (never runs) each kernel for a described ``v5e:2x2`` topology
 at gpt2-paper's real Muon bucket shapes — the §7 grid kernels (gram,
 matmul_add, sketch_chain) — and the §10 fused trio at a shape its VMEM
 model accepts, in fp32 and bf16, and checks that the Mosaic kernel is in
-the compiled program.  What the chip's compiler refuses (unaligned
-slices, too much VMEM) fails here at no chip time; results and times
-need the chip (``chip_smoke.py``).
+the compiled program; and the model's fused causal-attention kernel,
+forward and backward, at gpt2-paper's attention shape.  What the chip's
+compiler refuses (unaligned slices, too much VMEM) fails here at no chip
+time; results and times need the chip (``chip_smoke.py``).
 
 The topology is described inside a fixture, never at import: only one
 process may hold the TPU library, and under pytest-xdist every worker
@@ -24,7 +25,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.configs import get_config
 from repro.core import polynomials as poly
 from repro.kernels import fused_iter, gram, matmul_add, ops, sketch_traces
-from repro.models import build
+from repro.models import attention, build
 from repro.optim import bucketing
 from repro.optim.muon import matrix_view_shapes
 
@@ -136,3 +137,25 @@ def test_fused_shape_is_largest_fp32_fit():
     _, m, n = FUSED_SHAPE
     assert ops.fused_fits((m, n), np.float32)
     assert not ops.fused_fits((m + 128, n + 128), np.float32)
+
+
+def test_attention_kernel_compiles(one_chip):
+    """The fused attention's forward and backward at gpt2-paper's
+    [B, S, H, Hd] = [8, 1024, 16, 64] in bf16, blocks as committed."""
+    B, H, S, Hd = 8, 16, 1024, 64
+    assert S % attention.KERNEL_BLOCK == 0 and \
+        Hd in attention.KERNEL_HEAD_DIMS
+    x = _spec((B, S, H, Hd), "bfloat16", one_chip)
+
+    def fwd_bwd(q, k, v, dout):
+        out, vjp = jax.vjp(attention._kernel_attend, q, k, v)
+        return (out,) + vjp(dout)
+
+    compiled = jax.jit(fwd_bwd).lower(x, x, x, x).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    print(f"attention kernel fwd+bwd temporaries: {temp} bytes")
+    # q, k, v, dout and the kernel's operands in heads-first layout, the
+    # output and its three cotangents: a few [B, S, H, Hd] bf16 arrays,
+    # never an [S, S] score matrix (512 MiB in float32 at this shape)
+    assert temp < 16 * B * S * H * Hd * 2

@@ -121,6 +121,23 @@ def test_compiles_are_counted_by_step(tmp_path):
     assert counts[0] >= 1 and counts[3] == 1
 
 
+def test_attention_path_is_counted_when_the_step_traces(tmp_path):
+    tr = _trainer(tmp_path, steps=3)
+    assert "attention_path" not in tr.counters
+    tr.run()
+    # one trace of the step: the CPU takes the jnp chunk loop in every
+    # layer scan it stages (forward, remat and backward share one trace)
+    paths = tr.counters["attention_path"]
+    assert set(paths) == {"chunked"} and paths["chunked"] >= 1
+    params, opt_state, _ = tr.init_state()
+    text = _compiled_text(tr, (params, opt_state,
+                               tr.batch_fn(jnp.asarray(0)), jnp.int32(0),
+                               None))
+    dots = re.findall(r' dot\(.*op_name="([^"]*)"', text)
+    assert any("/jvp(layers)/" in n and "/attn_chunked/" in n for n in dots)
+    assert not any("/attn_kernel/" in n for n in dots)
+
+
 def _sleeping_step(slow_at, base=0.02, slow=0.4):
     import time
 
