@@ -41,8 +41,7 @@ def main(argv=None) -> int:
     harness.enable_compile_cache(jax)
     drv = harness.load_module(cell["driver"])
     from chipbench import traffic_gen
-    from chipbench.reference import muon as ref_muon
-    from chipbench.reference import transformer as ref_model
+    from chipbench.reference import controls
 
     with tempfile.TemporaryDirectory() as tmp:
         tc = drv.TrainCell(cell, tmp)
@@ -64,9 +63,9 @@ def main(argv=None) -> int:
                               "reference": ref["loss"]}}
             if i < args.faults:
                 row["control_model"] = judged(tc.reference(
-                    key, batches, q8=ref_model.fp8_round))
+                    key, batches, q8=controls.fp8_round))
                 row["control_chain"] = judged(tc.reference(
-                    key, batches, chain_q=ref_muon.bf16_round))
+                    key, batches, chain_q=controls.bf16_round))
                 row["fault_half_batch"] = judged(tc.reference(
                     key, batches, half_batch=True))
             if i == 0:

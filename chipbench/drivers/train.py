@@ -10,10 +10,13 @@ own step is wrapped for those three only, to take their readings).
 Then the window runs for ``--seconds``; only steps whose loss reached
 the host count.
 With ``--trace 1`` the window is followed by ``TRACED_STEPS`` steps under
-the profiler and by standalone calls of the model's loss and gradient,
-the optimizer update and the bucketed polar entry, each traced on its
-own.  After the loop has ended and its state is freed, the reference
-follows the same three steps and the run is judged against it.
+the profiler; the per-layer readers read that trace through the scopes
+of the compiled step that ran, the program's host spans, and counts
+taken on the step's shapes.  After the loop has ended and its state is
+freed, the reference follows the same three steps and the run is judged
+against it.  The reference is the configuration's model family's
+(``reference/<family>.py``, found by ``harness.resolve``): the driver
+knows no family by name.
 """
 from __future__ import annotations
 
@@ -28,14 +31,13 @@ from typing import Callable, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from chipbench import flops, harness, program, trace, traffic_gen
+from chipbench import harness, program, step_trace, trace, traffic_gen
+from chipbench.reference import controls
 from chipbench.reference import muon as ref_muon
-from chipbench.reference import transformer as ref_model
 
 WARM_STEPS = 4      # steps 0-2 are checked; step 3 runs on warm programs
 CHECKED_STEPS = 3
 TRACED_STEPS = 4
-PROBE_CALLS = 3
 # a leaf whose reference gradient is below this share of the median
 # leaf's moves by round-off alone (a key bias under softmax)
 MOVING_SHARE = 1e-3
@@ -49,10 +51,8 @@ class _WindowClosed(Exception):
 
 def leaf_norms(tree) -> Dict[str, jax.Array]:
     """Frobenius norm of every leaf, keyed by its path."""
-    flat = jax.tree_util.tree_flatten_with_path(tree)[0]
-    return {"/".join(str(k.key) for k in path):
-            jnp.sqrt(jnp.sum(jnp.square(x.astype(jnp.float32))))
-            for path, x in flat}
+    return {path: jnp.sqrt(jnp.sum(jnp.square(x.astype(jnp.float32))))
+            for path, x in ref_muon.leaf_paths(tree).items()}
 
 
 def _floats(d: Dict) -> Dict[str, float]:
@@ -65,15 +65,16 @@ class TrainCell:
     def __init__(self, cell: Dict, ckpt_dir: str):
         c, traffic = cell["config"], cell["traffic"]
         self.c, self.traffic = c, traffic
+        self.ref = ref = harness.load_module(cell["reference"])
         self.trainer = program.trainer_for(c, traffic, ckpt_dir)
         self.tokens_per_step = traffic["seq"] * traffic["batch"]
         prog_shapes = self.trainer.model.param_shapes()
-        ref_shapes = ref_model.param_shapes(c)
+        ref_shapes = ref.param_shapes(c)
         self.hparams = dict(traffic["optimizer"])
         beta1 = self.hparams["beta1"]
 
         def weights(key):
-            return program.to_program(ref_model.init(key, c), prog_shapes)
+            return program.to_program(ref.init(key, c), prog_shapes)
 
         self.weights = jax.jit(weights)
         self.opt_init = jax.jit(self.trainer.opt.init)
@@ -82,13 +83,28 @@ class TrainCell:
         self.delta_norms = jax.jit(lambda p, key: leaf_norms(
             program.from_program(jax.tree.map(jnp.subtract, p, weights(key)),
                                  ref_shapes)))
-        self.muon = muon_leaves(ref_shapes)
-        self.pick = jax.jit(lambda tree, layer: pick_layer(
-            program.from_program(tree, ref_shapes), self.muon, layer))
-        self.pick_grads = jax.jit(lambda s, layer: pick_layer(
+        self.muon = ref_muon.muon_leaves(ref_shapes, ref.muon_views(c))
+        flat = ref_muon.leaf_paths(ref_shapes)
+        self.muon_shapes = {path: flat[path] for path in self.muon}
+        self.pick = jax.jit(lambda tree, layers: pick_layer(
+            program.from_program(tree, ref_shapes), layers))
+        self.pick_grads = jax.jit(lambda s, layers: pick_layer(
             program.from_program(program.gradients_from_state(s, beta1),
-                                 ref_shapes), self.muon, layer))
+                                 ref_shapes), layers))
         self._step = self.trainer.step_fn
+
+    def layers(self, key) -> Dict[str, int]:
+        """The layer of each Muon leaf that the chain check reads."""
+        layers = sampled_layers(key, self.muon_shapes)
+        check_layers(layers, self.muon_shapes)
+        return layers
+
+    def step_shapes(self, tokens: jax.Array) -> Tuple:
+        """The shapes of the trainer step's parameters, optimizer state
+        and batch, as ``jax.eval_shape`` gives them."""
+        params = jax.eval_shape(self.weights, jax.random.PRNGKey(0))
+        return (params, jax.eval_shape(self.opt_init, params),
+                {"tokens": jax.ShapeDtypeStruct(tokens.shape, tokens.dtype)})
 
     # ---------------------------------------------------------- program
 
@@ -104,9 +120,9 @@ class TrainCell:
         if n == 0:
             self._got["grad"] = self.grad_norms(opt_state)
             self._got["samples"]["p1"] = jax.device_get(
-                self.pick(params, self._layer))
+                self.pick(params, self._layers))
             self._got["samples"]["g"] = jax.device_get(
-                self.pick_grads(opt_state, self._layer))
+                self.pick_grads(opt_state, self._layers))
         elif n == CHECKED_STEPS - 1:
             self._got["delta"] = self.delta_norms(params, self._wkey)
             self.trainer.step_fn = self._step
@@ -119,11 +135,11 @@ class TrainCell:
         ``on_step(t, loss)`` raises ``_WindowClosed`` or ``steps`` steps
         have run.  Returns the checked readings of the first steps."""
         wkey = jax.random.fold_in(key, 0)
-        layer = sampled_layer(key, self.c)
+        layers = self.layers(key)
         first = [self.weights(wkey)]
         first.append(self.opt_init(first[0]))
         self._got = {"samples": {"p0": jax.device_get(
-            self.pick(first[0], layer))}}
+            self.pick(first[0], layers))}}
 
         def init_state(seed):
             params, state = first
@@ -140,7 +156,7 @@ class TrainCell:
 
         self.trainer.batch_fn = batch_fn
         self.trainer.step_fn = self._checked_step
-        self._calls, self._wkey, self._layer = 0, wkey, layer
+        self._calls, self._wkey, self._layers = 0, wkey, layers
         losses: List[float] = []
 
         def on_metrics(t, metrics):
@@ -164,96 +180,96 @@ class TrainCell:
     # -------------------------------------------------------- reference
 
     def reference(self, key, batches: List[jax.Array],
-                  q8: Callable = ref_model._identity,
-                  chain_q: Callable = ref_model._identity,
+                  q8: Callable = controls.identity,
+                  chain_q: Callable = controls.identity,
                   half_batch: bool = False) -> Dict:
         """The reference's readings of the first ``CHECKED_STEPS`` steps
-        from the same weights and batches, with the same layer sampled
+        from the same weights and batches, with the same layers sampled
         as the program's.  ``q8`` (the model's products), ``chain_q``
         (the polar chain's products) and ``half_batch`` plant the
         control's precision or a fault in its place."""
-        c, h = self.c, self.hparams
+        c, h, ref, muon = self.c, self.hparams, self.ref, self.muon
         wkey = jax.random.fold_in(key, 0)
-        layer = sampled_layer(key, c)
+        layers = self.layers(key)
 
-        def step(p, s, tok):
+        def step(p, s, tok, layers):
             if half_batch:
                 tok = tok[: tok.shape[0] // 2]
-            loss, g = ref_model.loss_and_grad(
+            loss, g = ref.loss_and_grad(
                 p, tok, c, q8, min(REF_BLOCK_ROWS, tok.shape[0]))
-            p0 = pick_layer(p, self.muon, layer)
+            p0 = pick_layer(p, layers)
             with jax.default_matmul_precision("highest"):
-                p, s, used = ref_muon.update(g, s, p, h, chain_q)
-            samples = {"p0": p0, "p1": pick_layer(p, self.muon, layer),
-                       "g": pick_layer(used, self.muon, layer)}
+                p, s, used = ref_muon.update(g, s, p, h, muon, chain_q)
+            samples = {"p0": p0, "p1": pick_layer(p, layers),
+                       "g": pick_layer(used, layers)}
             return p, s, loss, leaf_norms(used), samples
 
         step = jax.jit(step, donate_argnums=(0, 1))
-        p = jax.jit(lambda k: ref_model.init(k, c))(wkey)
-        s = jax.jit(ref_muon.init_state)(p)
+        p = jax.jit(lambda k: ref.init(k, c))(wkey)
+        s = jax.jit(lambda p: ref_muon.init_state(p, muon))(p)
         losses, grad, samples = [], None, None
         for t in range(CHECKED_STEPS):
-            p, s, loss, gn, sm = step(p, s, batches[t % len(batches)])
+            p, s, loss, gn, sm = step(p, s, batches[t % len(batches)],
+                                      layers)
             losses.append(float(loss))
             if t == 0:
                 grad, samples = _floats(gn), jax.device_get(sm)
         delta = jax.jit(lambda p, k: leaf_norms(jax.tree.map(
-            jnp.subtract, p, ref_model.init(k, c))))(p, wkey)
+            jnp.subtract, p, ref.init(k, c))))(p, wkey)
         return {"loss": losses, "grad": grad, "delta": _floats(delta),
                 "samples": samples}
 
     def chain_gaps(self, samples: Dict) -> Dict[str, float]:
-        """For each Muon leaf, by its sampled layer: the gap between the
-        parameters' first change and the one the reference's polar chain
-        makes from the same gradient, over the latter's norm."""
-        h = self.hparams
-        lr, wd, mu = h["learning_rate"], h["weight_decay"], h["momentum"]
-        out = {}
-        for path in self.muon:
-            name = path.rsplit("/", 1)[-1]
-            p0, p1, g = (jnp.asarray(samples[k][path])
-                         for k in ("p0", "p1", "g"))
-            out[path] = float(_chain_gap(name, p0, p1, g, lr, wd, mu,
-                                         tuple(sorted(h["prism"].items()))))
-        return out
+        return chain_gaps(samples, self.muon, self.hparams)
 
 
-def muon_leaves(ref_shapes: Dict) -> List[str]:
-    """Paths of the leaves the reference orthogonalizes."""
-    paths = jax.tree_util.tree_flatten_with_path(
-        ref_shapes, is_leaf=lambda s: isinstance(s, tuple))[0]
-    out = []
-    for path, shape in paths:
-        name = path[-1].key
-        if ref_muon.is_muon_leaf(name, shape):
-            out.append("/".join(str(k.key) for k in path))
-    return out
+def sampled_layers(key, shapes: Dict[str, tuple]) -> Dict[str, int]:
+    """The layer of each leaf (path -> shape, the layer axis first) whose
+    matrices the chain check reads, drawn from the seed within that
+    leaf's own layer axis: leaves of one depth share their draw."""
+    k = jax.random.fold_in(key, 2)
+    by_depth = {n: int(jax.random.randint(k, (), 0, n))
+                for n in sorted({s[0] for s in shapes.values()})}
+    return {path: by_depth[s[0]] for path, s in shapes.items()}
 
 
-def pick_layer(tree: Dict, paths: List[str], layer) -> Dict:
-    """One layer (kept as a leading axis of 1) of each leaf in ``paths``."""
+def check_layers(layers: Dict[str, int], shapes: Dict[str, tuple]) -> None:
+    """Raise unless every index lies on its leaf's layer axis: a slice
+    on the device would clamp it and read another layer."""
+    for path, i in layers.items():
+        if not 0 <= i < shapes[path][0]:
+            raise IndexError(f"layer {i} of {path} outside its "
+                             f"{shapes[path][0]} layers")
+
+
+def pick_layer(tree: Dict, layers: Dict[str, int]) -> Dict:
+    """One layer (kept as a leading axis of 1) of each leaf in
+    ``layers`` (path -> index, checked by ``check_layers``)."""
+    flat = ref_muon.leaf_paths(tree)
+    return {path: jax.lax.dynamic_slice_in_dim(
+        flat[path], i, 1, 0).astype(jnp.float32)
+        for path, i in layers.items()}
+
+
+def chain_gaps(samples: Dict, views: Dict, h: Dict) -> Dict[str, float]:
+    """For each Muon leaf of ``views``, by its sampled layer: the gap
+    between the parameters' first change and the one the reference's
+    polar chain makes from the same gradient, over the latter's norm."""
+    lr, wd, mu = h["learning_rate"], h["weight_decay"], h["momentum"]
+    prism = tuple(sorted(h["prism"].items()))
     out = {}
-    for path in paths:
-        x = tree
-        for k in path.split("/"):
-            x = x[k]
-        out[path] = jax.lax.dynamic_slice_in_dim(
-            x, layer, 1, 0).astype(jnp.float32)
+    for path, view in views.items():
+        p0, p1, g = (jnp.asarray(samples[k][path])
+                     for k in ("p0", "p1", "g"))
+        out[path] = float(_chain_gap(view, p0, p1, g, lr, wd, mu, prism))
     return out
-
-
-def sampled_layer(key, c: Dict) -> int:
-    """The layer whose Muon leaves the chain check reads, drawn from the
-    seed."""
-    return int(jax.random.randint(jax.random.fold_in(key, 2), (), 0,
-                                  c["num_hidden_layers"]))
 
 
 @functools.partial(jax.jit, static_argnums=(0, 7))
-def _chain_gap(name, p0, p1, g, lr, wd, mu, prism_items):
+def _chain_gap(view, p0, p1, g, lr, wd, mu, prism_items):
     prism = dict(prism_items)
     u = g + mu * g  # the first step: momentum is the gradient itself
-    want = -lr * (wd * p0 + ref_muon.direction(name, u, prism))
+    want = -lr * (wd * p0 + ref_muon.direction(view, u, prism))
     return (jnp.sqrt(jnp.sum(jnp.square((p1 - p0) - want)))
             / jnp.maximum(jnp.sqrt(jnp.sum(jnp.square(want))), 1e-30))
 
@@ -281,79 +297,25 @@ def compare(got: Dict, ref: Dict) -> Dict[str, float]:
             "chain_gap": max(got["chain"].values())}
 
 
-# ------------------------------------------------------------- probes
+# ------------------------------------------------------------ readers
 
 
-def _device_ms(fn: Callable, args_fn: Callable, logdir: str) -> float:
-    """Device time of one call of ``fn``: the busy time of the device
-    over ``PROBE_CALLS`` traced calls, divided by their number.  The
-    call is warmed first; ``args_fn(out)`` gives the next call's
-    arguments from the last output (None: the first call's)."""
-    args = args_fn(None)
-    out = jax.block_until_ready(fn(*args))
-    jax.profiler.start_trace(logdir)
-    for _ in range(PROBE_CALLS):
-        args = args_fn(out)
-        out = jax.block_until_ready(fn(*args))
-    jax.profiler.stop_trace()
-    ops = trace.extract(logdir)["devices"][0]
-    lo = min(s for _, s, _ in ops)
-    hi = max(s + d for _, s, d in ops)
-    return trace.busy_ns(ops, lo, hi) * 1e-6 / PROBE_CALLS
-
-
-def probe_layers(tc: TrainCell, key, tokens, logdir: str) -> Dict:
-    """Standalone device times of the model's loss and gradient, the
-    optimizer update and the bucketed polar entry Muon's update calls,
-    on the cell's weights, state and one batch."""
-    from repro.optim import base, bucketing
-
-    tr = tc.trainer
-    model, opt, ocfg = tr.model, tr.opt, tr.ocfg
-    dtypes = model.param_dtypes()
-    batch = {"tokens": tokens}
-
-    def loss_fn(p):
-        pc = jax.tree.map(lambda x, dt: x.astype(dt), p, dtypes)
-        return model.loss(pc, batch)
-
-    fwd_bwd = jax.jit(lambda p: jax.value_and_grad(loss_fn, has_aux=True)(p))
-    params = tc.weights(jax.random.fold_in(key, 0))
-    fb_ms = _device_ms(fwd_bwd, lambda out: (params,), logdir + "/fwd_bwd")
-    (_, _), grads = fwd_bwd(params)
-
-    axes = jax.tree.leaves(model.logical_axes(),
-                           is_leaf=lambda t: isinstance(t, tuple))
-    views = [base.to_matrix_view(g, a)[0]
-             for g, a in zip(jax.tree.leaves(grads), axes)
-             if base.is_matrix_param(a, g.shape)]
-    pkey = jax.random.PRNGKey(0)
-    polar = jax.jit(lambda vs: bucketing.polar_bucketed(vs, ocfg, pkey))
-    polar_ms = _device_ms(polar, lambda out: (views,), logdir + "/polar")
-    shapes: Dict = {}
-    for v in views:
-        mn = tuple(v.shape[-2:])
-        shapes[mn] = shapes.get(mn, 0) + math.prod(v.shape[:-2])
-    del views
-
-    update = jax.jit(lambda g, s, p: opt.update(g, s, p, jnp.int32(0), pkey),
-                     donate_argnums=(1, 2))
-    state = tc.opt_init(params)
-    carry = {"p": params, "s": state}
-    del params, state
-
-    def upd_args(out):
-        if out is not None:
-            carry["p"], carry["s"] = out
-        return grads, carry["s"], carry["p"]
-
-    opt_ms = _device_ms(update, upd_args, logdir + "/optimizer")
-    pc = ocfg.resolved_prism
-    return {"fwd_bwd_ms": fb_ms, "optimizer_ms": opt_ms,
-            "polar_ms": polar_ms,
-            "polar_buckets": [(n, mn) for mn, n in sorted(shapes.items())],
-            "polar_iters": pc.iterations,
-            "polar_degree": pc.degree, "polar_dtype": pc.dtype}
+def layer_context(tc: TrainCell, rec: Dict, window_ns: Tuple[float, float],
+                  steps_per_s: float, peaks: Optional[Dict], chips: int,
+                  shapes: Tuple) -> Dict:
+    """What the per-layer readers read: the traced window's record (with
+    the program's scopes and host spans, ``step_trace.extract``), the
+    untraced window's rate, the step's FLOPs by the family's reference,
+    and, on the step's ``shapes`` (``TrainCell.step_shapes``), its
+    Pallas launches and polar chains."""
+    params, state, batch = shapes
+    return dict(program.polar_plan(tc.trainer, params), trace=rec,
+                window_ns=window_ns, peaks=peaks, chips=chips,
+                steps_per_s=steps_per_s,
+                step_flops=tc.ref.train_step_flops(
+                    tc.c, tc.traffic["seq"], tc.traffic["batch"]),
+                matfn_launches=program.step_launches(
+                    tc.trainer, params, state, batch))
 
 
 # ---------------------------------------------------------------- run
@@ -373,7 +335,7 @@ def run(cell: Dict, *, seed: int, seconds: float, traced: bool, t0: float,
         jax.block_until_ready(batches)
         marks["batches"] = time.perf_counter()
         clock = {"start": None, "end": None, "n": 0, "bad": 0,
-                 "traced": 0}
+                 "traced": 0, "ends": []}
 
         def on_step(t, loss):
             now = time.perf_counter()
@@ -386,6 +348,7 @@ def run(cell: Dict, *, seed: int, seconds: float, traced: bool, t0: float,
                 return
             if clock["end"] is None:
                 clock["n"] += 1
+                clock["ends"].append(now)
                 clock["bad"] += not math.isfinite(loss)
                 if now - clock["start"] >= seconds:
                     clock["end"] = now
@@ -413,18 +376,22 @@ def run(cell: Dict, *, seed: int, seconds: float, traced: bool, t0: float,
             ops = rec["devices"][0]
             busy = [trace.busy_ns(o, lo, hi)
                     for o in rec["devices"][:len(devs)]]
-            probes = probe_layers(tc, key, batches[0], tmp + "/probes")
-            ctx = dict(probes, trace=rec, window_ns=(lo, hi), peaks=peaks,
-                       steps_per_s=clock["n"] / window,
-                       step_flops=flops.train_step_flops(
-                           tc.c, tc.traffic["seq"], tc.traffic["batch"]),
-                       chips=len(devs))
+            shapes = tc.step_shapes(batches[0])
+            rec.update(step_trace.extract(
+                tmp + "/steps", program.step_text(tc.trainer, *shapes)))
+            ctx = layer_context(tc, rec, (lo, hi), clock["n"] / window,
+                                peaks, len(devs), shapes)
             metrics = {}
             for m in cell["per_layer"]:
                 reader = harness.load_module(cell["readers"][m["name"]])
                 value = reader.read(ctx)
                 if value is not None:
                     metrics[m["name"]] = {"value": value, "unit": m["unit"]}
+            compiles = sum(n for t, n in
+                           tc.trainer.counters["compiles_by_step"].items()
+                           if t >= WARM_STEPS)
+            print(step_trace.summary(rec, lo, hi, len(devs), compiles),
+                  file=sys.stderr)
             result["device"].update(
                 busy_s=sum(busy) / len(busy) * 1e-9,
                 window_s=(hi - lo) * 1e-9)
@@ -444,9 +411,12 @@ def run(cell: Dict, *, seed: int, seconds: float, traced: bool, t0: float,
         for name, at in marks.items():
             phases.append(f"{name} {at - last:.3f}")
             last = at
-        print(f"window: {clock['n']} steps in {window:.6f} s, set-up "
-              f"{clock['start'] - t0:.6f} s ({', '.join(phases)})",
-              file=sys.stderr)
+        ends = [clock["start"]] + clock["ends"]
+        step_ms = sorted((b - a) * 1e3 for a, b in zip(ends, ends[1:]))
+        print(f"window: {clock['n']} steps in {window:.6f} s (step ms min "
+              f"{step_ms[0]:.3f}, median {statistics.median(step_ms):.3f}, "
+              f"max {step_ms[-1]:.3f}), set-up {clock['start'] - t0:.6f} s "
+              f"({', '.join(phases)})", file=sys.stderr)
         tr0 = time.perf_counter()
         ref = tc.reference(key, batches)
         got["chain"] = tc.chain_gaps(got["samples"])

@@ -1,9 +1,11 @@
-"""Operation and byte counts the per-layer metrics divide by.
+"""Operation and byte counts of the polar chains, which the per-layer
+metrics divide by.
 
 Kept with the benchmark so that no change to the program can change
-what a roofline or utilization is measured against.  The polar counts
-follow the cubic Newton-Schulz family of the PRISM paper (arXiv
-2601.22137): per iteration on an [m, n] matrix with m >= n, the Gram
+what a roofline is measured against (a model's own FLOPs per step are
+its family's reference's ``train_step_flops``).  The counts follow
+the cubic Newton-Schulz family of the PRISM paper (arXiv 2601.22137):
+per iteration on an [m, n] matrix with m >= n, the Gram
 residual X^T X (2 m n^2 FLOPs), the (degree - 1) Horner products on the
 [n, n] side (2 n^3 each) and the [m, n] x [n, n] apply (2 m n^2).  The
 sketched trace chain is O(n^2 p) and left out, so the counts are a
@@ -11,38 +13,9 @@ floor of the work.
 """
 from __future__ import annotations
 
-from typing import Dict, Iterable, Tuple
+from typing import Iterable, Tuple
 
 import jax.numpy as jnp
-
-
-def matmul_params(c: Dict) -> int:
-    """Parameters that take part in a matrix product per token: every
-    layer's projections and MLP, and the LM head.  The embedding lookup
-    is a gather and is not counted."""
-    d, f, L = c["hidden_size"], c["intermediate_size"], c["num_hidden_layers"]
-    H, KV, hd = (c["num_attention_heads"], c["num_key_value_heads"],
-                 c["head_dim"])
-    attn = d * H * hd * 2 + d * KV * hd * 2
-    mlp = d * f * (3 if c["hidden_act"] == "silu" else 2)
-    return L * (attn + mlp) + d * c["vocab_size"]
-
-
-def layer_matrix_params(c: Dict) -> int:
-    """Matrix parameters of one decoder layer (projections and MLP)."""
-    return (matmul_params(dict(c, num_hidden_layers=1))
-            - c["hidden_size"] * c["vocab_size"])
-
-
-def train_step_flops(c: Dict, seq: int, batch: int) -> int:
-    """Model FLOPs of one forward and backward pass over [batch, seq]
-    tokens: 6 per matrix parameter per token, plus the attention score
-    and value products (12 L H hd seq per token, the full square the
-    program computes).  Recomputation and the optimizer are not counted."""
-    tokens = seq * batch
-    attn = 12 * c["num_hidden_layers"] * c["num_attention_heads"] \
-        * c["head_dim"] * seq
-    return tokens * (6 * matmul_params(c) + attn)
 
 
 def polar_flops(mshape: Tuple[int, ...], *, iters: int,

@@ -3,12 +3,14 @@ device and its peaks, the compile cache, and the result line.
 
 A cell is found by name alone.  ``BENCHMARK.json`` names its
 configuration and traffic; the configuration's file is the one the
-manifest gives, the traffic mix is ``traffic/<traffic>.json``, the loop
-that drives it is ``drivers/<driver>.py`` (the traffic file names the
-driver), the limits its outputs are judged by are
-``limits/<workload>.json``, and each per-layer metric is read by
-``metrics/<metric>.py``.  Adding a cell, a mix, a driver or a metric is
-adding files and manifest entries; nothing here changes.
+manifest gives, and names its model family (``"reference"``), whose
+plain reference is ``reference/<family>.py``; the traffic mix is
+``traffic/<traffic>.json``, the loop that drives it is
+``drivers/<driver>.py`` (the traffic file names the driver), the limits
+its outputs are judged by are ``limits/<workload>.json``, and each
+per-layer metric is read by ``metrics/<metric>.py``.  Adding a cell, a
+mix, a model family, a driver or a metric is adding files and manifest
+entries; nothing here changes.
 """
 from __future__ import annotations
 
@@ -70,9 +72,12 @@ def resolve(workload: str, manifest_path: str = MANIFEST,
                  if workload in m.get("workloads", [workload])]
     end_to_end = [m for m in man["end_to_end"]
                   if workload in m.get("workloads", [workload])]
+    config = read_json(os.path.join(root, centry["file"]))
     return {
         "workload": w,
-        "config": read_json(os.path.join(root, centry["file"])),
+        "config": config,
+        "reference": os.path.join(bench, "reference",
+                                  config["reference"] + ".py"),
         "traffic": traffic,
         "driver": os.path.join(bench, "drivers", traffic["driver"] + ".py"),
         "limits": read_json(os.path.join(bench, "limits",
@@ -109,10 +114,15 @@ def require_accelerator(jax, chips: int) -> List:
 def enable_compile_cache(jax) -> None:
     """The persistent compile cache at a fixed path inside the checkout,
     whatever ``JAX_COMPILATION_CACHE_DIR`` says: every program is kept,
-    so only a cell's first run in a checkout compiles."""
+    so only a cell's first run in a checkout compiles.  Its size is not
+    bounded, whatever ``JAX_COMPILATION_CACHE_MAX_SIZE`` says: a float32
+    reference that unrolls every layer of every block of rows is a
+    program of hundreds of MiB, which a bound would refuse to keep, and
+    it would compile anew in every run."""
     jax.config.update("jax_compilation_cache_dir", CACHE_DIR)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    jax.config.update("jax_compilation_cache_max_size", -1)
 
 
 def device_record(devs, peak_bytes: int) -> Dict:

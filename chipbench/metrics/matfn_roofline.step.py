@@ -1,7 +1,10 @@
-"""Least time the chip could take for the cell's bucketed polar chains,
-counted exactly as ``matfn_roofline`` counts it, over the device time
-per traced training step under the ``polar`` scope
-(``optim/bucketing.py::polar_bucketed``) of the step the trainer runs."""
+"""Least time the chip could take for one step's bucketed polar chains
+(per bucket, the larger of its FLOPs over the bf16 peak and its bytes
+over the HBM bandwidth, counted by ``chipbench/flops.py`` for the
+optimizer's matrix views of the trainer's parameter shapes, with its own
+iteration count and dtype), over the device time per traced training
+step under the ``polar`` scope (``optim/bucketing.py::polar_bucketed``)
+of the step the trainer runs."""
 import sys
 
 from chipbench import flops, step_trace

@@ -1,6 +1,7 @@
 """Model FLOPs of the training step (forward and backward, counted from
-the configuration by ``chipbench/flops.py``) times the steps per second
-of the traced run's untraced window, over the bf16 peak of the chips."""
+the configuration by its family's reference, ``train_step_flops``) times
+the steps per second of the traced run's untraced window, over the bf16
+peak of the chips."""
 
 
 def read(ctx):

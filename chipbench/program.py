@@ -1,18 +1,21 @@
 """The benchmark's one contact with the system under test.
 
 Everything the harness takes from the program goes through here: the
-model configuration built from a configuration file, the trainer that
+model configuration built from a configuration file (through its
+family's translation, ``translations/``), the trainer that
 ``launch/train.py::build_trainer`` returns, the layout of its parameter
-tree, and what its optimizer state holds after a step.  The reference
-(``reference/``) imports none of it.
+tree, what its optimizer state holds after a step, and, on the step's
+shapes, its compiled text, its Pallas launches and its polar chains.
+The reference (``reference/``) imports none of it.
 """
 from __future__ import annotations
 
+import math
 import os
 import sys
 from typing import Dict
 
-from chipbench.harness import ROOT
+from chipbench.harness import HERE, ROOT, load_module
 
 sys.path.insert(0, os.path.join(ROOT, "src"))
 
@@ -22,26 +25,25 @@ import jax.numpy as jnp  # noqa: E402
 from repro.configs import get_config  # noqa: E402
 from repro.launch.train import build_trainer  # noqa: E402
 
-# configuration-file key -> ModelConfig field
-_FIELDS = {"hidden_size": "d_model", "intermediate_size": "d_ff",
-           "num_attention_heads": "num_heads",
-           "num_key_value_heads": "num_kv_heads", "head_dim": "head_dim",
-           "num_hidden_layers": "num_layers", "vocab_size": "vocab_size",
-           "rope_theta": "rope_theta", "rms_norm_eps": "norm_eps",
-           "tie_word_embeddings": "tie_embeddings", "qk_norm": "qk_norm",
-           "attention_bias": "qkv_bias"}
-_ACT = {"silu": "silu", "gelu_new": "gelu"}
-_DTYPE = {"bfloat16": "bfloat16", "float32": "float32"}
+# keys of a configuration file that describe the file, not the model
+DESCRIPTIVE = ("name", "source", "arch", "reduced", "assumed", "deployment",
+               "reference")
 
 
 def model_config(c: Dict):
     """The program's ModelConfig for configuration file ``c``: the
-    registry entry ``c["arch"]`` with every size the file states."""
-    kw = {field: c[key] for key, field in _FIELDS.items()}
-    kw.update(name=c["name"], mlp_act=_ACT[c["hidden_act"]],
-              dtype=_DTYPE[c["torch_dtype"]],
-              emb_dtype=_DTYPE[c["torch_dtype"]])
-    return get_config(c["arch"]).replace(**kw)
+    registry entry ``c["arch"]`` with every size the file states, as its
+    family's translation (``translations/<family>.py``, the family being
+    ``c["reference"]``) sets them.  A key that the translation does not
+    take, and that does not describe the file, raises: the program would
+    build another model than the reference computes."""
+    t = load_module(os.path.join(HERE, "translations",
+                                 c["reference"] + ".py"))
+    unknown = sorted(set(c) - t.KEYS - set(DESCRIPTIVE))
+    if unknown:
+        raise ValueError(f"configuration {c['name']!r}: the program takes "
+                         f"no key {unknown} of family {c['reference']!r}")
+    return get_config(c["arch"]).replace(name=c["name"], **t.fields(c))
 
 
 def trainer_for(c: Dict, traffic: Dict, ckpt_dir: str):
@@ -114,3 +116,44 @@ def gradients_from_state(opt_state: Dict, beta1: float) -> Dict:
         return slot["mom"]
     return jax.tree.map(grad, opt_state["leaves"],
                         is_leaf=lambda s: isinstance(s, dict) and "mom" in s)
+
+
+def step_text(trainer, params, opt_state, batch) -> str:
+    """The text of the compiled step that ``trainer`` runs, compiled on
+    the shapes of its arguments (``jax.eval_shape``'s): the program the
+    window ran, found in the persistent compile cache.  It holds every
+    operation's scope, which a TPU's trace events do not."""
+    return trainer.step_fn.lower(
+        params, opt_state, batch, jax.ShapeDtypeStruct((), jnp.int32),
+        None).compile().as_text()
+
+
+def step_launches(trainer, params, opt_state, batch) -> int:
+    """Pallas launches of one step of ``trainer``, counted by tracing its
+    unjitted step abstractly on the shapes of its arguments."""
+    from repro.kernels import ops
+
+    return ops.count_launches(trainer.raw_step_fn, params, opt_state, batch,
+                              jnp.int32(0))
+
+
+def polar_plan(trainer, params) -> Dict:
+    """The polar chains of one step of ``trainer``, from the shapes of
+    its parameters: the optimizer's matrix views grouped by shape,
+    ``[(count, (m, n)), ...]``, and the chain's iteration count, degree
+    and dtype."""
+    from repro.optim import base
+
+    axes = jax.tree.leaves(trainer.model.logical_axes(),
+                           is_leaf=lambda t: isinstance(t, tuple))
+    counts: Dict = {}
+    for p, a in zip(jax.tree.leaves(params), axes):
+        if base.is_matrix_param(a, p.shape):
+            view = jax.eval_shape(lambda x, a=a: base.to_matrix_view(x, a)[0],
+                                  p)
+            mn = tuple(view.shape[-2:])
+            counts[mn] = counts.get(mn, 0) + math.prod(view.shape[:-2])
+    pc = trainer.ocfg.resolved_prism
+    return {"polar_buckets": [(n, mn) for mn, n in sorted(counts.items())],
+            "polar_iters": pc.iterations, "polar_degree": pc.degree,
+            "polar_dtype": pc.dtype}

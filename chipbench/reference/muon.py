@@ -3,12 +3,13 @@
 Muon as the PRISM paper trains with it (arXiv 2601.22137, Sec. 6.2):
 
 * gradients are clipped to a global norm of ``grad_clip_norm``;
-* hidden weight matrices (attention q/k/v/o, their biases when they are
-  [heads, head_dim] matrices of at least 16 x 16, the MLP) take
-  Nesterov momentum ``m = mu m + g``, ``u = g + mu m``; the update is
+* the leaves the model family's reference names in ``muon_views`` (its
+  hidden weight matrices, each at least 16 x 16) take Nesterov momentum
+  ``m = mu m + g``, ``u = g + mu m``; the update is
   ``polar(U) * sqrt(max(1, rows / cols))``, where ``U`` is ``u`` viewed
-  as a matrix with the model width as its rows (a bias: heads as rows),
-  and the parameter moves by ``-lr (update + weight_decay p)``;
+  as a stack of matrices by the leaf's view (its leading batch axes
+  index the matrices, its row axes form their rows), and the parameter
+  moves by ``-lr (update + weight_decay p)``;
 * every other leaf (embedding, LM head, norm offsets) takes AdamW at
   ``lr * adamw_lr_scale`` with bias-corrected moments.
 
@@ -23,55 +24,58 @@ nothing of the system under test.
 from __future__ import annotations
 
 import math
-from typing import Callable, Dict
+from typing import Callable, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
 
-# matrix leaves -> (axes of the leaf, without the layer axis, that form
-# the rows of the matrix view)
-_MATRIX_ROWS = {"wq": (0,), "wk": (0,), "wv": (0,), "wo": (2,),
-                "w_up": (0,), "w_gate": (0,), "w_down": (1,),
-                "bq": (0,), "bk": (0,), "bv": (0,)}
+from chipbench.reference.controls import identity
+
+# a leaf's matrix view: (leading axes that index separate matrices, axes
+# after them that form each matrix's rows)
+View = Tuple[int, Tuple[int, ...]]
 
 
-def _identity(x):
-    return x
+def leaf_paths(tree) -> Dict[str, object]:
+    """Every leaf of a nested dict (of arrays, or of shape tuples) by its
+    path, ``"layers/attn/wq"``."""
+    flat = jax.tree_util.tree_flatten_with_path(
+        tree, is_leaf=lambda s: isinstance(s, tuple))[0]
+    return {"/".join(str(k.key) for k in path): x for path, x in flat}
 
 
-def is_muon_leaf(name: str, shape) -> bool:
-    if name not in _MATRIX_ROWS:
-        return False
-    rows, cols = matrix_dims(name, shape)
-    return min(rows, cols) >= 16
+def matrix_dims(view: View, shape) -> Tuple[int, int]:
+    """(rows, cols) of each matrix of a leaf."""
+    batch, rows_ax = view
+    per = shape[batch:]
+    rows = math.prod(per[a] for a in rows_ax)
+    return rows, math.prod(per) // rows
 
 
-def matrix_dims(name: str, shape):
-    """(rows, cols) of the matrix view of one layer's slice of a leaf."""
-    per_layer = shape[1:]
-    rows_ax = _MATRIX_ROWS[name]
-    rows = 1
-    for a in rows_ax:
-        rows *= per_layer[a]
-    cols = 1
-    for a, n in enumerate(per_layer):
-        if a not in rows_ax:
-            cols *= n
-    return rows, cols
+def muon_leaves(shapes: Dict, views: Dict[str, View]) -> Dict[str, View]:
+    """The leaves of ``shapes`` (a parameter tree's leaf shapes) that Muon
+    orthogonalizes, by path: those ``views`` names whose matrices are at
+    least 16 x 16."""
+    flat = leaf_paths(shapes)
+    return {path: view for path, view in views.items()
+            if min(matrix_dims(view, flat[path])) >= 16}
 
 
-def _to_matrix(name: str, x: jax.Array) -> jax.Array:
-    rows_ax = [a + 1 for a in _MATRIX_ROWS[name]]
-    rest = [a for a in range(1, x.ndim) if a not in rows_ax]
-    y = jnp.transpose(x, [0] + rows_ax + rest)
-    r, c = matrix_dims(name, x.shape)
-    return y.reshape(x.shape[0], r, c), y.shape
+def _perm(view: View, ndim: int):
+    batch, rows_ax = view
+    rows = [batch + a for a in rows_ax]
+    rest = [a for a in range(batch, ndim) if a not in rows]
+    return list(range(batch)) + rows + rest
 
 
-def _from_matrix(name: str, m: jax.Array, mid_shape, shape) -> jax.Array:
-    rows_ax = [a + 1 for a in _MATRIX_ROWS[name]]
-    rest = [a for a in range(1, len(shape)) if a not in rows_ax]
-    perm = [0] + rows_ax + rest
+def _to_matrix(view: View, x: jax.Array):
+    y = jnp.transpose(x, _perm(view, x.ndim))
+    r, c = matrix_dims(view, x.shape)
+    return y.reshape(x.shape[:view[0]] + (r, c)), y.shape
+
+
+def _from_matrix(view: View, m: jax.Array, mid_shape, shape) -> jax.Array:
+    perm = _perm(view, len(shape))
     inv = [perm.index(a) for a in range(len(shape))]
     return jnp.transpose(m.reshape(mid_shape), inv)
 
@@ -80,7 +84,7 @@ def _from_matrix(name: str, m: jax.Array, mid_shape, shape) -> jax.Array:
 ALPHA_BOUNDS = {1: (0.5, 1.0), 2: (3.0 / 8.0, 29.0 / 20.0)}
 
 
-def polar(M: jax.Array, prism: Dict, q: Callable = _identity) -> jax.Array:
+def polar(M: jax.Array, prism: Dict, q: Callable = identity) -> jax.Array:
     """The configured polar iteration on every [..., m, n] slice, in
     float32 at the highest matmul precision: the slice (taken with its
     long side as rows) is divided by its Frobenius norm, then each of
@@ -113,58 +117,50 @@ def polar(M: jax.Array, prism: Dict, q: Callable = _identity) -> jax.Array:
     return jnp.swapaxes(X, -1, -2) if m < n else X
 
 
-def bf16_round(x: jax.Array) -> jax.Array:
-    """Round trip through bfloat16: the control's precision, one step
-    below the float32 the chain is configured to run in."""
-    return x.astype(jnp.bfloat16).astype(jnp.float32)
-
-
-def direction(name: str, u: jax.Array, prism: Dict,
-              q: Callable = _identity) -> jax.Array:
+def direction(view: View, u: jax.Array, prism: Dict,
+              q: Callable = identity) -> jax.Array:
     """The orthogonalized direction of a Muon leaf: ``polar(U)`` scaled
     by ``sqrt(max(1, rows / cols))``, in the leaf's own layout."""
-    U, mid = _to_matrix(name, u)
+    U, mid = _to_matrix(view, u)
     rows, cols = U.shape[-2], U.shape[-1]
     O = polar(U, prism, q) * jnp.sqrt(jnp.maximum(1.0, rows / cols))
-    return _from_matrix(name, O, mid, u.shape)
+    return _from_matrix(view, O, mid, u.shape)
 
 
-def _named_leaves(tree):
-    paths, treedef = jax.tree_util.tree_flatten_with_path(tree)
-    return [(p[-1].key, x) for p, x in paths], treedef
-
-
-def init_state(params: Dict) -> Dict:
+def init_state(params: Dict, views: Dict[str, View]) -> Dict:
     """Zero momentum for every leaf; a second moment for AdamW leaves
-    (a Muon leaf keeps a scalar placeholder)."""
-    named, treedef = _named_leaves(params)
-    nu = [jnp.zeros((), jnp.float32) if is_muon_leaf(n, p.shape)
-          else jnp.zeros_like(p) for n, p in named]
+    (a Muon leaf of ``views`` keeps a scalar placeholder)."""
+    nu = {path: jnp.zeros((), jnp.float32) if path in views
+          else jnp.zeros_like(p) for path, p in leaf_paths(params).items()}
+    treedef = jax.tree.structure(params)
     return {"mom": jax.tree.map(jnp.zeros_like, params),
-            "nu": jax.tree.unflatten(treedef, nu),
+            "nu": jax.tree.unflatten(treedef, list(nu.values())),
             "count": jnp.zeros((), jnp.int32)}
 
 
 def update(grads: Dict, state: Dict, params: Dict, h: Dict,
-           q: Callable = _identity):
-    """One optimizer step; ``h`` holds the hyperparameters and ``q``
-    rounds the polar chain's operands.  Returns (new params, new state,
-    the clipped gradients the step used)."""
+           views: Dict[str, View], q: Callable = identity):
+    """One optimizer step; ``h`` holds the hyperparameters, ``views`` the
+    Muon leaves (``muon_leaves``) and ``q`` rounds the polar chain's
+    operands.  Returns (new params, new state, the clipped gradients the
+    step used)."""
     gn = jnp.sqrt(sum(jnp.sum(jnp.square(g)) for g in jax.tree.leaves(grads)))
     clip = jnp.minimum(1.0, h["grad_clip_norm"] / jnp.maximum(gn, 1e-12))
     grads = jax.tree.map(lambda g: g * clip, grads)
     t = (state["count"] + 1).astype(jnp.float32)
     lr, wd = h["learning_rate"], h["weight_decay"]
-    named_g, treedef = _named_leaves(grads)
+    treedef = jax.tree.structure(grads)
+    flat_g = leaf_paths(grads)
     flat_p = jax.tree.leaves(params)
     flat_m = jax.tree.leaves(state["mom"])
     flat_v = jax.tree.leaves(state["nu"])
     new_p, new_m, new_v = [], [], []
-    for (name, g), p, m, v in zip(named_g, flat_p, flat_m, flat_v):
-        if is_muon_leaf(name, p.shape):
+    for (path, g), p, m, v in zip(flat_g.items(), flat_p, flat_m, flat_v):
+        if path in views:
             m = h["momentum"] * m + g
             u = g + h["momentum"] * m
-            p = p * (1.0 - lr * wd) - lr * direction(name, u, h["prism"], q)
+            p = p * (1.0 - lr * wd) - lr * direction(views[path], u,
+                                                     h["prism"], q)
         else:
             b1, b2 = h["beta1"], h["beta2"]
             m = b1 * m + (1.0 - b1) * g

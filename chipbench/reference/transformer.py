@@ -36,9 +36,7 @@ from typing import Callable, Dict
 import jax
 import jax.numpy as jnp
 
-
-def _identity(x):
-    return x
+from chipbench.reference.controls import identity
 
 
 def param_shapes(c: Dict) -> Dict:
@@ -62,6 +60,50 @@ def param_shapes(c: Dict) -> Dict:
     if not c["tie_word_embeddings"]:
         out["head"] = (d, V)
     return out
+
+
+def muon_views(c: Dict) -> Dict:
+    """Muon's matrix view of each hidden matrix, layer by layer: the
+    model width as the rows (``wo``, ``w_down``: their output side), a
+    bias's heads as its rows."""
+    views = {"layers/attn/wq": (1, (0,)), "layers/attn/wk": (1, (0,)),
+             "layers/attn/wv": (1, (0,)), "layers/attn/wo": (1, (2,)),
+             "layers/mlp/w_up": (1, (0,)), "layers/mlp/w_down": (1, (1,))}
+    if c["attention_bias"]:
+        views.update({"layers/attn/" + b: (1, (0,))
+                      for b in ("bq", "bk", "bv")})
+    if c["hidden_act"] == "silu":
+        views["layers/mlp/w_gate"] = (1, (0,))
+    return views
+
+
+def matmul_params(c: Dict) -> int:
+    """Parameters that take part in a matrix product per token: every
+    layer's projections and MLP, and the LM head.  The embedding lookup
+    is a gather and is not counted."""
+    d, f, L = c["hidden_size"], c["intermediate_size"], c["num_hidden_layers"]
+    H, KV, hd = (c["num_attention_heads"], c["num_key_value_heads"],
+                 c["head_dim"])
+    attn = d * H * hd * 2 + d * KV * hd * 2
+    mlp = d * f * (3 if c["hidden_act"] == "silu" else 2)
+    return L * (attn + mlp) + d * c["vocab_size"]
+
+
+def layer_matrix_params(c: Dict) -> int:
+    """Matrix parameters of one decoder layer (projections and MLP)."""
+    return (matmul_params(dict(c, num_hidden_layers=1))
+            - c["hidden_size"] * c["vocab_size"])
+
+
+def train_step_flops(c: Dict, seq: int, batch: int) -> int:
+    """Model FLOPs of one forward and backward pass over [batch, seq]
+    tokens: 6 per matrix parameter per token, plus the attention score
+    and value products (12 L H hd seq per token, the full square the
+    program computes).  Recomputation and the optimizer are not counted."""
+    tokens = seq * batch
+    attn = 12 * c["num_hidden_layers"] * c["num_attention_heads"] \
+        * c["head_dim"] * seq
+    return tokens * (6 * matmul_params(c) + attn)
 
 
 # fan-in axis of each matrix leaf (negative, counted from the end)
@@ -152,7 +194,7 @@ def _layer(p, x, c, q8: Callable):
 
 
 def loss(params: Dict, tokens: jax.Array, c: Dict,
-         q8: Callable = _identity) -> jax.Array:
+         q8: Callable = identity) -> jax.Array:
     """Mean cross-entropy of predicting tokens[:, 1:] from tokens[:, :-1]
     context, over every position of the batch [B, S]."""
     x = params["embed"][tokens]
@@ -168,7 +210,7 @@ def loss(params: Dict, tokens: jax.Array, c: Dict,
 
 
 def loss_and_grad(params: Dict, tokens: jax.Array, c: Dict,
-                  q8: Callable = _identity, block_rows: int = 0):
+                  q8: Callable = identity, block_rows: int = 0):
     """The loss and its gradient; with ``block_rows`` the batch is taken
     in blocks of that many rows, one after the other, and their equal
     shares summed (every row has the same length), so that only one
@@ -186,10 +228,3 @@ def loss_and_grad(params: Dict, tokens: jax.Array, c: Dict,
             g = jax.tree.map(lambda x: x * (block / rows), g)
             grads = g if grads is None else jax.tree.map(jnp.add, grads, g)
     return total, grads
-
-
-def fp8_round(x: jax.Array) -> jax.Array:
-    """Per-tensor scaled round trip through float8_e4m3fn: the control's
-    precision, one step below the bfloat16 the model computes in."""
-    scale = jnp.maximum(jnp.max(jnp.abs(x)), 1e-30) / 448.0
-    return (x / scale).astype(jnp.float8_e4m3fn).astype(jnp.float32) * scale

@@ -30,7 +30,11 @@ SYNC_SPAN = "trainer.sync"
 STEP_SCOPES = ("fwd_bwd", "clip", "optimizer")
 UNSCOPED = "unscoped"
 
-_HLO_LINE = re.compile(r'^\s*(?:ROOT )?%?([\w.\-]+) = .*op_name="([^"]*)"')
+_HLO_INSTRUCTION = re.compile(r'^[ \t]*(?:ROOT )?%?([\w.\-]+) = ', re.M)
+_HLO_COMPUTATION = re.compile(r'^(?:ENTRY )?%?([\w.\-]+) [^\n]*\{\n(.*?)^\}$',
+                              re.M | re.S)
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_CALLS = re.compile(r'calls=%?([\w.\-]+)')
 _SEGMENT = re.compile(r"^(?:[\w]+\()*([^()]*)\)*$")
 
 
@@ -72,13 +76,41 @@ def extract(logdir: str, hlo_text: str) -> Dict:
 
 def hlo_scopes(text: str) -> Dict[str, str]:
     """Instruction name -> ``op_name`` for every instruction of an HLO
-    module's text that carries one."""
+    module's text that carries one.  An instruction may span lines: a
+    Pallas kernel's custom call, named by its kernel
+    (``%splash_mha_fwd_residuals.17``), writes its ``kernel_metadata``
+    over several, and its ``op_name`` comes after them.  A fusion that
+    carries none (XLA drops it from some multi-output fusions) takes
+    the scope path that every ``op_name`` of its fused computation
+    shares."""
+    bodies = {m.group(1): m.group(2) for m in _HLO_COMPUTATION.finditer(text)}
     out = {}
-    for line in text.splitlines():
-        m = _HLO_LINE.match(line)
-        if m:
-            out[m.group(1)] = m.group(2)
+    starts = list(_HLO_INSTRUCTION.finditer(text))
+    ends = [m.start() for m in starts[1:]] + [len(text)]
+    for m, end in zip(starts, ends):
+        op = _OP_NAME.search(text, m.end(), end)
+        if op:
+            out[m.group(1)] = op.group(1)
+            continue
+        calls = _CALLS.search(text, m.end(), end)
+        if calls and calls.group(1) in bodies:
+            path = _shared_path(_OP_NAME.findall(bodies[calls.group(1)]))
+            if path:
+                out[m.group(1)] = path
     return out
+
+
+def _shared_path(paths: Sequence[str]) -> str:
+    """The leading scopes that all ``paths`` share, "" if none."""
+    if not paths:
+        return ""
+    parts = [p.split("/") for p in paths]
+    shared = []
+    for segs in zip(*parts):
+        if any(s != segs[0] for s in segs):
+            break
+        shared.append(segs[0])
+    return "/".join(shared)
 
 
 # ------------------------------------------------------------- scopes

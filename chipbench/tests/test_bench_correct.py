@@ -13,8 +13,7 @@ import jax
 import pytest
 
 from chipbench import harness, traffic_gen
-from chipbench.reference import muon as ref_muon
-from chipbench.reference import transformer as ref_model
+from chipbench.reference import controls
 from chipbench.tests import tiny
 from repro.train import trainer as trainer_mod
 
@@ -66,8 +65,8 @@ def test_a_broken_step_is_not_correct(monkeypatch, fault, name):
     assert not result["correct"], checks
 
 
-CONTROLS = {"model": {"q8": ref_model.fp8_round},
-            "chain": {"chain_q": ref_muon.bf16_round}}
+CONTROLS = {"model": {"q8": controls.fp8_round},
+            "chain": {"chain_q": controls.bf16_round}}
 
 
 @pytest.mark.parametrize("name", CONFIGS)

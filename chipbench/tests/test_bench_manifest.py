@@ -9,9 +9,12 @@ import shutil
 import subprocess
 import sys
 
+import jax
+import numpy as np
 import pytest
 
-from chipbench import flops, harness, program
+from chipbench import harness, program
+from chipbench.reference import transformer as ref_model
 from chipbench.tests import tiny
 
 MANIFEST = json.load(open(harness.MANIFEST))
@@ -69,11 +72,11 @@ def test_config_files_give_the_published_sizes(name):
         c["num_hidden_layers"], c["hidden_size"], c["num_attention_heads"])
     if name == "qwen3-8b":
         # q, o 4096 x 4096; k, v 4096 x 1024; 3 MLP matrices 4096 x 12288
-        assert flops.layer_matrix_params(c) == 192_937_984
+        assert ref_model.layer_matrix_params(c) == 192_937_984
     else:
         assert c["reduced"] == {}
         # the paper's 229M parameters: matrices, biases, embedding, norms
-        total = flops.matmul_params(c) + 50257 * 1024 + 10 * 3 * 1024 \
+        total = ref_model.matmul_params(c) + 50257 * 1024 + 10 * 3 * 1024 \
             + 21 * 1024
         assert total == 228_807_680
 
@@ -112,6 +115,162 @@ def test_a_new_cell_needs_only_new_files(tmp_path):
     reader = harness.load_module(cell["readers"]["tokens_per_step"])
     assert reader.read({"tokens": 32768}) == 32768
     assert cell["driver"].endswith("chipbench/drivers/train.py")
+
+
+TOY_FAMILY = '''"""A family the program does not have: one dense matrix, then a
+stack of routed experts [L, E, d, f] per layer, each token through k."""
+import jax
+import jax.numpy as jnp
+
+from chipbench.reference.controls import identity
+
+
+def param_shapes(c):
+    L, E = c["num_hidden_layers"], c["n_routed_experts"]
+    d, f = c["hidden_size"], c["moe_intermediate_size"]
+    return {"embed": (c["vocab_size"], d), "dense": {"w": (1, d, d)},
+            "layers": {"experts": {"w_up": (L, E, d, f),
+                                   "w_down": (L, E, f, d)}}}
+
+
+def init(key, c):
+    shapes, treedef = jax.tree.flatten(
+        param_shapes(c), is_leaf=lambda s: isinstance(s, tuple))
+    return jax.tree.unflatten(treedef, [
+        jax.random.normal(jax.random.fold_in(key, i), s) * s[-2] ** -0.5
+        for i, s in enumerate(shapes)])
+
+
+def loss_and_grad(params, tokens, c, q8=identity, block_rows=0):
+    def loss(p):
+        x = p["embed"][tokens]
+        x = x + jnp.tanh(q8(x) @ q8(p["dense"]["w"][0]))
+        ex = p["layers"]["experts"]
+        for up, down in zip(ex["w_up"], ex["w_down"]):
+            x = x + sum(jnp.tanh(q8(x) @ q8(u)) @ q8(w)
+                        for u, w in zip(up, down)) / len(up)
+        return jnp.mean(jnp.square(x))
+    return jax.value_and_grad(loss)(params)
+
+
+def train_step_flops(c, seq, batch):
+    d, f, k = (c["hidden_size"], c["moe_intermediate_size"],
+               c["num_experts_per_tok"])
+    return 6 * seq * batch * (d * d + c["num_hidden_layers"] * k * 2 * d * f)
+
+
+def muon_views(c):
+    return {"dense/w": (1, (0,)), "layers/experts/w_up": (2, (0,)),
+            "layers/experts/w_down": (2, (1,))}
+'''
+
+
+def _digest(root):
+    return {p: p.read_bytes() for p in sorted(root.rglob("*")) if p.is_file()}
+
+
+def test_a_new_model_family_needs_only_new_files(tmp_path):
+    """A configuration names its own reference module, whose Muon leaves
+    include an expert stack: ``drivers/train.py``'s reference side takes
+    the views, the layer draw, the chain check and the step FLOPs from
+    it, and no file of the benchmark changes."""
+    from chipbench.reference import muon as ref_muon
+
+    root = tmp_path / "repo"
+    shutil.copytree(harness.HERE, root / "chipbench",
+                    ignore=shutil.ignore_patterns("_jax_cache", "tests",
+                                                  "__pycache__"))
+    before = _digest(root)
+    bench = root / "chipbench"
+    (bench / "reference" / "toy_moe.py").write_text(TOY_FAMILY)
+    c = {"name": "toy-moe", "arch": "toy", "reference": "toy_moe",
+         "num_hidden_layers": 3, "n_routed_experts": 4, "hidden_size": 32,
+         "moe_intermediate_size": 16, "num_experts_per_tok": 2,
+         "vocab_size": 64}
+    (bench / "configs" / "toy-moe.json").write_text(json.dumps(c))
+    (bench / "limits" / "toy-moe.muon-8k.json").write_text(json.dumps(
+        tiny.read("limits", "gpt2-paper.muon-8k.json")))
+    man = json.loads(json.dumps(MANIFEST))
+    man["configs"].append({"name": "toy-moe", "source": "x",
+                           "file": "chipbench/configs/toy-moe.json",
+                           "reduced": [], "why": "a test"})
+    man["workloads"].append({"name": "toy-moe.muon-8k", "config": "toy-moe",
+                             "traffic": "muon-8k", "chips": 1,
+                             "why": "a test"})
+    (root / "BENCHMARK.json").write_text(json.dumps(man))
+    after = _digest(root)
+    assert all(after[p] == b for p, b in before.items())
+
+    cell = harness.resolve("toy-moe.muon-8k", str(root / "BENCHMARK.json"),
+                           str(root))
+    assert cell["reference"] == str(bench / "reference" / "toy_moe.py")
+    ref = harness.load_module(cell["reference"])
+    driver = harness.load_module(cell["driver"])
+    c = cell["config"]
+    shapes = ref_muon.leaf_paths(ref.param_shapes(c))
+    views = ref_muon.muon_leaves(ref.param_shapes(c), ref.muon_views(c))
+    assert set(views) == {"dense/w", "layers/experts/w_up",
+                          "layers/experts/w_down"}
+    assert ref_muon.matrix_dims(views["layers/experts/w_down"],
+                                shapes["layers/experts/w_down"]) == (32, 16)
+    key = jax.random.PRNGKey(2 ** 31 + 21)
+    layers = driver.sampled_layers(key, {p: shapes[p] for p in views})
+    driver.check_layers(layers, shapes)
+    assert layers["dense/w"] == 0 and 0 <= layers["layers/experts/w_up"] < 3
+
+    # one reference step, read by the chain check of drivers/train.py: the
+    # reference against itself reads the float32 rounding of the parameters
+    h = cell["traffic"]["optimizer"]
+    params = ref.init(key, c)
+    tokens = jax.random.randint(key, (4, 8), 0, c["vocab_size"])
+    _, grads = ref.loss_and_grad(params, tokens, c)
+    with jax.default_matmul_precision("highest"):
+        new, _, used = ref_muon.update(
+            grads, ref_muon.init_state(params, views), params, h, views)
+    samples = {"p0": driver.pick_layer(params, layers),
+               "p1": driver.pick_layer(new, layers),
+               "g": driver.pick_layer(used, layers)}
+    assert samples["p1"]["layers/experts/w_up"].shape == (1, 4, 32, 16)
+    gaps = driver.chain_gaps(samples, views, h)
+    assert set(gaps) == set(views) and max(gaps.values()) < 1e-4
+
+    # the chain on a stack is the chain on each expert's own matrix
+    u = jax.random.normal(key, shapes["layers/experts/w_down"])
+    with jax.default_matmul_precision("highest"):
+        stack = ref_muon.direction(views["layers/experts/w_down"], u,
+                                   h["prism"])
+        for layer in range(3):
+            for e in range(4):
+                one = ref_muon.polar(u[layer, e].T, h["prism"]).T \
+                    * math.sqrt(32 / 16)
+                np.testing.assert_allclose(stack[layer, e], one, rtol=1e-5,
+                                           atol=1e-6)
+    assert ref.train_step_flops(c, 1024, 8) == 6 * 8192 * (
+        32 * 32 + 3 * 2 * 2 * 32 * 16)
+    # the program has no such family: its translation refuses the file
+    with pytest.raises(harness.CellError, match="toy_moe"):
+        program.model_config(c)
+
+
+def test_a_published_config_is_translated_whole():
+    """Every shape key of a configuration file reaches the program, and
+    a key or a value the program does not take raises instead of being
+    dropped."""
+    from repro.configs import get_config
+
+    c = tiny.read("configs", "gpt2-paper.json")
+    assert program.model_config(c) == get_config("gpt2-paper").replace(
+        name="gpt2-paper", d_model=1024, d_ff=4096, num_heads=16,
+        num_kv_heads=16, head_dim=64, num_layers=10, vocab_size=50257,
+        rope_theta=10000.0, norm_eps=1e-06, tie_embeddings=False,
+        qk_norm=False, qkv_bias=True, mlp_act="gelu", dtype="bfloat16",
+        emb_dtype="bfloat16")
+    assert program.model_config(dict(c, deployment="x", assumed={})) == \
+        program.model_config(c)
+    with pytest.raises(ValueError, match="n_routed_experts"):
+        program.model_config(dict(c, n_routed_experts=64))
+    with pytest.raises(KeyError, match="relu"):
+        program.model_config(dict(c, hidden_act="relu"))
 
 
 def test_unknown_device_kind_raises():

@@ -5,7 +5,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from chipbench import program, traffic_gen
+from chipbench import harness, program, traffic_gen
 from chipbench.reference import muon as ref_muon
 from chipbench.reference import transformer as ref_model
 from chipbench.tests import tiny
@@ -49,9 +49,11 @@ def test_reference_muon_step_matches_the_optimizer(name):
         prism=PrismConfig(**h["prism"]))
     opt = make_optimizer(ocfg, model.logical_axes())
     _, ref_grads = ref_model.loss_and_grad(ref_params, tokens, c)
+    views = ref_muon.muon_leaves(ref_model.param_shapes(c),
+                                 ref_model.muon_views(c))
     # the reference clips; hand the optimizer the gradient it would get
     new_ref, _, used = ref_muon.update(ref_grads, ref_muon.init_state(
-        ref_params), ref_params, h)
+        ref_params, views), ref_params, h, views)
     grads = program.to_program(used, model.param_shapes())
     with jax.default_matmul_precision("highest"):
         new, _ = opt.update(grads, opt.init(params), params, jnp.int32(0),
@@ -90,3 +92,40 @@ def test_published_grouped_heads_of_the_qwen3_8b_cell_match():
     with jax.default_matmul_precision("highest"):
         ref_loss = ref_model.loss(ref_params, tokens, c)
     np.testing.assert_allclose(loss, ref_loss, rtol=1e-5)
+
+
+# the rows of each dense leaf's matrix view, counted after its layer axis
+DENSE_ROWS = {"wq": (0,), "wk": (0,), "wv": (0,), "wo": (2,), "w_up": (0,),
+              "w_gate": (0,), "w_down": (1,), "bq": (0,), "bk": (0,),
+              "bv": (0,)}
+
+
+@pytest.mark.parametrize("name", CONFIGS)
+def test_dense_muon_views_keep_the_dense_table(name):
+    """Every dense leaf named in the table, and no other, is a stack of
+    one matrix per layer with the table's rows."""
+    c = (tiny.QWEN3_8B if name == "qwen3-8b"
+         else tiny.read("configs", name + ".json"))
+    flat = ref_muon.leaf_paths(ref_model.param_shapes(c))
+    want = {path: (1, DENSE_ROWS[path.rsplit("/", 1)[-1]]) for path in flat
+            if path.rsplit("/", 1)[-1] in DENSE_ROWS}
+    assert ref_model.muon_views(c) == want
+    assert ref_muon.muon_leaves(ref_model.param_shapes(c), want) == want
+
+
+def test_a_layer_outside_its_leaf_raises():
+    driver = harness.load_module(harness.HERE + "/drivers/train.py")
+    shapes = {"layers/attn/wq": (10, 64, 16, 4), "dense/w": (1, 64, 64),
+              "layers/experts/w_up": (26, 8, 64, 32)}
+    key = jax.random.PRNGKey(2 ** 31 + 7)
+    layers = driver.sampled_layers(key, shapes)
+    # each leaf is drawn within its own layer axis; ten-layer leaves
+    # keep the draw of one layer for the whole model
+    assert layers["dense/w"] == 0 and 0 <= layers["layers/experts/w_up"] < 26
+    assert layers["layers/attn/wq"] == int(jax.random.randint(
+        jax.random.fold_in(key, 2), (), 0, 10))
+    driver.check_layers(layers, shapes)
+    for bad in (10, -1):
+        with pytest.raises(IndexError, match="layers/attn/wq"):
+            driver.check_layers(dict(layers, **{"layers/attn/wq": bad}),
+                                shapes)

@@ -4,6 +4,7 @@ on a hand-made record with nested operations and spans, on a trace the
 CPU records here, and on a small trace recorded on the chip."""
 import gzip
 import json
+import math
 import os
 
 import jax
@@ -15,6 +16,7 @@ from chipbench import flops, harness, step_trace, trace
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 DATA = os.path.join(HERE, "data", "step_scopes_trace.json.gz")
+KERNELS = os.path.join(HERE, "data", "step_kernels_trace.json.gz")
 NEW = ["fwd_bwd_ms.step", "optimizer_ms.step", "matfn_roofline.step",
        "host_loop_ms.step", "matfn_launches.step"]
 J = "jit(train_step)/"
@@ -120,14 +122,33 @@ def test_readers_on_a_hand_made_record():
     assert "94.74% inside a leaf span" in line  # 180 of 190 ns
 
 
-def test_readers_fall_silent_on_the_benchmark_record():
-    """The record and context the benchmark builds today carry no
-    program spans, scopes or launch count: every new reader reads
-    nothing and raises nothing."""
-    rec = _record(2)
-    bare = {"devices": rec["devices"], "spans": []}
-    for name in NEW:
-        assert _read(name, _ctx(bare, 0, 2000)) is None, name
+def test_readers_read_the_record_the_traced_run_builds(tmp_path):
+    """The context the traced branch builds, on a tiny trainer and a
+    record with the program's scopes and spans: every per-layer metric
+    the benchmark lists for the cell reads a number, and the polar
+    chains counted on the trainer's shapes are the reference's Muon
+    matrices, layer by layer."""
+    from chipbench import traffic_gen
+    from chipbench.reference import muon as ref_muon
+    from chipbench.tests import tiny
+
+    driver = harness.load_module(harness.HERE + "/drivers/train.py")
+    tc = driver.TrainCell(tiny.cell("gpt2-paper"), str(tmp_path))
+    tokens = traffic_gen.batches(jax.random.PRNGKey(3), tc.traffic,
+                                 tc.c["vocab_size"])[0]
+    ctx = driver.layer_context(tc, _record(2), (0, 2000), 4.0,
+                               harness.peaks("TPU v5 lite"), 1,
+                               tc.step_shapes(tokens))
+    matrices = sum(math.prod(ref_muon.leaf_paths(
+        tc.ref.param_shapes(tc.c))[path][:view[0]])
+        for path, view in tc.muon.items())
+    assert sum(n for n, _ in ctx["polar_buckets"]) == matrices == 18
+    assert (ctx["polar_iters"], ctx["polar_degree"]) == (3, 2)
+    cell = harness.resolve("gpt2-paper.muon-8k")
+    assert set(NEW) <= {m["name"] for m in cell["per_layer"]}
+    for name, path in cell["readers"].items():
+        value = harness.load_module(path).read(ctx)
+        assert isinstance(value, (int, float)) and math.isfinite(value), name
 
 
 def test_program_spans_stay_out_of_the_benchmarks_spans(tmp_path):
@@ -171,6 +192,40 @@ def test_hlo_text_gives_each_instruction_its_scope(line, want):
     """Trace events on a TPU carry an operation's HLO text without its
     metadata; the compiled module's text maps the name to the path."""
     assert step_trace.hlo_scopes(line) == want
+
+
+# a Pallas kernel's custom call as a TPU compile writes it: the
+# instruction is named by the kernel, and its kernel_metadata attribute
+# breaks the line before its op_name (the body and shapes cut short);
+# then a multi-output fusion that carries no op_name of its own
+KERNEL_HLO = """\
+  %splash_mha_fwd_residuals.17 = (f32[8,512,128]{2,1,0:T(8,128)}) custom-call(%copy-done.2, %fusion), custom_call_target="tpu_custom_call", frontend_attributes={kernel_metadata={
+"xprof_metadata":"{\\"block_q\\": 512, \\"block_kv\\": 512}"
+}}, metadata={op_name="jit(train_step)/fwd_bwd/jvp(layers)/while/body/attn_kernel/vmap(jit(_splash_attention))/splash_mha_fwd_residuals/pallas_call" stack_frame_id=15}, backend_config={"custom_call_config":{"body":"TUzvUgFNTElSMjIuMC4wZ2l0"}}
+  %copy-start.1 = (s8[1,2,2]{2,1,0}) copy-start(%constant.13)
+  %fusion.3 = f32[8]{0} fusion(%p), kind=kLoop, calls=%c, metadata={op_name="jit(train_step)/clip/mul"}
+  %fusion.490 = (bf16[8,1024,16,32]{3,1,2,0}) fusion(%a, %b), kind=kLoop, calls=%fused_computation.214
+}
+
+%fused_computation.214 (param_0: bf16[8,1024,16,32]) -> (bf16[8,1024,16,32]) {
+  %param_0 = bf16[8,1024,16,32]{3,1,2,0} parameter(0)
+  %mul.1 = bf16[8,1024,16,32]{3,1,2,0} multiply(%param_0, %param_0), metadata={op_name="jit(train_step)/fwd_bwd/jvp(layers)/while/body/mul"}
+  ROOT %sub.1 = bf16[8,1024,16,32]{3,1,2,0} subtract(%mul.1, %param_0), metadata={op_name="jit(train_step)/fwd_bwd/jvp(layers)/while/body/sub"}
+}
+"""
+
+
+def test_kernels_and_bare_fusions_take_their_scopes():
+    body = J + "fwd_bwd/jvp(layers)/while/body/"
+    assert step_trace.hlo_scopes(KERNEL_HLO) == {
+        "splash_mha_fwd_residuals.17": body + "attn_kernel/vmap(jit("
+        "_splash_attention))/splash_mha_fwd_residuals/pallas_call",
+        "fusion.3": J + "clip/mul",
+        # the scopes its fused computation's operations share
+        "fusion.490": J + "fwd_bwd/jvp(layers)/while/body",
+        "mul.1": body + "mul", "sub.1": body + "sub"}
+    assert step_trace.label(step_trace.hlo_scopes(KERNEL_HLO)[
+        "splash_mha_fwd_residuals.17"]) == "fwd_bwd/forward"
 
 
 def _recorded():
@@ -222,3 +277,31 @@ def test_recorded_chip_step_against_a_timeline():
     idle = step_trace.idle_ms_by_span(rec, lo, hi)
     leaf = sum(v for k, v in idle.items() if k not in ("step", "outside"))
     assert leaf >= 0.9 * sum(idle.values())
+
+
+def test_recorded_step_with_kernels_is_scoped():
+    """One step of the tree with the attention kernel, recorded on the
+    chip, with the HLO text of each of its operations (kernel bodies and
+    backend configs cut): the splash kernels' events and the fusions
+    that lost their own op_name take the model's scope, and the three
+    scopes hold at least 97% of the step's busy time."""
+    with gzip.open(KERNELS, "rt") as f:
+        rec = json.load(f)
+    scopes = step_trace.hlo_scopes(rec.pop("hlo"))
+    ops = rec["devices"][0]
+    rec["op_scopes"] = [[scopes.get(trace.op_name(n).lstrip("%"), "")
+                         for n, _, _ in ops]]
+    lo, hi = rec["window_ns"]
+    by = step_trace.device_ms_by_label(rec, lo, hi)
+    busy = trace.busy_ns(ops, lo, hi) * 1e-6
+    scoped = sum(v for k, v in by.items()
+                 if k.split("/")[0] in step_trace.STEP_SCOPES)
+    assert scoped >= 0.97 * busy
+    kernels = [p for (n, _, _), p in zip(ops, rec["op_scopes"][0])
+               if n.startswith("%splash_mha_")]
+    assert len(kernels) == 30  # forward, remat, backward in 10 layers
+    assert all("/fwd_bwd/" in p and "/attn_kernel/" in p for p in kernels)
+    model = _read("fwd_bwd_ms.step", _ctx(rec, lo, hi))
+    assert model == pytest.approx(sum(v for k, v in by.items()
+                                      if k.startswith("fwd_bwd")))
+    assert 120 < model < 130

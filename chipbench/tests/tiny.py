@@ -9,7 +9,8 @@ BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 # configuration files' keys: grouped-query attention, qk-norm and SwiGLU,
 # the layer kinds the reference covers beside gpt2-paper's
 QWEN3_8B = {
-    "name": "qwen3-8b", "arch": "qwen3-14b", "num_hidden_layers": 36,
+    "name": "qwen3-8b", "arch": "qwen3-14b", "reference": "transformer",
+    "num_hidden_layers": 36,
     "hidden_size": 4096, "num_attention_heads": 32, "num_key_value_heads": 8,
     "head_dim": 128, "intermediate_size": 12288, "vocab_size": 151936,
     "hidden_act": "silu", "attention_bias": False, "qk_norm": True,
@@ -35,8 +36,11 @@ def config(name: str) -> dict:
 def cell(name: str) -> dict:
     """A resolved cell (as ``harness.resolve`` gives it) at tiny size,
     judged by the gpt2-paper cell's limits."""
+    c = config(name)
     return {"workload": {"name": name + ".muon-8k", "chips": 1},
-            "config": config(name),
+            "config": c,
+            "reference": os.path.join(BENCH, "reference",
+                                      c["reference"] + ".py"),
             "traffic": dict(read("traffic", "muon-8k.json"), seq=32,
                             batch=4),
             "limits": read("limits", "gpt2-paper.muon-8k.json"),
