@@ -187,7 +187,56 @@ class MoEConfig:
     # crosses data shards -> all-gathers of the token stream).
     # "per_sample": dispatch within each sequence -> gathers stay local to
     # the batch shard (§Perf MoE iteration); capacity is per sample.
+    # "grouped": dropless — the assignments to the held experts are sorted
+    # by expert and run as grouped products; no capacity, nothing dropped.
     dispatch: str = "global"
+    # expert parallelism as DeepSeek's code states it: the routed experts
+    # are split over ep_size chips and this layer holds rank 0's share,
+    # experts [0, num_experts // ep_size).  The router still scores all
+    # num_experts; assignments to the other shares are left out.
+    ep_size: int = 1
+    # shared experts: one SwiGLU MLP of width n_shared_experts * d_ff
+    # that every token passes through
+    n_shared_experts: int = 0
+    # "softmax": top-k of the logits, gates softmaxed over the chosen;
+    # "sigmoid": s = sigmoid(logits), top-k of s (+ the correction bias
+    # when ``selection_bias``), gates are s at the chosen ids
+    score_func: str = "softmax"
+    # a fixed per-expert bias added to the scores for selection only
+    # (DeepSeek-V3's noaux_tc, held fixed: its update speed is 0)
+    selection_bias: bool = False
+    norm_topk_prob: bool = True  # sigmoid gates normalized to sum 1
+    routed_scaling_factor: float = 1.0  # sigmoid gates scaled after that
+    # balance loss: False the Switch loss over the batch; True
+    # DeepSeek-V3's sequence-wise loss, both at router_aux_loss_coef
+    seq_aux: bool = False
+
+    def __post_init__(self):
+        if self.dispatch not in ("global", "per_sample", "grouped"):
+            raise ValueError(f"MoEConfig.dispatch must be global|per_sample"
+                             f"|grouped, got {self.dispatch!r}")
+        if self.score_func not in ("softmax", "sigmoid"):
+            raise ValueError(f"MoEConfig.score_func must be softmax|sigmoid,"
+                             f" got {self.score_func!r}")
+        if self.num_experts % self.ep_size:
+            raise ValueError(f"{self.num_experts} experts do not split over "
+                             f"ep_size {self.ep_size}")
+        grouped = self.dispatch == "grouped"
+        if grouped != (self.score_func == "sigmoid"):
+            raise ValueError("dispatch='grouped' routes by sigmoid scores, "
+                             "the capacity dispatch by softmax")
+        if not grouped and (
+                self.ep_size != 1 or self.n_shared_experts or self.seq_aux
+                or self.selection_bias or not self.norm_topk_prob
+                or self.routed_scaling_factor != 1.0):
+            raise ValueError("held shares, shared experts, the selection "
+                             "bias, unnormalized or scaled gates and the "
+                             "sequence-wise loss need dispatch='grouped'")
+
+    @property
+    def held_experts(self) -> int:
+        """Routed experts this layer holds: rank 0's share."""
+        return self.num_experts // self.ep_size
 
 
 @dataclass(frozen=True)
@@ -245,10 +294,26 @@ class ModelConfig:
     # seq-chunk size for the chunked CE loss; larger chunks amortize the
     # LM-head all-gather across more tokens (ZeRO-3; §Perf iteration 4)
     loss_chunk: int = 512
+    # latent attention (MLA, DeepSeek-V2/V3) when kv_lora_rank > 0: q is
+    # x W_q with qk_nope + qk_rope dims a head; [c_kv | k_rope] is
+    # x W_kv_a; [k_nope | v] is RMSNorm(c_kv) W_kv_b; k_rope is shared by
+    # every head.  head_dim and num_kv_heads are then unused.
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # leading dense layers of an expert model (DeepSeek's
+    # first_k_dense_replace) and their MLP width
+    first_k_dense: int = 0
+    dense_d_ff: int = 0
 
     @property
     def resolved_head_dim(self) -> int:
         return self.head_dim or self.d_model // self.num_heads
+
+    @property
+    def mla(self) -> bool:
+        return self.kv_lora_rank > 0
 
     @property
     def attention_free(self) -> bool:

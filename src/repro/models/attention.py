@@ -1,8 +1,13 @@
-"""Grouped-query attention: a fused Pallas kernel or a jnp chunked softmax.
+"""Grouped-query and latent attention: a fused Pallas kernel or a jnp
+chunked softmax.
 
 Supports: GQA (num_kv_heads < num_heads), qk-norm (Qwen3), QKV bias
 (Qwen2.5), RoPE, sliding-window (Mixtral) / local (RecurrentGemma)
-attention, and single-token decode against a KV cache.
+attention, single-token decode against a KV cache, and DeepSeek's latent
+attention (MLA, ``cfg.kv_lora_rank > 0``, train and prefill only): q/k
+heads of ``qk_nope + qk_rope`` dims, v heads of ``v_head_dim``, keys and
+values projected up from a normed latent, one rotary key shared by every
+head (``_project_mla``, under the scope ``mla``).
 
 The train/prefill path has two implementations of one algorithm
 (causal online-softmax attention, bf16 operands, float32 accumulation),
@@ -42,7 +47,10 @@ KV_BLOCK = 1024
 # the fused kernel's q and kv block (forward and fused backward), and the
 # head sizes it is taken for
 KERNEL_BLOCK = 512
-KERNEL_HEAD_DIMS = (64, 128)
+KERNEL_HEAD_DIMS = (64, 128, 192)
+# DeepSeek's RMSNorm default eps, which MLA's latent norm takes (the
+# block norms take the configuration's norm_eps)
+MLA_NORM_EPS = 1e-6
 HEAD_PAD = 16  # pad head counts to the model-axis width for clean TP
 
 # trace-time count of ``attend`` calls by path: "kernel" or "chunked"
@@ -63,6 +71,8 @@ def padded_heads(cfg: ModelConfig):
 
 
 def init_attention(key, cfg: ModelConfig):
+    if cfg.mla:
+        return init_mla(key, cfg)
     dtype = jnp.dtype(cfg.dtype)
     d = cfg.d_model
     hd = cfg.resolved_head_dim
@@ -96,6 +106,8 @@ def init_attention(key, cfg: ModelConfig):
 
 
 def attention_axes(cfg: ModelConfig):
+    if cfg.mla:
+        return mla_axes(cfg)
     p = {
         "wq": ("embed", "heads", "head_dim"),
         "wk": ("embed", "kv_heads", "head_dim"),
@@ -126,6 +138,51 @@ def _project_qkv(params, x, positions, cfg: ModelConfig):
     k = shard_activation(k, ("batch", "seq", "kv_heads", "head_dim"))
     v = shard_activation(v, ("batch", "seq", "kv_heads", "head_dim"))
     return q, k, v
+
+
+def init_mla(key, cfg: ModelConfig):
+    """MLA without q-LoRA: wq [d, H, dn + dr], wkv_a [d, r + dr] (the
+    latent and the shared rotary key), kv_norm [r], wkv_b [r, H, dn + dv]
+    (each head's nope key and value), wo [H, dv, d]."""
+    dtype = jnp.dtype(cfg.dtype)
+    d, H, r = cfg.d_model, cfg.num_heads, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    ks = jax.random.split(key, 4)
+    return {"wq": L.dense_init(ks[0], (d, H, dn + dr), -3, dtype),
+            "wkv_a": L.dense_init(ks[1], (d, r + dr), -2, dtype),
+            "kv_norm": L.init_rms_norm(r),
+            "wkv_b": L.dense_init(ks[2], (r, H, dn + dv), -3, dtype),
+            "wo": L.dense_init(ks[3], (H, dv, d), -3, dtype)}
+
+
+def mla_axes(cfg: ModelConfig):
+    """The latent axis marks wkv_b's rows, as ``embed`` marks the other
+    projections' (optim/base.py: Muon's matrix views)."""
+    return {"wq": ("embed", "heads", "head_dim"),
+            "wkv_a": ("embed", "kv_lora"),
+            "kv_norm": ("kv_lora",),
+            "wkv_b": ("kv_lora", "heads", "head_dim"),
+            "wo": ("heads", "head_dim", "embed")}
+
+
+@scoped("mla")
+def _project_mla(params, x, positions, cfg: ModelConfig):
+    """x [B, S, D] -> q, k [B, S, H, dn + dr], v [B, S, H, dv]: q and
+    the latent from x, the latent normed and projected up to each head's
+    nope key and value, rotary positions on q's last dr dims and on the
+    one rotary key every head shares."""
+    r, dn = cfg.kv_lora_rank, cfg.qk_nope_head_dim
+    q = jnp.einsum("bsd,dhk->bshk", x, params["wq"])
+    kv_a = x @ params["wkv_a"]
+    c = L.rms_norm(kv_a[..., :r], params["kv_norm"], MLA_NORM_EPS)
+    kv = jnp.einsum("bsr,rhk->bshk", c, params["wkv_b"])
+    k_rope = L.rope(kv_a[..., None, r:], positions, cfg.rope_theta)
+    q = jnp.concatenate(
+        [q[..., :dn], L.rope(q[..., dn:], positions, cfg.rope_theta)], -1)
+    k = jnp.concatenate(
+        [kv[..., :dn], jnp.broadcast_to(k_rope, kv.shape[:3]
+                                        + k_rope.shape[-1:])], -1)
+    return q, k, kv[..., dn:]
 
 
 def _expand_kv(k, groups: int):
@@ -159,9 +216,10 @@ def _causal_kernel(H: int, S: int):
 @scoped("attn_kernel")
 def _kernel_attend(q, k, v):
     """Causal attention as one fused Pallas kernel, forward and backward;
-    q [B,S,H,Hd], k/v [B,S,KV,Hd] -> [B,S,H,Hd].  q is scaled in float32
-    and cast back as ``_flash_attend`` does; q, k and v stay bf16, the
-    scores, softmax and accumulators float32."""
+    q/k [B,S,H|KV,Hd], v [B,S,KV,Hv] -> [B,S,H,Hv], scaled by
+    1/sqrt(Hd).  q is scaled in float32 and cast back as
+    ``_flash_attend`` does; q, k and v stay bf16, the scores, softmax
+    and accumulators float32."""
     B, S, H, Hd = q.shape
     k = _expand_kv(k, H // k.shape[2])
     v = _expand_kv(v, H // v.shape[2])
@@ -175,9 +233,10 @@ def _kernel_attend(q, k, v):
 
 @scoped("attn_chunked")
 def _flash_attend(q, k, v, q_pos, k_pos, window: int, kv_block: int):
-    """Online-softmax attention; q [B,S,H,Hd], k/v [B,Sk,KV,Hd]."""
+    """Online-softmax attention; q/k [B,S|Sk,H|KV,Hd], v [B,Sk,KV,Hv]
+    -> [B,S,H,Hv], scaled by 1/sqrt(Hd)."""
     B, S, H, Hd = q.shape
-    Sk, KV = k.shape[1], k.shape[2]
+    Sk, KV, Hv = k.shape[1], k.shape[2], v.shape[3]
     k = shard_activation(_expand_kv(k, H // KV),
                          ("batch", "seq", "heads", "head_dim"))
     v = shard_activation(_expand_kv(v, H // KV),
@@ -191,7 +250,7 @@ def _flash_attend(q, k, v, q_pos, k_pos, window: int, kv_block: int):
     kpos = jnp.pad(k_pos, ((0, 0), (0, pad)),
                    constant_values=jnp.iinfo(jnp.int32).max)
     kb = kp.reshape(B, nb, kv_block, H, Hd).transpose(1, 0, 2, 3, 4)
-    vb = vp.reshape(B, nb, kv_block, H, Hd).transpose(1, 0, 2, 3, 4)
+    vb = vp.reshape(B, nb, kv_block, H, Hv).transpose(1, 0, 2, 3, 4)
     pb = kpos.reshape(B, nb, kv_block).transpose(1, 0, 2)
 
     def body(carry, blk):
@@ -217,7 +276,7 @@ def _flash_attend(q, k, v, q_pos, k_pos, window: int, kv_block: int):
                           ("batch", "seq", "heads"))
     d0 = shard_activation(jnp.zeros((B, S, H), jnp.float32),
                           ("batch", "seq", "heads"))
-    a0 = shard_activation(jnp.zeros((B, S, H, Hd), jnp.float32),
+    a0 = shard_activation(jnp.zeros((B, S, H, Hv), jnp.float32),
                           ("batch", "seq", "heads", "head_dim"))
     (m, den, acc), _ = jax.lax.scan(body, (m0, d0, a0), (kb, vb, pb))
     out = acc / jnp.maximum(den, 1e-30)[..., None]
@@ -234,12 +293,14 @@ def _one_device() -> bool:
             and (mesh.empty or mesh.size == 1))
 
 
-def _use_kernel(S: int, Sk: int, Hd: int, window: int) -> bool:
-    """Whether ``attend`` takes the fused kernel for these shapes here."""
+def _use_kernel(S: int, Sk: int, Hd: int, window: int,
+                Hv: Optional[int] = None) -> bool:
+    """Whether ``attend`` takes the fused kernel for these shapes here
+    (q/k heads of Hd dims, v heads of Hv, Hd if None)."""
     return (jax.default_backend() == "tpu"
             and (not window or window >= S)
             and S == Sk and S % KERNEL_BLOCK == 0
-            and Hd in KERNEL_HEAD_DIMS
+            and Hd in KERNEL_HEAD_DIMS and (Hv or Hd) in KERNEL_HEAD_DIMS
             and _one_device())
 
 
@@ -250,9 +311,12 @@ def attend(params, x, positions, cfg: ModelConfig, window: int | None = None,
     ``positions`` must be ``arange(S)`` on every row, as every caller
     builds it: the fused kernel masks causally by index, not by
     position (the jnp loop reads the positions)."""
-    q, k, v = _project_qkv(params, x, positions, cfg)
+    if cfg.mla:
+        q, k, v = _project_mla(params, x, positions, cfg)
+    else:
+        q, k, v = _project_qkv(params, x, positions, cfg)
     w = cfg.sliding_window if window is None else window
-    if _use_kernel(q.shape[1], k.shape[1], q.shape[3], w):
+    if _use_kernel(q.shape[1], k.shape[1], q.shape[3], w, v.shape[3]):
         path_counts["kernel"] += 1
         out = _kernel_attend(q, k, v)
     else:
@@ -285,6 +349,8 @@ def decode_attend(params, x, position, cache_k, cache_v, cache_pos, slot,
 
     Returns (out [B, 1, D], new cache_k, new cache_v).
     """
+    if cfg.mla:
+        raise NotImplementedError("latent attention has no decode cache")
     q, k, v = _project_qkv(params, x, position, cfg)
     w = cfg.sliding_window if window is None else window
     B, _, H, Hd = q.shape

@@ -13,7 +13,9 @@ One ``Model`` object (bound to a ModelConfig) provides:
 
 Families:
   dense  — [attn, mlp] x L, one lax.scan over stacked layer params
-  moe    — [attn, moe_ffn] x L
+  moe    — [attn, moe_ffn] x L; with ``first_k_dense`` the leading layers
+           are [attn, mlp] of width ``dense_d_ff``, scanned on their own
+           (``dense_layers``) before the expert layers (``layers``)
   ssm    — [mamba] x L (attention-free)
   hybrid — scan over (recurrent, recurrent, attention) periods + tail
   vlm    — dense backbone; stub frontend projects precomputed patch embeds
@@ -46,25 +48,27 @@ VOCAB_PAD_MULTIPLE = 16  # pad odd vocab tables so TP sharding divides
 # per-layer blocks
 
 
-def _init_block(key, cfg: ModelConfig):
-    """One decoder layer (dense / moe families)."""
+def _init_block(key, cfg: ModelConfig, dense: bool = False):
+    """One decoder layer (dense / moe families); ``dense`` makes a moe
+    model's leading dense layer, its MLP ``dense_d_ff`` wide."""
     k1, k2 = jax.random.split(key)
     p = {
         "ln1": L.init_rms_norm(cfg.d_model),
         "ln2": L.init_rms_norm(cfg.d_model),
         "attn": attn.init_attention(k1, cfg),
     }
-    if cfg.family in ("moe",):
+    if cfg.family in ("moe",) and not dense:
         p["moe"] = moe_lib.init_moe(k2, cfg)
     else:
-        p["mlp"] = L.init_mlp(k2, cfg)
+        p["mlp"] = L.init_mlp(k2, cfg, d_ff=cfg.dense_d_ff if dense
+                              else None)
     return p
 
 
-def _block_axes(cfg: ModelConfig):
+def _block_axes(cfg: ModelConfig, dense: bool = False):
     p = {"ln1": ("embed",), "ln2": ("embed",),
          "attn": attn.attention_axes(cfg)}
-    if cfg.family in ("moe",):
+    if cfg.family in ("moe",) and not dense:
         p["moe"] = moe_lib.moe_axes(cfg)
     else:
         p["mlp"] = L.mlp_axes(cfg)
@@ -72,18 +76,20 @@ def _block_axes(cfg: ModelConfig):
 
 
 def _apply_block(p, x, positions, cfg: ModelConfig):
-    """One decoder layer -> (x, aux, (k, v)).  The per-layer (k, v) are
-    what ``attend`` already projects; serving prefill (§16) stacks them
-    into the decode cache, the train/loss path discards them."""
+    """One decoder layer -> (x, aux, stats, (k, v)).  ``stats`` is the
+    expert layer's (``moe.moe_layer``), empty otherwise.  The per-layer
+    (k, v) are what ``attend`` already projects; serving prefill (§16)
+    stacks them into the decode cache, the train/loss path discards
+    them."""
     h, kv = attn.attend(p["attn"], L.rms_norm(x, p["ln1"], cfg.norm_eps),
                         positions, cfg)
     x = x + h
     xin = L.rms_norm(x, p["ln2"], cfg.norm_eps)
-    if cfg.family in ("moe",):
-        h, aux = moe_lib.moe_ffn(p["moe"], xin, cfg)
+    if "moe" in p:
+        h, aux, stats = moe_lib.moe_layer(p["moe"], xin, cfg)
     else:
-        h, aux = L.mlp(p["mlp"], xin, cfg), 0.0
-    return x + h, aux, kv
+        h, aux, stats = L.mlp(p["mlp"], xin, cfg), 0.0, {}
+    return x + h, aux, stats, kv
 
 
 def _init_mamba_layer(key, cfg: ModelConfig):
@@ -224,7 +230,12 @@ class Model:
                 ]
         else:
             params["layers"] = _stacked_init(
-                lambda k: _init_block(k, cfg), klay, cfg.num_layers)
+                lambda k: _init_block(k, cfg), klay,
+                cfg.num_layers - cfg.first_k_dense)
+            if cfg.first_k_dense:
+                params["dense_layers"] = _stacked_init(
+                    lambda k: _init_block(k, cfg, dense=True), ktail,
+                    cfg.first_k_dense)
         return params
 
     def logical_axes(self):
@@ -250,6 +261,8 @@ class Model:
         else:
             lay = _block_axes(cfg)
         axes["layers"] = _add_layer_axis(lay)
+        if cfg.first_k_dense:
+            axes["dense_layers"] = _add_layer_axis(_block_axes(cfg, True))
         if cfg.family == "hybrid" and self.n_tail:
             axes["tail"] = [
                 (_rec_layer_axes(cfg) if cfg.rglru.pattern[i] == "recurrent"
@@ -300,7 +313,9 @@ class Model:
 
     @scoped("layers")
     def _backbone(self, params, x, positions):
-        """x [B, S, D] -> (x, aux_loss)."""
+        """x [B, S, D] -> (x, aux_loss, stats): ``stats`` the expert
+        layers' (``moe.moe_layer``), their held rows averaged over the
+        layers and their load ratio the largest, or empty."""
         cfg = self.cfg
         remat = cfg.remat == "block"
 
@@ -312,7 +327,7 @@ class Model:
                 return h, 0.0
             body = jax.checkpoint(body) if remat else body
             x, _ = jax.lax.scan(body, x, params["layers"])
-            return x, 0.0
+            return x, 0.0, {}
 
         if cfg.family == "hybrid":
             def body(carry, p):
@@ -333,23 +348,29 @@ class Model:
                 else:
                     x = _apply_attn_layer(p, x, positions, cfg,
                                           cfg.rglru.attention_window)
-            return x, 0.0
+            return x, 0.0, {}
 
         def body(carry, p):
-            h, aux, _ = _apply_block(p, carry, positions, cfg)
-            return h, aux
+            h, aux, stats, _ = _apply_block(p, carry, positions, cfg)
+            return h, (aux, stats)
         body = jax.checkpoint(body) if remat else body
-        x, auxs = jax.lax.scan(body, x, params["layers"])
-        return x, jnp.sum(auxs)
+        if "dense_layers" in params:
+            x, _ = jax.lax.scan(body, x, params["dense_layers"])
+        x, (auxs, stats) = jax.lax.scan(body, x, params["layers"])
+        if stats:
+            stats = {"moe_held_rows": jnp.mean(stats["moe_held_rows"]),
+                     "moe_load_max": jnp.max(stats["moe_load_max"])}
+        return x, jnp.sum(auxs), stats
 
     def _backbone_kv(self, params, x, positions):
         """Dense/moe/vlm/audio backbone that also stacks each layer's
         projected (k, v) — [L, B, S, KV, Hd] — for the §16 serving
         prefill.  No remat: prefill is forward-only."""
         cfg = self.cfg
+        self._no_cache()
 
         def body(carry, p):
-            h, aux, (k, v) = _apply_block(p, carry, positions, cfg)
+            h, aux, _, (k, v) = _apply_block(p, carry, positions, cfg)
             return h, (aux, k, v)
 
         x, (auxs, ks, vs) = jax.lax.scan(body, x, params["layers"])
@@ -367,7 +388,7 @@ class Model:
             x = jnp.concatenate([px.astype(x.dtype), x], axis=1)
             S = x.shape[1]
         positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
-        x, aux = self._backbone(params, x, positions)
+        x, aux, _ = self._backbone(params, x, positions)
         if cfg.family == "vlm":
             x = x[:, patches.shape[1]:]
         return self._lm_logits(params, x), aux
@@ -385,7 +406,7 @@ class Model:
             x = jnp.concatenate([px.astype(x.dtype), x], axis=1)
         S = x.shape[1]
         positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
-        x, _ = self._backbone(params, x, positions)
+        x, _, _ = self._backbone(params, x, positions)
         return self._lm_logits(params, x[:, -1:])
 
     def loss(self, params, batch,
@@ -407,13 +428,13 @@ class Model:
             x = jnp.concatenate([px.astype(x.dtype), x], axis=1)
         S = x.shape[1]
         positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
-        x, aux = self._backbone(params, x, positions)
+        x, aux, stats = self._backbone(params, x, positions)
         if cfg.family == "vlm":
             x = x[:, batch["patches"].shape[1]:]
         ce = self._ce_from_hidden(params, x, tokens, chunk)
         loss = ce + aux
-        return loss, {"ce": ce, "aux": aux,
-                      "ppl_proxy": jnp.exp(jnp.minimum(ce, 20.0))}
+        return loss, dict(stats, ce=ce, aux=aux,
+                          ppl_proxy=jnp.exp(jnp.minimum(ce, 20.0)))
 
     @scoped("ce")
     def _ce_from_hidden(self, params, x, tokens,
@@ -476,8 +497,15 @@ class Model:
             return min(seq_len, cfg.rglru.attention_window)
         return seq_len
 
+    def _no_cache(self):
+        if self.cfg.mla or self.cfg.first_k_dense:
+            raise NotImplementedError(
+                "no decode cache for latent attention or leading dense "
+                "layers")
+
     def init_cache(self, batch: int, seq_len: int):
         cfg = self.cfg
+        self._no_cache()
         dtype = jnp.dtype(cfg.dtype)
         hd = cfg.resolved_head_dim
         kv = attn.padded_heads(cfg)[1] if cfg.family != "ssm" \
@@ -670,7 +698,7 @@ class Model:
             h = h + y
             hin = L.rms_norm(h, p["ln2"], cfg.norm_eps)
             if cfg.family == "moe":
-                ff, _ = moe_lib.moe_ffn(p["moe"], hin, cfg)
+                ff, _, _ = moe_lib.moe_layer(p["moe"], hin, cfg)
             else:
                 ff = L.mlp(p["mlp"], hin, cfg)
             h = h + ff

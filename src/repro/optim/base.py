@@ -395,12 +395,21 @@ def is_matrix_param(path_axes: tuple, shape: tuple,
     return min(m, n) >= 16
 
 
+# logical axes that mark a matrix view's rows, the first found winning:
+# the model width, else MLA's latent (wkv_b [kv_lora, heads, head_dim])
+ROW_AXES = ("embed", "kv_lora")
+
+
+def _row_axis(names) -> Optional[str]:
+    return next((a for a in ROW_AXES if a in names), None)
+
+
 def matrix_view_dims(path_axes: tuple, shape: tuple) -> Optional[tuple]:
     """(rows, cols) of the Muon matrix view; None if not matrix-like.
 
-    The 'embed' logical axis marks the contraction side: the matrix is
-    (embed-dim) x (product of remaining non-batch dims).  Leading 'layers'
-    / 'experts' axes are batch.  Without an 'embed' tag, the last two dims
+    A ``ROW_AXES`` logical axis marks the contraction side: the matrix is
+    (that dim) x (product of remaining non-batch dims).  Leading 'layers'
+    / 'experts' axes are batch.  Without such a tag, the last two dims
     form the matrix (generic case).
     """
     axes = tuple(path_axes)
@@ -410,8 +419,9 @@ def matrix_view_dims(path_axes: tuple, shape: tuple) -> Optional[tuple]:
         return None
     idxs = [i for i, _ in non_batch]
     names = [a for _, a in non_batch]
-    if "embed" in names:
-        e = idxs[names.index("embed")]
+    row = _row_axis(names)
+    if row:
+        e = idxs[names.index(row)]
         rest = [i for i in idxs if i != e]
         m = shape[e]
         n = 1
@@ -426,15 +436,17 @@ def matrix_view_dims(path_axes: tuple, shape: tuple) -> Optional[tuple]:
 
 
 def to_matrix_view(p: jax.Array, path_axes: tuple) -> jax.Array:
-    """Reshape p to [..batch.., m, n] with 'embed' as the row dim (possibly
-    transposed into place).  Inverse via from_matrix_view."""
+    """Reshape p to [..batch.., m, n] with the ``ROW_AXES`` dim as the
+    rows (possibly transposed into place).  Inverse via
+    from_matrix_view."""
     axes = tuple(path_axes)
     batch = {"layers", "experts"}
     batch_idx = [i for i, a in enumerate(axes) if a in batch]
     other_idx = [i for i, a in enumerate(axes) if a not in batch]
     names = [axes[i] for i in other_idx]
-    if "embed" in names:
-        e = other_idx[names.index("embed")]
+    row = _row_axis(names)
+    if row:
+        e = other_idx[names.index(row)]
         rest = [i for i in other_idx if i != e]
         perm = batch_idx + [e] + rest
         q = jnp.transpose(p, perm)

@@ -147,7 +147,7 @@ def make_pipeline_stage_fn(model: Model):
         mb, S = x0.shape[0], x0.shape[1]
         positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32),
                                      (mb, S))
-        y, aux = model._backbone({"layers": lac}, x0, positions)
+        y, aux, _ = model._backbone({"layers": lac}, x0, positions)
         ce = jax.lax.cond(
             is_last,
             lambda: model._ce_from_hidden(shc, y, tokens),

@@ -18,7 +18,10 @@ Features (DESIGN.md §4):
     ``counters["compiles_by_step"]`` records, for each step during which
     JAX compiled a program, how many it compiled, and
     ``counters["attention_path"]`` how many ``attend`` calls took each
-    attention path when the step was last traced;
+    attention path, and ``counters["moe_path"]`` how many expert layers
+    took each dispatch path, when the step was last traced; the expert
+    layers' held rows and load ratio ride in the step's metrics and are
+    printed with the loss at ``log_every``;
   * multi-pod: the same code lowers under the production mesh — the
     launcher passes (mesh, shardings); on CPU tests mesh=None runs local.
 """
@@ -36,7 +39,7 @@ import numpy as np
 from repro import checkpoint as ckpt
 from repro.config import OptimizerConfig, TrainConfig
 from repro.data import DataConfig, make_batch_fn
-from repro.models import attention
+from repro.models import attention, moe
 from repro.models.transformer import Model
 from repro.optim import base, make_optimizer
 from repro.train import fault
@@ -170,6 +173,7 @@ class Trainer:
         for t in range(start, steps):
             compiles = _compiles[0]
             paths = attention.path_counts.copy()
+            moe_paths = moe.path_counts.copy()
             with jax.profiler.StepTraceAnnotation("trainer.step",
                                                   step_num=t):
                 params, opt_state, loss = self._step(
@@ -180,6 +184,9 @@ class Trainer:
             traced = attention.path_counts - paths
             if traced:
                 self.counters["attention_path"] = dict(traced)
+            traced = moe.path_counts - moe_paths
+            if traced:
+                self.counters["moe_path"] = dict(traced)
         if self._ckpt_thread is not None:
             self._ckpt_thread.join()
         return params, opt_state, losses
@@ -246,9 +253,13 @@ class Trainer:
                     print(f"[trainer] straggler: step {t} took {dt:.2f}s "
                           f"(median {med:.2f}s)", flush=True)
             if t % self.tcfg.log_every == 0:
+                moe_stats = "".join(
+                    f" {k} {float(metrics[k]):.4g}"
+                    for k in ("moe_held_rows", "moe_load_max")
+                    if k in metrics)
                 print(f"[trainer] step {t} loss {loss:.4f} "
-                      f"gnorm {float(metrics['grad_norm']):.3f} "
-                      f"{dt:.2f}s", flush=True)
+                      f"gnorm {float(metrics['grad_norm']):.3f}"
+                      f"{moe_stats} {dt:.2f}s", flush=True)
         if on_metrics is not None:
             with span("trainer.callback"):
                 on_metrics(t, metrics)
