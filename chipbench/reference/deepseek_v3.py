@@ -363,13 +363,34 @@ def loss(params: Dict, tokens: jax.Array, c: Dict,
     return _cross_entropy(x, params["head"], tokens[:, 1:], q8) + aux
 
 
+def both_passes(q8: Callable) -> Callable:
+    """``q8`` in the backward pass too: the cotangent that reaches a
+    rounded operand is rounded the same way, with its own scale.  A
+    float8 cast's own transpose would round the cotangent with no scale,
+    and the cotangents of this loss, mostly far under float8's smallest
+    step, would flush to 0 (at a tiny size the earliest leaves' gradients
+    read 0 to 1e-7 of the reference's 0.2-0.4), so the control's
+    gradient would read a flushed backward, not a precision."""
+    if q8 is identity:
+        return q8
+
+    @jax.custom_vjp
+    def rounded(x):
+        return q8(x)
+
+    rounded.defvjp(lambda x: (q8(x), None), lambda _, ct: (q8(ct),))
+    return rounded
+
+
 def loss_and_grad(params: Dict, tokens: jax.Array, c: Dict,
                   q8: Callable = identity, block_rows: int = 0):
-    """The loss and its gradient; with ``block_rows`` the batch is taken
-    in blocks of that many rows, one after the other, and their equal
-    shares summed (every row has the same length, and the balance loss
-    is a mean over sequences), so that only one block's activations are
-    alive at a time."""
+    """The loss and its gradient, ``q8`` in both passes
+    (``both_passes``); with ``block_rows`` the batch is taken in blocks
+    of that many rows, one after the other, and their equal shares
+    summed (every row has the same length, and the balance loss is a
+    mean over sequences), so that only one block's activations are alive
+    at a time."""
+    q8 = both_passes(q8)
     rows = tokens.shape[0]
     block = block_rows or rows
     if rows % block:

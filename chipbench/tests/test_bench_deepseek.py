@@ -207,9 +207,8 @@ def test_the_translation_refuses_what_the_program_does_not_implement():
     cfg = program.model_config(c)
     assert cfg.moe == MoEConfig(
         num_experts=64, num_experts_per_tok=6, dispatch="grouped", ep_size=8,
-        n_shared_experts=2, score_func="sigmoid", selection_bias=True,
-        norm_topk_prob=True, routed_scaling_factor=2.446, seq_aux=True,
-        router_aux_loss_coef=1e-4)
+        n_shared_experts=2, selection_bias=True, norm_topk_prob=True,
+        routed_scaling_factor=2.446, seq_aux=True, router_aux_loss_coef=1e-4)
     assert (cfg.num_layers, cfg.first_k_dense, cfg.dense_d_ff, cfg.d_ff,
             cfg.vocab_size, cfg.kv_lora_rank) == (5, 1, 11264, 1408, 20480,
                                                   512)
@@ -217,3 +216,24 @@ def test_the_translation_refuses_what_the_program_does_not_implement():
                        ("topk_method", "greedy"), ("scoring_func", "softmax")]:
         with pytest.raises(ValueError, match=key):
             program.model_config(dict(c, **{key: value}))
+
+
+def test_the_model_control_rounds_its_backward_at_scale():
+    """The float8 control's gradient reads a precision: every leaf the
+    reference moves keeps its norm within a factor of 2 (float8's
+    rounding moves it by a few percent), where the float8 casts' own
+    transpose, unscaled, flushes the earliest leaves' cotangents to 0."""
+    from chipbench.reference import controls
+    c = tiny_config()
+    params = ref_model.init(jax.random.PRNGKey(SEED), c)
+    tokens = _tokens(c)
+    norms = lambda g: {k: float(jnp.linalg.norm(v)) for k, v in
+                       jax.tree_util.tree_flatten_with_path(g)[0]}
+    want = norms(ref_model.loss_and_grad(params, tokens, c)[1])
+    got = norms(ref_model.loss_and_grad(params, tokens, c,
+                                        controls.fp8_round)[1])
+    moved = {k: v for k, v in want.items() if v > 1e-6}
+    assert len(moved) > 20
+    for k, v in moved.items():
+        assert 0.5 < got[k] / v < 2, (k, got[k], v)
+    assert ref_model.both_passes(controls.identity) is controls.identity

@@ -46,7 +46,6 @@ def fields(c):
     kw = {field: c[key] for key, field in _FIELDS.items()}
     kw.update(mlp_act="silu", dtype=_DTYPE[c["torch_dtype"]],
               emb_dtype=_DTYPE[c["torch_dtype"]],
-              moe=MoEConfig(dispatch="grouped", score_func="sigmoid",
-                            selection_bias=True,
+              moe=MoEConfig(dispatch="grouped", selection_bias=True,
                             **{f: c[k] for k, f in _MOE.items()}))
     return kw

@@ -189,6 +189,10 @@ class MoEConfig:
     # the batch shard (§Perf MoE iteration); capacity is per sample.
     # "grouped": dropless — the assignments to the held experts are sorted
     # by expert and run as grouped products; no capacity, nothing dropped.
+    # The capacity paths route by softmax (top-k of the logits, gates
+    # softmaxed over the chosen), the grouped path by sigmoid scores
+    # (top-k of s = sigmoid(logits), plus the correction bias when
+    # ``selection_bias``; gates are s at the chosen ids).
     dispatch: str = "global"
     # expert parallelism as DeepSeek's code states it: the routed experts
     # are split over ep_size chips and this layer holds rank 0's share,
@@ -198,10 +202,6 @@ class MoEConfig:
     # shared experts: one SwiGLU MLP of width n_shared_experts * d_ff
     # that every token passes through
     n_shared_experts: int = 0
-    # "softmax": top-k of the logits, gates softmaxed over the chosen;
-    # "sigmoid": s = sigmoid(logits), top-k of s (+ the correction bias
-    # when ``selection_bias``), gates are s at the chosen ids
-    score_func: str = "softmax"
     # a fixed per-expert bias added to the scores for selection only
     # (DeepSeek-V3's noaux_tc, held fixed: its update speed is 0)
     selection_bias: bool = False
@@ -215,17 +215,10 @@ class MoEConfig:
         if self.dispatch not in ("global", "per_sample", "grouped"):
             raise ValueError(f"MoEConfig.dispatch must be global|per_sample"
                              f"|grouped, got {self.dispatch!r}")
-        if self.score_func not in ("softmax", "sigmoid"):
-            raise ValueError(f"MoEConfig.score_func must be softmax|sigmoid,"
-                             f" got {self.score_func!r}")
         if self.num_experts % self.ep_size:
             raise ValueError(f"{self.num_experts} experts do not split over "
                              f"ep_size {self.ep_size}")
-        grouped = self.dispatch == "grouped"
-        if grouped != (self.score_func == "sigmoid"):
-            raise ValueError("dispatch='grouped' routes by sigmoid scores, "
-                             "the capacity dispatch by softmax")
-        if not grouped and (
+        if self.dispatch != "grouped" and (
                 self.ep_size != 1 or self.n_shared_experts or self.seq_aux
                 or self.selection_bias or not self.norm_topk_prob
                 or self.routed_scaling_factor != 1.0):

@@ -20,10 +20,9 @@ CONFIG = ModelConfig(
     kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64,
     v_head_dim=128, first_k_dense=1, dense_d_ff=11264,
     moe=MoEConfig(num_experts=64, num_experts_per_tok=6, dispatch="grouped",
-                  ep_size=1, n_shared_experts=2, score_func="sigmoid",
-                  selection_bias=True, norm_topk_prob=True,
-                  routed_scaling_factor=2.446, seq_aux=True,
-                  router_aux_loss_coef=1e-4),
+                  ep_size=1, n_shared_experts=2, selection_bias=True,
+                  norm_topk_prob=True, routed_scaling_factor=2.446,
+                  seq_aux=True, router_aux_loss_coef=1e-4),
 )
 
 SMOKE = CONFIG.replace(
@@ -31,7 +30,6 @@ SMOKE = CONFIG.replace(
     num_kv_heads=4, d_ff=32, vocab_size=256, kv_lora_rank=32,
     qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16, dense_d_ff=96,
     moe=MoEConfig(num_experts=16, num_experts_per_tok=4, dispatch="grouped",
-                  ep_size=2, n_shared_experts=2, score_func="sigmoid",
-                  selection_bias=True, norm_topk_prob=True,
-                  routed_scaling_factor=2.446, seq_aux=True,
-                  router_aux_loss_coef=1e-4))
+                  ep_size=2, n_shared_experts=2, selection_bias=True,
+                  norm_topk_prob=True, routed_scaling_factor=2.446,
+                  seq_aux=True, router_aux_loss_coef=1e-4))

@@ -188,11 +188,13 @@ def moe_ffn(params, x, cfg: ModelConfig):
 def route_sigmoid(params, x, m: MoEConfig):
     """x [T, D] -> (scores [T, E], ids [T, K], gates [T, K]), float32.
 
-    ``s = sigmoid(x W_g)``; the top K of ``s + b`` (``b`` the correction
-    bias, selection only: no gradient reaches it) are chosen, and their
-    gates are ``s`` at the chosen ids, normalized to sum 1 and scaled by
-    ``routed_scaling_factor``."""
-    logits = jnp.einsum("td,de->te", x.astype(jnp.float32), params["router"])
+    ``s = sigmoid(x W_g)``, contracted in float32 (``HIGHEST``: a TPU's
+    default would round W_g to bfloat16); the top K of ``s + b`` (``b``
+    the correction bias, selection only: no gradient reaches it) are
+    chosen, and their gates are ``s`` at the chosen ids, normalized to
+    sum 1 and scaled by ``routed_scaling_factor``."""
+    logits = jnp.einsum("td,de->te", x.astype(jnp.float32), params["router"],
+                        precision=jax.lax.Precision.HIGHEST)
     s = jax.nn.sigmoid(logits)
     sel = s + params["correction_bias"] if m.selection_bias else s
     _, ids = jax.lax.top_k(sel, m.num_experts_per_tok)
@@ -221,14 +223,18 @@ def grouped_dot(lhs, rhs, group_sizes):
     """lhs [M, k] @ rhs [G, k, n] by groups of consecutive rows (group g
     takes the next ``group_sizes[g]`` rows); rows past the groups are
     left out of the work and their output rows are not read.  On a TPU,
-    where its row tile divides M, the shipped megablox kernel (``gmm``:
-    its grid covers only the tiles the groups fill, and its launches
-    carry their scope in a trace, which XLA's ragged-dot kernels do
-    not); elsewhere XLA's ``ragged_dot``."""
-    if jax.default_backend() == "tpu" and lhs.shape[0] % GMM_TILING[0] == 0:
-        return megablox.gmm(lhs, rhs, group_sizes, lhs.dtype, GMM_TILING)
-    return jax.lax.ragged_dot(lhs, rhs, group_sizes,
-                              preferred_element_type=lhs.dtype)
+    the shipped megablox kernel (``gmm``: its grid covers only the tiles
+    the groups fill, and its launches carry their scope in a trace,
+    which XLA's ragged-dot kernels do not), with M padded to its row
+    tile; elsewhere XLA's ``ragged_dot``."""
+    if jax.default_backend() != "tpu":
+        return jax.lax.ragged_dot(lhs, rhs, group_sizes,
+                                  preferred_element_type=lhs.dtype)
+    M = lhs.shape[0]
+    pad = -M % GMM_TILING[0]
+    out = megablox.gmm(jnp.pad(lhs, ((0, pad), (0, 0))), rhs, group_sizes,
+                       lhs.dtype, GMM_TILING)
+    return out[:M]
 
 
 def _grouped_ffn(params, x, cfg: ModelConfig):

@@ -134,6 +134,10 @@ def make_pipeline_stage_fn(model: Model):
     over stage gradients."""
     cfg = model.cfg
     assert cfg.family in PIPELINE_FAMILIES, cfg.family
+    if cfg.first_k_dense:
+        raise NotImplementedError(
+            "the 1F1B stages split only the scanned ``layers``; leading "
+            "dense layers have no stage")
     dtypes = model.param_dtypes()
     sh_dtypes = {k: v for k, v in dtypes.items() if k != "layers"}
     lay_dtypes = dtypes["layers"]

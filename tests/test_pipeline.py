@@ -15,7 +15,12 @@ import subprocess
 import sys
 import textwrap
 
+import pytest
+
+from repro.configs import get_smoke_config
 from repro.launch.pipeline import bubble_fraction, n_ticks_1f1b
+from repro.models.transformer import Model
+from repro.train.state import make_pipeline_stage_fn
 
 PIN = textwrap.dedent("""
     import os
@@ -51,6 +56,19 @@ def test_schedule_contract():
             # more microbatches amortize the fixed fill+drain
             assert bubble_fraction(S, 2 * M) <= b
     assert bubble_fraction(1, 4) == 0.0  # no pipeline, no bubble
+
+
+@pytest.mark.parametrize("arch, refused", [("gpt2-paper", False),
+                                           ("moonlight-16b-a3b", True)])
+def test_stage_fn_refuses_leading_dense_layers(arch, refused):
+    """The stages split only the scanned ``layers``: a model with leading
+    dense layers would lose them silently, so it is refused."""
+    model = Model(get_smoke_config(arch))
+    if refused:
+        with pytest.raises(NotImplementedError, match="leading dense"):
+            make_pipeline_stage_fn(model)
+    else:
+        assert callable(make_pipeline_stage_fn(model))
 
 
 SCRIPT = textwrap.dedent("""
