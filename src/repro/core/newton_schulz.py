@@ -40,6 +40,7 @@ through the iteration, which lax.while_loop does not support).
 """
 from __future__ import annotations
 
+import collections
 from typing import List, NamedTuple, Optional, Tuple
 
 import jax
@@ -50,6 +51,11 @@ from repro.config import PrismConfig
 from repro.core import polynomials as poly
 from repro.core import prism
 from repro.core import sketch as sk
+
+
+# trace-time count of ``apply_g`` calls by evaluation order: "gram_side"
+# or "horner" (``Trainer.counters["polar_apply"]`` copies it)
+path_counts: collections.Counter = collections.Counter()
 
 
 class IterInfo(NamedTuple):
@@ -117,16 +123,47 @@ def _gram_residual(X: jax.Array, use_kernels: bool) -> jax.Array:
     return (eye - G).astype(X.dtype)
 
 
+def _sym_mm(A, B, use_kernels, alpha, C=None, beta=0.0):
+    """alpha * A @ B (+ beta * C) where the result is symmetric (A, B, C
+    polynomials in one symmetric R), alpha fp32 per slice ([...] or
+    scalar).  The kernel computes the upper block triangle and mirrors
+    it (kernels/gram.py); the jnp path keeps _mm's accumulation order
+    with alpha applied to the fp32 accumulator — never rounded to the
+    compute dtype."""
+    if use_kernels:
+        from repro.kernels import ops as kops
+
+        return kops.sym_matmul_add(A, B, C, alpha=alpha, beta=beta)
+    a = jnp.asarray(alpha, jnp.float32)
+    out = a[..., None, None] * jnp.matmul(A, B,
+                                          preferred_element_type=jnp.float32)
+    if C is not None:
+        out = out + beta * C.astype(jnp.float32)
+    return out.astype(A.dtype)
+
+
 def apply_g(X: jax.Array, R: jax.Array, alpha, d: int,
-            side: str = "right", use_kernels: bool = False) -> jax.Array:
+            side: str = "right", use_kernels: bool = False,
+            family: str = "polar") -> jax.Array:
     """X @ g_d(R; alpha)  (side='right')  or  g_d(R; alpha) @ X  (side='left').
 
     g_d(x; a) = f_{d-1}(x) + a x^d with f the Taylor series of (1-x)^{-1/2}.
-    Evaluated as a chain of d GEMMs (Horner on R), never forming g(R).
+
+    The polar family at d >= 2 evaluates it on the Gram side (DESIGN.md
+    §7): Q = a R^d + f_{d-1} R^{d-1} + ... + f_1 R is formed as an
+    [n, n] matrix by d - 1 power products (Horner on R: Q = a R R +
+    f_{d-1} R, then Q <- R Q + f_j R), each on the upper block triangle,
+    since R = I - X^T X and every polynomial in it are symmetric; Q then
+    reaches the tall iterate in ONE product X Q + f_0 X.  Every other
+    case (d = 1, which has no power product; the sign family, whose R is
+    not symmetric; the coupled sqrt family) runs Horner on the iterate,
+    a chain of d GEMMs that never forms g(R).  ``path_counts`` counts
+    the calls each order took at trace time.
 
     alpha is applied IN fp32 (DESIGN.md §9): the PRISM fit is pinned
-    fp32, so under a bf16 compute policy the fitted alpha multiplies the
-    fp32-upcast X and the product rounds ONCE to the compute dtype —
+    fp32, so under a bf16 compute policy the fitted alpha multiplies an
+    fp32 accumulator (the Gram side's power product, or Horner's
+    fp32-upcast X) and the product rounds ONCE to the compute dtype —
     never pre-rounding alpha itself to bf16 (which would throw away the
     fit's precision before it reaches the update).  The fused kernel
     tier (kernels/fused_iter.apply_g) and ref.apply_g keep the same
@@ -134,6 +171,15 @@ def apply_g(X: jax.Array, R: jax.Array, alpha, d: int,
     """
     f = poly.taylor_inv_sqrt(d - 1)  # ascending, length d
     alpha = jnp.asarray(alpha, jnp.float32)
+    if family == "polar" and d >= 2:
+        path_counts["gram_side"] += 1
+        Q = _sym_mm(R, R, use_kernels, alpha, C=R, beta=float(f[d - 1]))
+        for j in range(d - 2, 0, -1):
+            Q = _sym_mm(R, Q, use_kernels, 1.0, C=R, beta=float(f[j]))
+        if side == "right":
+            return _mm(X, Q, use_kernels, C=X, beta=float(f[0]))
+        return _mm(Q, X, use_kernels, C=X, beta=float(f[0]))
+    path_counts["horner"] += 1
     if alpha.ndim:
         alpha = alpha[..., None, None]
     acc = (alpha * X.astype(jnp.float32)).astype(X.dtype)
@@ -302,9 +348,10 @@ def _adaptive_fit_run(X, Y, cfg: PrismConfig, k0: int, count: int, key,
             out = kops.apply_g(X_, R, a, degree=cfg.degree, Y=Y_)
             Xn, Yn = out if coupled else (out, None)
         else:
-            Xn = apply_g(X_, R, a, cfg.degree, "right", cfg.use_kernels)
-            Yn = apply_g(Y_, R, a, cfg.degree, "left",
-                         cfg.use_kernels) if coupled else None
+            Xn = apply_g(X_, R, a, cfg.degree, "right", cfg.use_kernels,
+                         family)
+            Yn = apply_g(Y_, R, a, cfg.degree, "left", cfg.use_kernels,
+                         family) if coupled else None
         return {"X": Xn, "Y": Yn} if coupled else {"X": Xn}
 
     iterates = {"X": X, "Y": Y} if coupled else {"X": X}
@@ -353,10 +400,11 @@ def _run_phases(X, cfg: PrismConfig, method: str, iters: int, key,
             for a in payload:
                 R = residual_fn(X, Y)
                 aa = jnp.full(R.shape[:-2], a, dtype=jnp.float32)
-                X = apply_g(X, R, aa, cfg.degree, "right", cfg.use_kernels)
+                X = apply_g(X, R, aa, cfg.degree, "right", cfg.use_kernels,
+                            family)
                 if coupled:
                     Y = apply_g(Y, R, aa, cfg.degree, "left",
-                                cfg.use_kernels)
+                                cfg.use_kernels, family)
                 if return_info:
                     alphas.append(aa)
                     fros.append(_fro(R)[..., 0, 0])
@@ -381,10 +429,11 @@ def _run_phases(X, cfg: PrismConfig, method: str, iters: int, key,
                 X, Y = unpack(kops.apply_g(X, R, a, degree=cfg.degree,
                                            Y=Y))
             else:
-                X = apply_g(X, R, a, cfg.degree, "right", cfg.use_kernels)
+                X = apply_g(X, R, a, cfg.degree, "right", cfg.use_kernels,
+                            family)
                 if coupled:
                     Y = apply_g(Y, R, a, cfg.degree, "left",
-                                cfg.use_kernels)
+                                cfg.use_kernels, family)
             if return_info:
                 alphas.append(a)
                 fros.append(_fro(R)[..., 0, 0])

@@ -1,10 +1,12 @@
 """Fused GEMM-with-epilogue Pallas TPU kernel:  D = alpha * A @ B + beta * C.
 
-This is the workhorse of the PRISM Newton-Schulz chains: every polynomial
-update X (f0 I + f1 R + ... + a R^d) is evaluated as d fused GEMMs
-(Horner on R), so the `+ beta * C` epilogue removes one full HBM
-read-modify-write of the [m, n] accumulator per Horner step compared to
-separate dot + add ops.
+This is the workhorse of the PRISM Newton-Schulz chains: the polar update
+applies its Gram-side polynomial Q = a R^d + ... + f1 R to the iterate as
+X Q + f0 X in one call (DESIGN.md §7), and the sign and coupled sqrt
+families evaluate X (f0 I + f1 R + ... + a R^d) as d fused GEMMs (Horner
+on R), so the `+ beta * C` epilogue removes one full HBM
+read-modify-write of the [m, n] output per product compared to separate
+dot + add ops.
 
 Tiling: (bm x bk) @ (bk x bn) MXU tiles with an fp32 VMEM scratch
 accumulator; K is the innermost grid dimension, the C-epilogue and the

@@ -218,15 +218,35 @@ def gram(X, *, alpha: float = 1.0, beta: float = -1.0,
     if mode == "ref":
         return _ref.gram(X, alpha=alpha, beta=beta)
     interp = mode == "interpret"
-    bn_eff = min(bn, X.shape[-1])
     lead = X.shape[:-2]
     (Xb,) = _collapse(lead, X) if lead else (X,)
     U = _gram.gram_upper(Xb, alpha=alpha, beta=beta, bn=bn, bk=bk,
                          interpret=interp)
-    # mirror: diagonal blocks carry alpha*I + full tile; strictly-upper
-    # blocks transpose into the lower triangle.
-    R = _gram.mirror_upper(U, bn_eff)
+    R = _gram.mirror_upper(U)
     return R.reshape(lead + R.shape[-2:]) if lead else R
+
+
+def sym_matmul_add(A, B, C=None, *, alpha, beta: float = 0.0,
+                   bn: int = 256, bk: int = 256):
+    """D = alpha * A @ B (+ beta * C) for [..., n, n] operands whose
+    product is symmetric — polynomials in one symmetric matrix, as the
+    Gram side's power products R @ Q + f_j R are: the upper block
+    triangle in one launch, mirrored to an exactly symmetric D.
+
+    alpha: scalar or [...] matching the leading dims, fp32 (stays fp32
+    in the epilogue).  The ref oracle is the full ``ref.matmul_add``.
+    """
+    lead = A.shape[:-2]
+    a = jnp.broadcast_to(jnp.asarray(alpha, jnp.float32), lead)
+    mode = _mode(A, B, C)
+    if mode == "ref":
+        return _ref.matmul_add(A, B, C, alpha=a[..., None, None], beta=beta)
+    Ab, Bb, Cb = _collapse(lead, A, B, C) if lead else \
+        (A[None], B[None], None if C is None else C[None])
+    U = _gram.matmul_add_upper(Ab, Bb, a.reshape(Ab.shape[0]), Cb,
+                               beta=beta, bn=bn, bk=bk,
+                               interpret=mode == "interpret")
+    return _gram.mirror_upper(U).reshape(A.shape)
 
 
 def _chain_vmem_bytes(n: int, p: int, dtype, bn: int) -> int:
@@ -379,7 +399,8 @@ def count_launches(fn, *args) -> int:
     helper for tests and benchmarks (the launch-count contract of
     DESIGN.md §7).
     """
-    targets = [(_gram, "gram_upper"), (_mma, "matmul_add"),
+    targets = [(_gram, "gram_upper"), (_gram, "matmul_add_upper"),
+               (_mma, "matmul_add"),
                (_sk, "sketch_chain"), (_sk, "sketch_step"),
                (_fused, "residual_chain"), (_fused, "apply_g"),
                (_fused, "warm_tail")]

@@ -18,10 +18,12 @@ Features (DESIGN.md §4):
     ``counters["compiles_by_step"]`` records, for each step during which
     JAX compiled a program, how many it compiled, and
     ``counters["attention_path"]`` how many ``attend`` calls took each
-    attention path, and ``counters["moe_path"]`` how many expert layers
-    took each dispatch path, when the step was last traced; the expert
-    layers' held rows and load ratio ride in the step's metrics and are
-    printed with the loss at ``log_every``;
+    attention path, ``counters["moe_path"]`` how many expert layers took
+    each dispatch path, and ``counters["polar_apply"]`` how many
+    ``newton_schulz.apply_g`` calls took each evaluation order, when the
+    step was last traced; the expert layers' held rows and load ratio
+    ride in the step's metrics and are printed with the loss at
+    ``log_every``;
   * multi-pod: the same code lowers under the production mesh — the
     launcher passes (mesh, shardings); on CPU tests mesh=None runs local.
 """
@@ -38,6 +40,7 @@ import numpy as np
 
 from repro import checkpoint as ckpt
 from repro.config import OptimizerConfig, TrainConfig
+from repro.core import newton_schulz
 from repro.data import DataConfig, make_batch_fn
 from repro.models import attention, moe
 from repro.models.transformer import Model
@@ -174,6 +177,7 @@ class Trainer:
             compiles = _compiles[0]
             paths = attention.path_counts.copy()
             moe_paths = moe.path_counts.copy()
+            applies = newton_schulz.path_counts.copy()
             with jax.profiler.StepTraceAnnotation("trainer.step",
                                                   step_num=t):
                 params, opt_state, loss = self._step(
@@ -187,6 +191,9 @@ class Trainer:
             traced = moe.path_counts - moe_paths
             if traced:
                 self.counters["moe_path"] = dict(traced)
+            traced = newton_schulz.path_counts - applies
+            if traced:
+                self.counters["polar_apply"] = dict(traced)
         if self._ckpt_thread is not None:
             self._ckpt_thread.join()
         return params, opt_state, losses

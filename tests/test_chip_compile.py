@@ -2,7 +2,8 @@
 
 Compiles (never runs) each kernel for a described ``v5e:2x2`` topology
 at gpt2-paper's real Muon bucket shapes — the §7 grid kernels (gram,
-matmul_add, sketch_chain) — and the §10 fused trio at a shape its VMEM
+the Gram side's power product matmul_add_upper, matmul_add,
+sketch_chain) — and the §10 fused trio at a shape its VMEM
 model accepts, in fp32 and bf16, and checks that the Mosaic kernel is in
 the compiled program; and the model's fused causal-attention kernel,
 forward and backward, at gpt2-paper's attention shape.  What the chip's
@@ -90,7 +91,8 @@ def test_gpt2_buckets_cover_both_tiers(gpt2_buckets):
 
 
 @pytest.mark.parametrize("dtype", DTYPES)
-@pytest.mark.parametrize("kernel", ["gram", "matmul_add", "sketch_chain"])
+@pytest.mark.parametrize("kernel", ["gram", "matmul_add_upper", "matmul_add",
+                                    "sketch_chain"])
 def test_grid_kernel_compiles(one_chip, gpt2_buckets, kernel, dtype):
     for B, m, n in gpt2_buckets:
         X = _spec((B, m, n), dtype, one_chip)
@@ -98,6 +100,10 @@ def test_grid_kernel_compiles(one_chip, gpt2_buckets, kernel, dtype):
         if kernel == "gram":
             _assert_kernel(functools.partial(gram.gram_upper,
                                              interpret=False), X)
+        elif kernel == "matmul_add_upper":
+            al = _spec((B,), "float32", one_chip)
+            _assert_kernel(lambda r, a: gram.matmul_add_upper(
+                r, r, a, r, beta=0.5, interpret=False), R, al)
         elif kernel == "matmul_add":
             _assert_kernel(lambda a, r, c: matmul_add.matmul_add(
                 a, r, c, beta=0.5, interpret=False), X, R, X)

@@ -58,7 +58,7 @@ def test_gram_sweep(key, m, n, dtype):
     X = jax.random.normal(key, (m, n), jnp.float32).astype(dtype)
     U = gram_kernel.gram_upper(X, alpha=1.0, beta=-1.0, bn=64, bk=64,
                                interpret=True)
-    got = gram_kernel.mirror_upper(U, min(64, n))
+    got = gram_kernel.mirror_upper(U)
     want = ref.gram(X, alpha=1.0, beta=-1.0)
     np.testing.assert_allclose(got.astype(jnp.float32),
                                want.astype(jnp.float32), **_tol(dtype))
@@ -129,7 +129,7 @@ def test_gram_batch_grid(key, B, m, n):
     X = jax.random.normal(key, (B, m, n))
     U = gram_kernel.gram_upper(X, alpha=1.0, beta=-1.0, bn=64, bk=64,
                                interpret=True)
-    got = gram_kernel.mirror_upper(U, min(64, n))
+    got = gram_kernel.mirror_upper(U)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref.gram(X)),
                                rtol=2e-5, atol=2e-5)
 
@@ -195,7 +195,7 @@ def test_fuzz_gram(key, B, m, k, n, dtype):
     X = jax.random.normal(key, (B, m, k), jnp.float32).astype(dtype)
     U = gram_kernel.gram_upper(X, alpha=1.0, beta=-1.0, bn=32, bk=32,
                                interpret=True)
-    got = gram_kernel.mirror_upper(U, min(32, k))
+    got = gram_kernel.mirror_upper(U)
     want = ref.gram(X, alpha=1.0, beta=-1.0)
     np.testing.assert_allclose(got.astype(jnp.float32),
                                want.astype(jnp.float32), **_tol(dtype))

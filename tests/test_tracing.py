@@ -13,6 +13,8 @@ from repro.config import OptimizerConfig, PrismConfig, TrainConfig
 from repro.configs import get_smoke_config
 from repro.data import DataConfig
 from repro.models import build
+from repro.optim import bucketing
+from repro.optim.muon import matrix_view_shapes
 from repro.train import Trainer
 from repro.train import trainer as trainer_mod
 
@@ -136,6 +138,19 @@ def test_attention_path_is_counted_when_the_step_traces(tmp_path):
     dots = re.findall(r' dot\(.*op_name="([^"]*)"', text)
     assert any("/jvp(layers)/" in n and "/attn_chunked/" in n for n in dots)
     assert not any("/attn_kernel/" in n for n in dots)
+
+
+def test_polar_apply_is_counted_when_the_step_traces(tmp_path):
+    tr = _trainer(tmp_path, steps=2)
+    assert "polar_apply" not in tr.counters
+    tr.run()
+    # the jnp chain runs every Muon bucket unfused: three warm updates
+    # a bucket, each on the Gram side (degree 2)
+    shapes = matrix_view_shapes(tr.model.param_shapes(),
+                                tr.model.logical_axes())
+    buckets = bucketing.plan_buckets(shapes, pad=OCFG.bucket_pad,
+                                     pad_slack=OCFG.bucket_pad_slack)
+    assert tr.counters["polar_apply"] == {"gram_side": 3 * len(buckets)}
 
 
 def _sleeping_step(slow_at, base=0.02, slow=0.4):
