@@ -48,10 +48,9 @@ SMOKE_CELLS = [(128, 2)]           # subset-scale: same row names
 PERIOD = 4
 
 
-def _make(n: int, layers: int, use_kernels: bool = False,
-          **kw) -> tuple:
+def _make(n: int, layers: int, **kw) -> tuple:
     prism = PrismConfig(degree=2, iterations=3, warm_alpha_iters=1,
-                        sketch_dim=8, use_kernels=use_kernels)
+                        sketch_dim=8)
     kw.setdefault("precond_every", PERIOD)
     cfg = OptimizerConfig(name="muon", learning_rate=0.02, prism=prism,
                           **kw)
@@ -110,8 +109,7 @@ def _step_costs(n: int, layers: int) -> dict:
         try:
             from repro.kernels import ops
 
-            kacfg, kaopt, _ = _make(n, layers, precond_async=True,
-                                    use_kernels=True)
+            kacfg, kaopt, _ = _make(n, layers, precond_async=True)
             kastate = kaopt.init(params)
             pending = base.install_pending(
                 kastate, kaopt.refresh(kastate, key), at_step=0)
@@ -126,7 +124,7 @@ def _step_costs(n: int, layers: int) -> dict:
                                                refresh=False), g, pending))
             cell["launches_refresh"] = ops.count_launches(
                 lambda s: kaopt.refresh(s, key), kastate)
-            kscfg, ksopt, _ = _make(n, layers, use_kernels=True)
+            kscfg, ksopt, _ = _make(n, layers)
             cell["launches_blocking_step"] = ops.count_launches(
                 lambda gg, s: ksopt.update(gg, s, params, 0, key,
                                            refresh=True),

@@ -18,14 +18,14 @@ Reported per (n, B) cell:
     fp32 upcasts, so bf16 wall clock is expected NEUTRAL-to-slower here
     (``bf16_speedup`` documents it); the HBM model is the TPU claim,
   * compile time of the first call (B unrolled chains vs one),
-  * Pallas launches per step for the kernel path (counted by tracing with
+  * Pallas launches per step (counted by tracing with
     REPRO_KERNEL_MODE=interpret and the interpret-size cutoff disabled —
-    counting only traces): per-leaf scales as B * (2 + d), bucketed
-    stays constant at 2 + d, and the count is dtype-independent,
-  * the §10 fused-iteration tier's contract (``launches_fused``: 1 launch
-    per warm tail + 2 per fitted iteration, dtype-blind) and its modeled
-    HBM bytes (``hbm_bytes_fused_*`` per fitted iteration,
-    ``hbm_bytes_warm_tail_*`` per whole tail) next to the §7 numbers.
+    counting only traces), on the tier the shape picks (``fused_fits_*``):
+    per-leaf scales with B, bucketed stays constant, and the count is
+    dtype-independent,
+  * the §10 fused-iteration tier's modeled HBM bytes
+    (``hbm_bytes_fused_*`` per fitted iteration, ``hbm_bytes_warm_tail_*``
+    per whole tail) next to the §7 numbers.
 
 The ``adaptive`` section (DESIGN.md §11) documents instance-adaptive
 iteration counts: at one shared residual target ``tol``, a
@@ -63,20 +63,13 @@ OUT = os.path.join(os.path.dirname(os.path.dirname(__file__)),
                    "BENCH_batched_matfn.json")
 
 
-def _prism_cfg(n: int, use_kernels: bool = False,
-               dtype: str = "float32", fuse: str = "off") -> PrismConfig:
-    # the per-leaf/bucketed engines pin fuse="off" so their cells keep
-    # measuring the §7 batch-grid tier; the fused engine forces "on" so
-    # its cells document the §10 contract on every n (the auto tier's
-    # budget decision is recorded separately as fused_fits)
+def _prism_cfg(n: int, dtype: str = "float32") -> PrismConfig:
     return PrismConfig(degree=2, iterations=3 if n <= 256 else 2,
-                       warm_alpha_iters=1, sketch_dim=8,
-                       use_kernels=use_kernels, dtype=dtype, fuse=fuse)
+                       warm_alpha_iters=1, sketch_dim=8, dtype=dtype)
 
 
-def _engines(n: int, use_kernels: bool = False, dtype: str = "float32",
-             fuse: str = "off"):
-    cfg = _prism_cfg(n, use_kernels, dtype, fuse)
+def _engines(n: int, dtype: str = "float32"):
+    cfg = _prism_cfg(n, dtype)
 
     def per_leaf(views, key):
         return [matfn.polar(v, method="prism", cfg=cfg,
@@ -218,26 +211,16 @@ def run(write_json: bool = True):
                 os.environ["REPRO_KERNEL_MODE"] = "interpret"
                 os.environ["REPRO_INTERPRET_MAX_ELEMS"] = "0"
                 try:
-                    pl_k, bu_k = _engines(n, use_kernels=True)
+                    pl_k, bu_k = _engines(n)
                     cell["launches_per_leaf"] = _count_launches(pl_k, views,
                                                                 key)
                     cell["launches_bucketed"] = _count_launches(bu_k, views,
                                                                 key)
-                    # dtype-independence of the §7 contract: the bf16
+                    # dtype-independence of the launch contract: the bf16
                     # engine must trace the SAME launch structure
-                    _, bu16 = _engines(n, use_kernels=True,
-                                       dtype="bfloat16")
+                    _, bu16 = _engines(n, dtype="bfloat16")
                     cell["launches_bucketed_bf16"] = _count_launches(
                         bu16, views, key)
-                    # §10 fused tier: warm tail 1 launch + 2 per fitted
-                    # iteration, independent of B, d, max_power and dtype
-                    _, fu_k = _engines(n, use_kernels=True, fuse="on")
-                    cell["launches_fused"] = _count_launches(fu_k, views,
-                                                             key)
-                    _, fu16 = _engines(n, use_kernels=True,
-                                       dtype="bfloat16", fuse="on")
-                    cell["launches_fused_bf16"] = _count_launches(
-                        fu16, views, key)
                 finally:
                     for var, old in [("REPRO_KERNEL_MODE", prev),
                                      ("REPRO_INTERPRET_MAX_ELEMS",
@@ -275,8 +258,7 @@ def run(write_json: bool = True):
             cell["hbm_bytes_fp32"] = hbm_bytes_per_iter(n, B, "float32")
             cell["hbm_bytes_bf16"] = hbm_bytes_per_iter(n, B, "bfloat16")
             # §10 fused-tier modeled HBM: per fitted iteration and per
-            # whole warm tail, plus the auto tier's budget decision for
-            # this shape at the default REPRO_VMEM_BUDGET
+            # whole warm tail, plus the tier this shape takes
             from repro.kernels import ops as kops
 
             cell["hbm_bytes_fused_fp32"] = hbm_bytes_per_iter_fused(
@@ -287,8 +269,8 @@ def run(write_json: bool = True):
                 n, B, "float32")
             cell["hbm_bytes_warm_tail_bf16"] = hbm_bytes_warm_tail(
                 n, B, "bfloat16")
-            # the auto tier's decision is dtype-dependent (bf16 halves
-            # the working set), so record both
+            # the tier is dtype-dependent (bf16 halves the working set),
+            # so record both
             cell["fused_fits_fp32"] = bool(kops.fused_fits((n, n),
                                                            "float32"))
             cell["fused_fits_bf16"] = bool(kops.fused_fits((n, n),
@@ -297,9 +279,7 @@ def run(write_json: bool = True):
             extra = ({"launches_per_leaf": cell["launches_per_leaf"],
                       "launches_bucketed": cell["launches_bucketed"],
                       "launches_bucketed_bf16":
-                          cell["launches_bucketed_bf16"],
-                      "launches_fused": cell["launches_fused"],
-                      "launches_fused_bf16": cell["launches_fused_bf16"]}
+                          cell["launches_bucketed_bf16"]}
                      if count_launches else {})
             emit(f"batched_matfn_n{n}_B{B}", 1e3 * cell["bucketed_ms"],
                  per_leaf_ms=cell["per_leaf_ms"],
@@ -320,8 +300,8 @@ def run(write_json: bool = True):
                "large-n CPU cells are flop-bound and XLA-CPU schedules a "
                "batched dot_general slightly worse than a loop of 2-D "
                "GEMMs, so speedup < 1 there is a host artifact; on the "
-               "TPU kernel path the same cells collapse B*(2+d) Pallas "
-               "launches to 2+d (see launches_per_leaf/launches_bucketed).",
+               "TPU the same cells collapse B per-leaf chains of Pallas "
+               "launches to one (see launches_per_leaf/launches_bucketed).",
                "dtype axis (DESIGN.md §9): bucketed_bf16_ms runs the "
                "bf16-compute/fp32-accumulate policy.  XLA-CPU emulates "
                "bf16 through fp32 upcasts, so CPU bf16 wall clock is "
@@ -329,21 +309,17 @@ def run(write_json: bool = True):
                "expected, not a regression); the accelerator claim is "
                "hbm_bytes_bf16 = hbm_bytes_fp32 / 2 at identical launch "
                "counts (launches_bucketed_bf16 == launches_bucketed).",
-               "fused axis (DESIGN.md §10): launches_fused traces the "
-               "fused-iteration tier (fuse='on'): 1 launch for the warm "
-               "tail + 2 per fitted iteration, vs warm*(1+d) + "
-               "fitted*(2+d) on the §7 tier — and dtype-blind "
-               "(launches_fused_bf16 == launches_fused).  "
-               "hbm_bytes_fused_* model one fitted iteration (5 matrices "
-               "+ the sketch vs 2 + (4d+2) + 4d on §7 — degree- and "
-               "max_power-independent because R and the Horner "
-               "accumulator never leave VMEM); hbm_bytes_warm_tail_* "
-               "model an ENTIRE warm tail (one read + one write of X).  "
-               "fused_fits_{fp32,bf16} record the auto tier's "
-               "trace-time decision for this n per compute dtype at the "
-               "default REPRO_VMEM_BUDGET (bf16 halves the working set, "
-               "so it can fuse where fp32 cannot); the launch counts "
-               "force fuse='on' so every cell documents the contract.",
+               "fused axis (DESIGN.md §10): the launch counts trace "
+               "the tier the shape picks, fused_fits_{fp32,bf16} (bf16 "
+               "halves the working set, so it can fuse where fp32 "
+               "cannot): on the fused tier 1 launch for the warm tail + "
+               "2 per fitted iteration, vs warm*(1+d) + fitted*(2+d) on "
+               "the §7 tier.  hbm_bytes_fused_* model one fitted "
+               "iteration (5 matrices + the sketch vs 2 + (4d+2) + 4d on "
+               "§7 — degree- and max_power-independent because R and "
+               "the Horner accumulator never leave VMEM); "
+               "hbm_bytes_warm_tail_* model an ENTIRE warm tail (one "
+               "read + one write of X).",
                "adaptive axis (DESIGN.md §11): at one residual target "
                "tol, the Gaussian bucket's iters_mean must sit strictly "
                "below iters_max_ill — the fixed iteration count a "

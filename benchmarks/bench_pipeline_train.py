@@ -97,9 +97,7 @@ def _child_main(argv):
         steps=args.steps, checkpoint_every=0,
         ckpt_dir=tempfile.mkdtemp(prefix="bench_pipe_"),
         async_precond=True, seq_len=SEQ_LEN, global_batch=GLOBAL_BATCH,
-        data=data, model_ax=model_ax,
-        use_kernels=args.count_launches,
-        num_layers=max(2, args.stages))
+        data=data, model_ax=model_ax, num_layers=max(2, args.stages))
 
     row = {
         "model": args.arch, "stages": args.stages,

@@ -43,10 +43,9 @@ SMOKE_CELLS = [(128, 2)]
 PERIOD = 4
 
 
-def _make(n: int, layers: int, use_kernels: bool = False, **kw):
+def _make(n: int, layers: int, **kw):
     prism = PrismConfig(degree=2, iterations=3, warm_alpha_iters=1,
-                        sketch_dim=8, tol=1e-2,
-                        use_kernels=use_kernels)
+                        sketch_dim=8, tol=1e-2)
     kw.setdefault("precond_every", PERIOD)
     kw.setdefault("matfn_tol", 1e-2)
     cfg = OptimizerConfig(name="muon", learning_rate=0.02, prism=prism,
@@ -101,7 +100,6 @@ def _guard_overhead(n: int, layers: int) -> dict:
             for skip in (False, True):
                 kcfg, kopt, params_k = _make(n, layers,
                                              precond_async=True,
-                                             use_kernels=True,
                                              skip_nonfinite=skip)
                 kstate = kopt.init(params_k)
                 gk = _grads(params_k, key)
@@ -111,8 +109,7 @@ def _guard_overhead(n: int, layers: int) -> dict:
                                               refresh=False), gk, kstate)
             # matfn-level: the status read is launch-free too
             mcfg = PrismConfig(degree=2, iterations=3, warm_alpha_iters=1,
-                               sketch_dim=8, tol=1e-2, use_kernels=True,
-                               fuse="on")
+                               sketch_dim=8, tol=1e-2)
             A = jnp.zeros((4, n, n))
             cell["matfn_launches_plain"] = ops.count_launches(
                 lambda a: matfn.polar(a, method="prism", cfg=mcfg,

@@ -112,8 +112,6 @@ BATCHED_MATFN_ROW = {
     "launches_bucketed": _pos_int,
     "launches_bucketed_bf16": _pos_int,
     # §10 fused-iteration tier axis
-    "launches_fused": _pos_int,
-    "launches_fused_bf16": _pos_int,
     "hbm_bytes_fused_fp32": _pos_int,
     "hbm_bytes_fused_bf16": _pos_int,
     "hbm_bytes_warm_tail_fp32": _pos_int,
@@ -142,21 +140,12 @@ def _check_batched_matfn_row(row: dict, where: str):
                     f"{row.get('launches_bucketed_bf16')} != "
                     f"{row['launches_bucketed']}")
     # §10 invariants: the fused tier halves nothing by dtype games — bf16
-    # still exactly halves bytes, counts stay dtype-blind, and the fused
-    # tier strictly beats the §7 tier on both launches and modeled HBM
+    # still exactly halves bytes, and the fused tier strictly beats the
+    # §7 tier on modeled HBM
     if _is_num(row.get("hbm_bytes_fused_fp32")) and \
             _is_num(row.get("hbm_bytes_fused_bf16")) and \
             row["hbm_bytes_fused_bf16"] * 2 != row["hbm_bytes_fused_fp32"]:
         errs.append(f"{where}: hbm_bytes_fused_bf16 must be half of fp32")
-    if "launches_fused" in row and \
-            row.get("launches_fused_bf16") != row["launches_fused"]:
-        errs.append(f"{where}: fused launch counts are dtype-dependent")
-    if _is_num(row.get("launches_fused")) and \
-            _is_num(row.get("launches_bucketed")) and \
-            not row["launches_fused"] < row["launches_bucketed"]:
-        errs.append(f"{where}: launches_fused must beat launches_bucketed "
-                    f"({row['launches_fused']} vs "
-                    f"{row['launches_bucketed']})")
     if _is_num(row.get("hbm_bytes_fused_fp32")) and \
             _is_num(row.get("hbm_bytes_fp32")) and \
             not row["hbm_bytes_fused_fp32"] < row["hbm_bytes_fp32"]:

@@ -74,6 +74,9 @@ class MatfnPrecision:
 class PrismConfig:
     """Configuration of the PRISM matrix-function engine.
 
+    How each product runs is not configured here: kernels/ops.py picks
+    the backend from the platform and the kernel tier from the shape.
+
     Attributes:
       degree: d in g_d(xi; alpha) = f_{d-1}(xi) + alpha xi^d.  degree=1 is
         the 3rd-order Newton-Schulz family, degree=2 the 5th-order family.
@@ -86,19 +89,9 @@ class PrismConfig:
         trick; preserves the quadratic-convergence guarantee by Lemma B.1).
       alpha_bounds: override [l, u]; None selects the paper's defaults
         ([1/2, 1] for d=1, [3/8, 29/20] for d=2).
-      use_kernels: route GEMM hot spots through the Pallas kernels (TPU);
-        False uses pure-jnp reference paths (CPU tests, oracles).
       dtype: COMPUTE dtype of the iteration (operands, iterates, sketch);
         accumulation and the alpha fit stay fp32 regardless — see
         ``precision`` / MatfnPrecision (DESIGN.md §9).
-      fuse: the single-launch fused-iteration kernel tier (DESIGN.md §10).
-        "auto" engages it per call when the iteration's whole working set
-        fits the VMEM budget (kernels/ops.py::fused_fits — a trace-time,
-        batch-size-independent shape test); "on"/"off" force it.  Only
-        meaningful with ``use_kernels``.
-      vmem_budget: VMEM budget in bytes for the fused tier (and the
-        sketch-chain size guard).  0 defers to ``REPRO_VMEM_BUDGET`` or
-        the built-in default (kernels/ops.py).
       tol: convergence certificate for ADAPTIVE early stopping
         (DESIGN.md §11).  When set, every FITTED iteration reads the
         sketched residual estimate est_r ~ ||R_k||_F off the trace chain
@@ -134,17 +127,11 @@ class PrismConfig:
     iterations: int = 5
     warm_alpha_iters: int = 0
     alpha_bounds: Optional[Tuple[float, float]] = None
-    use_kernels: bool = False
     dtype: str = "float32"
-    fuse: str = "auto"
-    vmem_budget: int = 0
     tol: Optional[float] = None
     divergence_factor: float = 10.0
 
     def __post_init__(self):
-        if self.fuse not in ("auto", "on", "off"):
-            raise ValueError(f"PrismConfig.fuse must be auto|on|off, "
-                             f"got {self.fuse!r}")
         if self.tol is not None and not self.tol > 0.0:
             raise ValueError(f"PrismConfig.tol must be positive or None, "
                              f"got {self.tol!r}")
@@ -346,12 +333,6 @@ class OptimizerConfig:
     # the PRISM fit stay fp32 regardless (MatfnPrecision pins them).
     # "float32" (default) defers to prism.dtype untouched.
     matfn_dtype: str = "float32"
-    # VMEM budget (bytes) for the fused single-launch iteration tier and
-    # the sketch-chain size guard (DESIGN.md §10).  0 defers to the
-    # REPRO_VMEM_BUDGET env var / built-in default; threads into
-    # resolved_prism so bucketing and the iteration families share one
-    # number.  The tier itself stays per-bucket automatic (prism.fuse).
-    vmem_budget: int = 0
     # adaptive early stopping (DESIGN.md §11): convergence certificate for
     # the fitted matfn iterations — a bucket slice freezes once its
     # sketched residual estimate drops to tol, so prism.iterations becomes
@@ -518,15 +499,13 @@ class OptimizerConfig:
 
     @property
     def resolved_prism(self) -> PrismConfig:
-        """PrismConfig with ``matfn_dtype`` (and ``vmem_budget``) threaded
+        """PrismConfig with ``matfn_dtype`` (and ``matfn_tol``) threaded
         in.  The default matfn_dtype="float32" leaves an explicitly
         configured prism.dtype alone."""
         out = self.prism
         if self.matfn_dtype != "float32" and \
                 self.matfn_dtype != out.dtype:
             out = dataclasses.replace(out, dtype=self.matfn_dtype)
-        if self.vmem_budget and self.vmem_budget != out.vmem_budget:
-            out = dataclasses.replace(out, vmem_budget=self.vmem_budget)
         if self.matfn_tol is not None and self.matfn_tol != out.tol:
             out = dataclasses.replace(out, tol=self.matfn_tol)
         return out

@@ -16,7 +16,8 @@ import jax.numpy as jnp
 
 from repro.core import polynomials as poly
 from repro.core import prism
-from repro.core.newton_schulz import IterInfo, _mm, _safe_fro
+from repro.core.newton_schulz import IterInfo, _safe_fro
+from repro.kernels import ops as kops
 
 
 def inv(A: jax.Array, iters: int = 20, method: str = "prism",
@@ -70,8 +71,8 @@ def inv(A: jax.Array, iters: int = 20, method: str = "prism",
 
     def step(X_, R, a):
         ab = a.astype(dtype)[..., None, None]
-        XR = _mm(X_, R)
-        return X_ + XR + ab * _mm(XR, R)
+        XR = kops.matmul_add(X_, R)
+        return X_ + XR + ab * kops.matmul_add(XR, R)
 
     if adaptive:
         out_it, used, status = prism.adaptive_masked_loop(

@@ -22,7 +22,8 @@ import jax.numpy as jnp
 from repro.config import PrismConfig
 from repro.core import polynomials as poly
 from repro.core import prism
-from repro.core.newton_schulz import IterInfo, _fro, _mm
+from repro.core.newton_schulz import IterInfo, _fro
+from repro.kernels import ops as kops
 
 
 def inv_proot(A: jax.Array, p: int, iters: int = 20, method: str = "prism",
@@ -73,10 +74,10 @@ def inv_proot(A: jax.Array, p: int, iters: int = 20, method: str = "prism",
         ab = a.astype(dtype)[..., None, None]
         T = eye + ab * (eye - M_)
         # fp32-accumulated chain products (DESIGN.md §9)
-        Xn = _mm(X_, T)
+        Xn = kops.matmul_add(X_, T)
         Mn = M_
         for _ in range(p):
-            Mn = _mm(T, Mn)
+            Mn = kops.matmul_add(T, Mn)
         return Xn, Mn
 
     if adaptive:

@@ -45,7 +45,8 @@ import jax.numpy as jnp
 
 from repro.config import PrismConfig
 from repro.core import sketch as sk
-from repro.core.newton_schulz import _fro, _mm
+from repro.core.newton_schulz import _fro
+from repro.kernels import ops as kops
 
 
 def _gaussian_test_matrix(key: jax.Array, n: int, l: int,
@@ -74,13 +75,13 @@ def rangefinder(M: jax.Array, l: int, key: jax.Array,
 
     n = M.shape[-1]
     Om = _gaussian_test_matrix(jax.random.fold_in(key, 0), n, l, M.dtype)
-    Y = _mm(M, Om, cfg.use_kernels)
+    Y = kops.matmul_add(M, Om)
     Mt = jnp.swapaxes(M, -1, -2)
     for _ in range(power_iters):
         # per-slice fro rescale between products: keeps the power
         # iterates away from bf16 overflow without touching directions
         Y = Y / jnp.maximum(_fro(Y).astype(Y.dtype), 1e-30)
-        Y = _mm(M, _mm(Mt, Y, cfg.use_kernels), cfg.use_kernels)
+        Y = kops.matmul_add(M, kops.matmul_add(Mt, Y))
     return matfn.polar(Y, method=method, cfg=cfg,
                        key=jax.random.fold_in(key, 1))
 
@@ -110,14 +111,14 @@ def polar_lowrank(M: jax.Array, rank: int, oversample: int,
     l = min(rank + oversample, n)
     Q = rangefinder(X, l, jax.random.fold_in(key, 0), cfg=cfg,
                     method=method, power_iters=power_iters)
-    B = _mm(jnp.swapaxes(Q, -1, -2), X, cfg.use_kernels)  # [..., l, n]
+    B = kops.matmul_add(jnp.swapaxes(Q, -1, -2), X)  # [..., l, n]
     P = matfn.polar(B, method=method, cfg=cfg,
                     key=jax.random.fold_in(key, 1),
                     return_iters=return_iters,
                     return_status=return_status)
     if return_iters or return_status:
         P, *aux = P
-    O = _mm(Q, P, cfg.use_kernels)
+    O = kops.matmul_add(Q, P)
     O = jnp.swapaxes(O, -1, -2) if transpose else O
     if return_iters or return_status:
         return (O, *aux)

@@ -17,15 +17,15 @@ Phase structure (DESIGN.md §10): every chain is an explicit sequence of
 WARM phases — maximal runs of iterations whose alpha is a static Python
 float (the PRISM warm-start value u, or the classical Taylor coefficient,
 which makes a whole classical chain one phase) — and FIT iterations,
-whose alpha is the sketched argmin and therefore data-dependent.  With
-``use_kernels`` and the fused kernel tier engaged (``cfg.fuse``, chosen
-at trace time from the matrix shape against the VMEM budget), a warm
-phase runs as ONE multi-iteration Pallas launch with X ping-ponging in
-VMEM, and a fit iteration as TWO launches: fused residual+sketch-chain,
-then the fused d-GEMM Horner application — the closed-form alpha
-minimization runs between them in XLA, which is exactly why the fit
-phase cannot fuse across iterations (alpha_{k+1} needs the traces of
-R_{k+1}).
+whose alpha is the sketched argmin and therefore data-dependent.  Every
+product runs through kernels/ops.py, which picks the backend from the
+platform and the tier from the shape: when the per-slice working set
+fits the VMEM budget (``ops.fused_fits``), a warm phase runs as ONE
+multi-iteration Pallas launch with X ping-ponging in VMEM, and a fit
+iteration as TWO launches: fused residual+sketch-chain, then the fused
+d-GEMM Horner application — the closed-form alpha minimization runs
+between them in XLA, which is exactly why the fit phase cannot fuse
+across iterations (alpha_{k+1} needs the traces of R_{k+1}).
 
 Adaptive early stopping (DESIGN.md §11): with ``cfg.tol`` set, each
 maximal run of fitted iterations becomes one ``lax.while_loop`` whose
@@ -51,6 +51,7 @@ from repro.config import PrismConfig
 from repro.core import polynomials as poly
 from repro.core import prism
 from repro.core import sketch as sk
+from repro.kernels import ops as kops
 
 
 # trace-time count of ``apply_g`` calls by evaluation order: "gram_side"
@@ -87,64 +88,8 @@ def _safe_fro(M: jax.Array) -> jax.Array:
     return jnp.maximum(_fro(M), jnp.float32(np.finfo(np.float32).tiny))
 
 
-def _mm(A, B, use_kernels=False, alpha=1.0, C=None, beta=0.0):
-    """alpha * A @ B (+ beta * C), optionally through the Pallas kernel.
-
-    The jnp path mirrors the kernels' accumulation semantics exactly
-    (DESIGN.md §9): the dot accumulates fp32 regardless of the operand
-    dtype, the epilogue runs on the fp32 accumulator, and only the final
-    result rounds back to the compute dtype — bit-matching ref.matmul_add.
-    """
-    if use_kernels:
-        from repro.kernels import ops as kops
-
-        return kops.matmul_add(A, B, C=C, alpha=alpha, beta=beta)
-    out = jnp.matmul(A, B, preferred_element_type=jnp.float32)
-    if alpha != 1.0:
-        out = alpha * out
-    if C is not None:
-        out = out + beta * C.astype(jnp.float32)
-    return out.astype(A.dtype)
-
-
-def _gram_residual(X: jax.Array, use_kernels: bool) -> jax.Array:
-    """R = I - X^T X (symmetric; Pallas syrk kernel when enabled).
-
-    jnp path: fp32-accumulated Gram + fp32 epilogue, rounded once to the
-    compute dtype (matches ref.gram / the kernel, DESIGN.md §9).
-    """
-    if use_kernels:
-        from repro.kernels import ops as kops
-
-        return kops.gram(X, alpha=1.0, beta=-1.0)
-    Xt = jnp.swapaxes(X, -1, -2)
-    G = jnp.matmul(Xt, X, preferred_element_type=jnp.float32)
-    eye = jnp.eye(X.shape[-1], dtype=jnp.float32)
-    return (eye - G).astype(X.dtype)
-
-
-def _sym_mm(A, B, use_kernels, alpha, C=None, beta=0.0):
-    """alpha * A @ B (+ beta * C) where the result is symmetric (A, B, C
-    polynomials in one symmetric R), alpha fp32 per slice ([...] or
-    scalar).  The kernel computes the upper block triangle and mirrors
-    it (kernels/gram.py); the jnp path keeps _mm's accumulation order
-    with alpha applied to the fp32 accumulator — never rounded to the
-    compute dtype."""
-    if use_kernels:
-        from repro.kernels import ops as kops
-
-        return kops.sym_matmul_add(A, B, C, alpha=alpha, beta=beta)
-    a = jnp.asarray(alpha, jnp.float32)
-    out = a[..., None, None] * jnp.matmul(A, B,
-                                          preferred_element_type=jnp.float32)
-    if C is not None:
-        out = out + beta * C.astype(jnp.float32)
-    return out.astype(A.dtype)
-
-
 def apply_g(X: jax.Array, R: jax.Array, alpha, d: int,
-            side: str = "right", use_kernels: bool = False,
-            family: str = "polar") -> jax.Array:
+            side: str = "right", family: str = "polar") -> jax.Array:
     """X @ g_d(R; alpha)  (side='right')  or  g_d(R; alpha) @ X  (side='left').
 
     g_d(x; a) = f_{d-1}(x) + a x^d with f the Taylor series of (1-x)^{-1/2}.
@@ -152,13 +97,14 @@ def apply_g(X: jax.Array, R: jax.Array, alpha, d: int,
     The polar family at d >= 2 evaluates it on the Gram side (DESIGN.md
     §7): Q = a R^d + f_{d-1} R^{d-1} + ... + f_1 R is formed as an
     [n, n] matrix by d - 1 power products (Horner on R: Q = a R R +
-    f_{d-1} R, then Q <- R Q + f_j R), each on the upper block triangle,
-    since R = I - X^T X and every polynomial in it are symmetric; Q then
-    reaches the tall iterate in ONE product X Q + f_0 X.  Every other
-    case (d = 1, which has no power product; the sign family, whose R is
-    not symmetric; the coupled sqrt family) runs Horner on the iterate,
-    a chain of d GEMMs that never forms g(R).  ``path_counts`` counts
-    the calls each order took at trace time.
+    f_{d-1} R, then Q <- R Q + f_j R), each on the upper block triangle
+    (``ops.sym_matmul_add``), since R = I - X^T X and every polynomial
+    in it are symmetric; Q then reaches the tall iterate in ONE product
+    X Q + f_0 X.  Every other case (d = 1, which has no power product;
+    the sign family, whose R is not symmetric; the coupled sqrt family)
+    runs Horner on the iterate, a chain of d GEMMs that never forms
+    g(R).  ``path_counts`` counts the calls each order took at trace
+    time.
 
     alpha is applied IN fp32 (DESIGN.md §9): the PRISM fit is pinned
     fp32, so under a bf16 compute policy the fitted alpha multiplies an
@@ -173,12 +119,12 @@ def apply_g(X: jax.Array, R: jax.Array, alpha, d: int,
     alpha = jnp.asarray(alpha, jnp.float32)
     if family == "polar" and d >= 2:
         path_counts["gram_side"] += 1
-        Q = _sym_mm(R, R, use_kernels, alpha, C=R, beta=float(f[d - 1]))
+        Q = kops.sym_matmul_add(R, R, R, alpha=alpha, beta=float(f[d - 1]))
         for j in range(d - 2, 0, -1):
-            Q = _sym_mm(R, Q, use_kernels, 1.0, C=R, beta=float(f[j]))
+            Q = kops.sym_matmul_add(R, Q, R, alpha=1.0, beta=float(f[j]))
         if side == "right":
-            return _mm(X, Q, use_kernels, C=X, beta=float(f[0]))
-        return _mm(Q, X, use_kernels, C=X, beta=float(f[0]))
+            return kops.matmul_add(X, Q, C=X, beta=float(f[0]))
+        return kops.matmul_add(Q, X, C=X, beta=float(f[0]))
     path_counts["horner"] += 1
     if alpha.ndim:
         alpha = alpha[..., None, None]
@@ -186,12 +132,12 @@ def apply_g(X: jax.Array, R: jax.Array, alpha, d: int,
     if side == "right":
         # X (f0 I + f1 R + ... + a R^d) = f0 X + (f1 X + (... + a X R) R) R
         for j in range(d - 1, 0, -1):
-            acc = _mm(acc, R, use_kernels, C=X, beta=float(f[j]))
-        return _mm(acc, R, use_kernels, C=X, beta=float(f[0]))
+            acc = kops.matmul_add(acc, R, C=X, beta=float(f[j]))
+        return kops.matmul_add(acc, R, C=X, beta=float(f[0]))
     else:
         for j in range(d - 1, 0, -1):
-            acc = _mm(R, acc, use_kernels, C=X, beta=float(f[j]))
-        return _mm(R, acc, use_kernels, C=X, beta=float(f[0]))
+            acc = kops.matmul_add(R, acc, C=X, beta=float(f[j]))
+        return kops.matmul_add(R, acc, C=X, beta=float(f[0]))
 
 
 def _classical_alpha(d: int) -> float:
@@ -255,27 +201,10 @@ def _phase_plan(iters: int, cfg: PrismConfig,
     return phases
 
 
-def _fused_tier(cfg: PrismConfig, mshape, return_info: bool,
-                coupled: bool = False) -> bool:
-    """Trace-time fused-tier choice: kernels on, not a diagnostics run
-    (return_info needs per-iteration residuals the fused launches never
-    materialize), and the per-slice working set fits the VMEM budget."""
-    if not cfg.use_kernels or return_info or cfg.fuse == "off":
-        return False
-    if cfg.fuse == "on":
-        return True
-    from repro.kernels import ops as kops
-
-    return kops.fused_fits(mshape, jnp.dtype(cfg.dtype), coupled=coupled,
-                           budget=cfg.vmem_budget)
-
-
 def _fused_fit_step(X, cfg: PrismConfig, k: int, key, n_real,
                     family: str, Y=None):
     """One fitted iteration in TWO launches: fused residual+sketch-chain,
     the XLA closed-form alpha fit, then the fused Horner application."""
-    from repro.kernels import ops as kops
-
     apoly = poly.newton_schulz_residual(cfg.degree)
     lo, hi = cfg.bounds
     n = X.shape[-1]
@@ -317,8 +246,6 @@ def _adaptive_fit_run(X, Y, cfg: PrismConfig, k0: int, count: int, key,
     lo, hi = cfg.bounds
     n = X.shape[-1]
     use_fused_fit = fused and key is not None and cfg.sketch_dim > 0
-    if fused:
-        from repro.kernels import ops as kops
 
     def fit(it, k):
         """(R, alpha, est_r) for iteration k (k is traced)."""
@@ -336,10 +263,7 @@ def _adaptive_fit_run(X, Y, cfg: PrismConfig, k0: int, count: int, key,
         kk = prism.alpha_schedule_key(key, k) if key is not None else None
         a, est = prism.fit_alpha(R, apoly, lo, hi, key=kk,
                                  sketch_dim=cfg.sketch_dim,
-                                 use_kernels=cfg.use_kernels,
-                                 n_real=n_real,
-                                 vmem_budget=cfg.vmem_budget,
-                                 return_est_r=True)
+                                 n_real=n_real, return_est_r=True)
         return R, a, est
 
     def step(it, R, a):
@@ -348,9 +272,8 @@ def _adaptive_fit_run(X, Y, cfg: PrismConfig, k0: int, count: int, key,
             out = kops.apply_g(X_, R, a, degree=cfg.degree, Y=Y_)
             Xn, Yn = out if coupled else (out, None)
         else:
-            Xn = apply_g(X_, R, a, cfg.degree, "right", cfg.use_kernels,
-                         family)
-            Yn = apply_g(Y_, R, a, cfg.degree, "left", cfg.use_kernels,
+            Xn = apply_g(X_, R, a, cfg.degree, "right", family)
+            Yn = apply_g(Y_, R, a, cfg.degree, "left",
                          family) if coupled else None
         return {"X": Xn, "Y": Yn} if coupled else {"X": Xn}
 
@@ -370,8 +293,9 @@ def _run_phases(X, cfg: PrismConfig, method: str, iters: int, key,
     path; ``Y`` is non-None only for the coupled sqrt family (both
     iterates then update per phase).  Returns (X, Y, alphas, fros,
     iters_used): the info lists are populated only under ``return_info``
-    (which disables the fused tier — see _fused_tier — and the adaptive
-    engine, whose per-iteration quantities a dynamic loop cannot stack);
+    (which disables the fused tier, whose launches never materialize the
+    per-iteration residuals, and the adaptive engine, whose
+    per-iteration quantities a dynamic loop cannot stack);
     ``iters_used`` is the per-matrix count of applied updates, shape
     ``X.shape[:-2]`` — the static total unless ``cfg.tol`` turns the fit
     phases adaptive (§11).  ``status`` is the per-matrix int8 guardian
@@ -379,9 +303,8 @@ def _run_phases(X, cfg: PrismConfig, method: str, iters: int, key,
     runs (all-zeros on static chains, which carry no certificate).
     """
     coupled = Y is not None
-    fused = _fused_tier(cfg, X.shape[-2:], return_info, coupled=coupled)
-    if fused:
-        from repro.kernels import ops as kops
+    fused = not return_info and kops.fused_fits(
+        X.shape[-2:], jnp.dtype(cfg.dtype), coupled=coupled)
     alphas, fros = [], []
     iters_used = jnp.zeros(X.shape[:-2], jnp.int32)
     status = jnp.zeros(X.shape[:-2], jnp.int8)
@@ -400,11 +323,9 @@ def _run_phases(X, cfg: PrismConfig, method: str, iters: int, key,
             for a in payload:
                 R = residual_fn(X, Y)
                 aa = jnp.full(R.shape[:-2], a, dtype=jnp.float32)
-                X = apply_g(X, R, aa, cfg.degree, "right", cfg.use_kernels,
-                            family)
+                X = apply_g(X, R, aa, cfg.degree, "right", family)
                 if coupled:
-                    Y = apply_g(Y, R, aa, cfg.degree, "left",
-                                cfg.use_kernels, family)
+                    Y = apply_g(Y, R, aa, cfg.degree, "left", family)
                 if return_info:
                     alphas.append(aa)
                     fros.append(_fro(R)[..., 0, 0])
@@ -429,11 +350,9 @@ def _run_phases(X, cfg: PrismConfig, method: str, iters: int, key,
                 X, Y = unpack(kops.apply_g(X, R, a, degree=cfg.degree,
                                            Y=Y))
             else:
-                X = apply_g(X, R, a, cfg.degree, "right", cfg.use_kernels,
-                            family)
+                X = apply_g(X, R, a, cfg.degree, "right", family)
                 if coupled:
-                    Y = apply_g(Y, R, a, cfg.degree, "left",
-                                cfg.use_kernels, family)
+                    Y = apply_g(Y, R, a, cfg.degree, "left", family)
             if return_info:
                 alphas.append(a)
                 fros.append(_fro(R)[..., 0, 0])
@@ -491,7 +410,7 @@ def polar(A: jax.Array, cfg: Optional[PrismConfig] = None,
     X = X.astype(cfg.dtype) / _safe_fro(X).astype(cfg.dtype)
     X, _, alphas, fros, used, status = _run_phases(
         X, cfg, method, iters, key, return_info, "polar",
-        lambda x, y: _gram_residual(x, cfg.use_kernels), n_real=n_real)
+        lambda x, y: kops.gram(x), n_real=n_real)
     X = jnp.swapaxes(X, -1, -2) if transpose else X
     X = X.astype(in_dtype)
     return _with_telemetry(X, (alphas, fros), used, return_info,
@@ -503,13 +422,13 @@ def polar(A: jax.Array, cfg: Optional[PrismConfig] = None,
 # ---------------------------------------------------------------------------
 
 
-def _coupled_residual(X, Y, use_kernels: bool):
+def _coupled_residual(X, Y):
     # R = I - Y X (Thm 3 coupling: X <- X h(YX), Y <- h(YX) Y).  This is
     # Higham's numerically *stable* coupled form; the R = I - X Y variant
     # written in the paper's Table-1 "Residual" column is the classically
     # unstable coupling and diverges right after convergence (verified
     # empirically in fp64 — see tests/test_matfn.py::test_sqrt_stability).
-    R = _eye_like(X) - _mm(Y, X, use_kernels)
+    R = _eye_like(X) - kops.matmul_add(Y, X)
     return 0.5 * (R + jnp.swapaxes(R, -1, -2))  # stability: re-symmetrize
 
 
@@ -534,7 +453,7 @@ def sqrtm(A: jax.Array, cfg: Optional[PrismConfig] = None,
     Y = jnp.broadcast_to(_eye_like(X), X.shape)
     X, Y, alphas, fros, used, status = _run_phases(
         X, cfg, method, iters, key, return_info, "sqrt",
-        lambda x, y: _coupled_residual(x, y, cfg.use_kernels), Y=Y)
+        _coupled_residual, Y=Y)
     sqrt_c = jnp.sqrt(c)
     out = (X * sqrt_c).astype(in_dtype), (Y / sqrt_c).astype(in_dtype)
     return _with_telemetry(out, (alphas, fros), used, return_info,
@@ -559,7 +478,7 @@ def signm(A: jax.Array, cfg: Optional[PrismConfig] = None,
     X = A.astype(cfg.dtype) / _safe_fro(A).astype(cfg.dtype)
     X, _, alphas, fros, used, status = _run_phases(
         X, cfg, method, iters, key, return_info, "sign",
-        lambda x, y: _eye_like(x) - _mm(x, x, cfg.use_kernels))
+        lambda x, y: _eye_like(x) - kops.matmul_add(x, x))
     X = X.astype(in_dtype)
     return _with_telemetry(X, (alphas, fros), used, return_info,
                            return_iters, status, return_status)

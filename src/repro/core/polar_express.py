@@ -19,7 +19,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.core.newton_schulz import _mm  # fp32-accumulate (DESIGN.md §9)
+from repro.kernels import ops as kops  # fp32-accumulate (DESIGN.md §9)
 
 # Algorithm 1 of Amsel et al. (2025), sigma_min = 1e-3 variant.
 POLAR_EXPRESS_COEFFS: Tuple[Tuple[float, float, float], ...] = (
@@ -54,12 +54,12 @@ def polar(A: jax.Array, iters: int = 8, dtype=jnp.float32,
     fros = []
     for k in range(iters):
         a, b, c = _coeff(k)
-        M = _mm(jnp.swapaxes(X, -1, -2), X)
+        M = kops.matmul_add(jnp.swapaxes(X, -1, -2), X)
         if return_info:
             eye = jnp.eye(M.shape[-1], dtype=M.dtype)
             fros.append(_fro(eye - M)[..., 0, 0])
-        M2 = _mm(M, M)
-        X = a * X + b * _mm(X, M) + c * _mm(X, M2)
+        M2 = kops.matmul_add(M, M)
+        X = a * X + b * kops.matmul_add(X, M) + c * kops.matmul_add(X, M2)
     X = jnp.swapaxes(X, -1, -2) if transpose else X
     X = X.astype(in_dtype)
     if return_info:
@@ -83,15 +83,15 @@ def sqrtm(A: jax.Array, iters: int = 8, dtype=jnp.float32,
     fros = []
     for k in range(iters):
         a, b, c = _coeff(k)
-        M = _mm(Y, X)
+        M = kops.matmul_add(Y, X)
         if return_info:
             eye = jnp.eye(M.shape[-1], dtype=M.dtype)
             fros.append(_fro(eye - M)[..., 0, 0])
-        M2 = _mm(M, M)
+        M2 = kops.matmul_add(M, M)
         H = a * jnp.broadcast_to(jnp.eye(M.shape[-1], dtype=M.dtype), M.shape) \
             + b * M + c * M2
-        X = _mm(X, H)
-        Y = _mm(H, Y)
+        X = kops.matmul_add(X, H)
+        Y = kops.matmul_add(H, Y)
     sc = jnp.sqrt(c0)
     out = (X * sc).astype(in_dtype), (Y / sc).astype(in_dtype)
     if return_info:

@@ -2,7 +2,7 @@
 
 Given the residual matrix R_k of any Table-1 iteration, the engine
 
-  1. sketches power traces t_i = tr(S_k R_k^i S_k^T)     (core/sketch.py)
+  1. sketches power traces t_i = tr(S_k R_k^i S_k^T)  (ops.sketch_traces)
   2. maps them through the algorithm's fixed trace-weight matrix W to get
      the coefficients of the quartic (degree-2s) objective m(alpha)
   3. minimizes m over the constraint interval [l, u] in closed form.
@@ -21,6 +21,7 @@ import numpy as np
 from repro.config import PrismConfig
 from repro.core import polynomials as poly
 from repro.core import sketch as sk
+from repro.kernels import ops as kops
 
 
 def fit_alpha(
@@ -30,9 +31,7 @@ def fit_alpha(
     hi: float,
     key: Optional[jax.Array] = None,
     sketch_dim: int = 8,
-    use_kernels: bool = False,
     n_real: Optional[jax.Array] = None,
-    vmem_budget: int = 0,
     return_est_r: bool = False,
 ):
     """alpha~_k = argmin_{alpha in [lo, hi]} || S h(R; alpha) ||_F^2.
@@ -52,9 +51,6 @@ def fit_alpha(
         recovers the traces of R_real exactly — the fitted alpha is
         bit-identical to the unpadded fit with sketch S[:, :n_real]
         (DESIGN.md §7).
-      vmem_budget: override (bytes) for the chain kernel's VMEM guard on
-        the use_kernels path (DESIGN.md §10); threaded from
-        PrismConfig.vmem_budget by resolve_alpha.
       return_est_r: also return the convergence certificate est_r (see
         ``fit_alpha_from_traces``) read off the same trace chain.
 
@@ -81,8 +77,7 @@ def fit_alpha(
         return fit_alpha_from_traces(t, apoly, lo, hi,
                                      return_est_r=return_est_r)
     S = sk.gaussian_sketch(key, sketch_dim, n, dtype=R.dtype)
-    t = sk.sketched_power_traces(R, S, max_pow, use_kernels=use_kernels,
-                                 vmem_budget=vmem_budget)
+    t = kops.sketch_traces(R, S, max_pow)
     return fit_alpha_from_traces(t, apoly, lo, hi, S=S, n_real=n_real,
                                  return_est_r=return_est_r)
 
@@ -270,5 +265,4 @@ def resolve_alpha(
     if key is not None:
         key = alpha_schedule_key(key, k)
     return fit_alpha(R, apoly, lo, hi, key=key, sketch_dim=cfg.sketch_dim,
-                     use_kernels=cfg.use_kernels, n_real=n_real,
-                     vmem_budget=cfg.vmem_budget)
+                     n_real=n_real)

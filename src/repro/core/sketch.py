@@ -7,7 +7,9 @@ oblivious subspace embedding; PRISM only needs the sketched power traces
 
 computed by the chained products V_i = R V_{i-1} with V_0 = S^T, so the
 total cost is O(n^2 p max_power) — negligible next to the O(n^3) GEMMs of
-one Newton-Schulz iteration.
+one Newton-Schulz iteration.  The chain itself is a kernel
+(kernels/ops.py::sketch_traces); this module draws S and holds the exact,
+unsketched traces.
 
 Note: the paper's Theorem 2 types the entries as N(1, 1/p); the OSE
 literature it cites (Balabanov & Nouy 2019, Prop. 3.7) uses mean-zero
@@ -27,43 +29,6 @@ def gaussian_sketch(key: jax.Array, p: int, n: int, dtype=jnp.float32) -> jax.Ar
     """S in R^{p x n} with i.i.d. N(0, 1/p) entries."""
     return jax.random.normal(key, (p, n), dtype=dtype) / jnp.sqrt(
         jnp.asarray(p, dtype=dtype))
-
-
-def sketched_power_traces(R: jax.Array, S: jax.Array, max_power: int,
-                          use_kernels: bool = False,
-                          vmem_budget: int = 0) -> jax.Array:
-    """t_i = tr(S R^i S^T) for i = 0..max_power.
-
-    Args:
-      R: residual matrix [..., n, n] (symmetric).
-      S: sketch [p, n].
-      max_power: largest power (4d+2 for Newton-Schulz degree d).
-      use_kernels: route the chained R @ V products + trace epilogue through
-        the Pallas ``sketch_traces`` kernel.
-      vmem_budget: override (bytes) for the chain kernel's VMEM guard
-        (DESIGN.md §10); 0 defers to REPRO_VMEM_BUDGET / the default.
-
-    Returns: [..., max_power + 1] stacked traces (fp32).
-    """
-    if use_kernels:
-        from repro.kernels import ops as kops
-
-        return kops.sketch_traces(R, S, max_power, budget=vmem_budget)
-    # Accumulation semantics match the fused chain kernel (DESIGN.md §9):
-    # each product R @ V accumulates fp32, the trace epilogue reduces the
-    # fp32 accumulator (NOT the rounded V'), and only the V that feeds the
-    # next power rounds back to the compute dtype.
-    St = S.T.astype(R.dtype)  # [n, p]
-    St32 = St.astype(jnp.float32)
-    V = jnp.broadcast_to(St, R.shape[:-2] + St.shape)
-    traces = [jnp.sum(St32 * St32)
-              * jnp.ones(R.shape[:-2], dtype=jnp.float32)]
-    for _ in range(max_power):
-        Vacc = jnp.matmul(R, V, preferred_element_type=jnp.float32)
-        # tr(S R^i S^T) = sum_{jk} S^T[j,k] * (R^i S^T)[j,k]
-        traces.append(jnp.sum(St32 * Vacc, axis=(-2, -1)))
-        V = Vacc.astype(R.dtype)
-    return jnp.stack(traces, axis=-1)
 
 
 def exact_power_traces(R: jax.Array, max_power: int) -> jax.Array:

@@ -1,16 +1,21 @@
 """jit'd public wrappers around the Pallas kernels.
 
-Dispatch policy:
-  * on TPU backends the compiled Mosaic kernels run natively, always: a
-    ``REPRO_KERNEL_MODE`` other than auto/native raises there;
-  * on CPU ``interpret=True`` executes the kernel bodies
-    in Python for correctness validation, or — when ``REPRO_KERNEL_MODE=ref``
-    or the shapes are large — the pure-jnp oracle in ref.py is used so CPU
-    benchmarks aren't dominated by the interpreter.  "Large" is a per-call
-    operand-size cutoff on the interpret path: any operand above
-    ``REPRO_INTERPRET_MAX_ELEMS`` elements (default 2**21; 0 disables the
-    cutoff) falls back to ref, so CPU kernel-validation runs don't crawl
-    through the Python interpreter on production-sized buckets.
+Dispatch policy — the one place that decides how a chain product runs
+(every matrix-function chain calls these wrappers unconditionally):
+  * backend, from the platform: on a TPU the compiled Mosaic kernels
+    run, always (a ``REPRO_KERNEL_MODE`` other than auto/native raises
+    there).  Elsewhere the pure-jnp oracle in ref.py runs, or, under
+    ``REPRO_KERNEL_MODE=interpret``, the kernel bodies in the Pallas
+    interpreter for correctness validation; an interpret call with any
+    operand above ``REPRO_INTERPRET_MAX_ELEMS`` elements (default 2**21;
+    0 disables the cutoff) falls back to the oracle, so validation runs
+    don't crawl through the interpreter on production-sized buckets.
+  * tier, from the shape: a chain whose per-slice working set fits
+    ``VMEM_BUDGET`` (``fused_fits``) runs the fused-iteration tier
+    below, any other the batch-grid kernels; the sketch chain's
+    whole-chain kernel obeys the same budget (``sketch_traces``).  The
+    test is batch-size blind because the batch dim is the streamed grid
+    dimension.
 
 All wrappers accept leading batch dimensions, which are collapsed into the
 single batch-grid dimension of the kernels (DESIGN.md §7): a whole
@@ -23,10 +28,7 @@ same accumulation semantics, so dispatch mode never changes the contract.
 
 Fused-iteration tier (DESIGN.md §10): ``residual_chain`` / ``apply_g`` /
 ``warm_tail`` collapse a fitted iteration to 2 launches and a whole
-constant-alpha run to 1.  The tier is chosen at trace time per bucket by
-``fused_fits`` — a pure shape test against the VMEM budget
-(REPRO_VMEM_BUDGET / config ``vmem_budget``), independent of the batch
-size because the batch dim is the streamed grid dimension.
+constant-alpha run to 1.
 """
 from __future__ import annotations
 
@@ -43,17 +45,10 @@ from repro.kernels import sketch_traces as _sk
 
 _LANE = 128  # TPU lane width: sketch dim padded up to this
 _DEFAULT_INTERPRET_MAX_ELEMS = 1 << 21
-# Fused-tier VMEM budget (DESIGN.md §10): ~16 MiB/core minus headroom for
-# the grid pipeline's double buffering.  Override via REPRO_VMEM_BUDGET
-# or the PrismConfig/OptimizerConfig ``vmem_budget`` knob.
-_DEFAULT_VMEM_BUDGET = 12 << 20
-
-
-def vmem_budget(override: int = 0) -> int:
-    """Effective VMEM budget in bytes: config override > env > default."""
-    if override:
-        return int(override)
-    return int(os.environ.get("REPRO_VMEM_BUDGET", _DEFAULT_VMEM_BUDGET))
+# VMEM budget of the fused tier and the whole-chain sketch kernel
+# (DESIGN.md §10): ~16 MiB/core minus headroom for the grid pipeline's
+# double buffering.
+VMEM_BUDGET = 12 << 20
 
 
 def fused_vmem_bytes(mshape, dtype, *, coupled: bool = False,
@@ -83,11 +78,9 @@ def fused_vmem_bytes(mshape, dtype, *, coupled: bool = False,
     return mats + resid + horner + chain
 
 
-def fused_fits(mshape, dtype, *, coupled: bool = False,
-               budget: int = 0) -> bool:
+def fused_fits(mshape, dtype, *, coupled: bool = False) -> bool:
     """Trace-time fused-tier choice for a bucket of [m, n] matrices."""
-    return fused_vmem_bytes(mshape, dtype, coupled=coupled) <= \
-        vmem_budget(budget)
+    return fused_vmem_bytes(mshape, dtype, coupled=coupled) <= VMEM_BUDGET
 
 
 def polar_flops(mshape, *, iters: int, degree: int = 2) -> int:
@@ -259,8 +252,7 @@ def _chain_vmem_bytes(n: int, p: int, dtype, bn: int) -> int:
     return 3 * N * p * item + 2 * bn * bn * item + bn * p * 4
 
 
-def sketch_traces(R, S, max_power: int, *, bn: int = 256,
-                  budget: int = 0):
+def sketch_traces(R, S, max_power: int, *, bn: int = 256):
     """t_i = tr(S R^i S^T), i = 0..max_power; one fused chain launch.
 
     ``bn`` tiles both the rows and the contraction dim of the chain (they
@@ -269,11 +261,10 @@ def sketch_traces(R, S, max_power: int, *, bn: int = 256,
 
     VMEM guard (DESIGN.md §10): the whole-chain kernel keeps St and two
     V ping-pong buffers — O(n * 128) bytes — resident for the entire
-    launch with no size bound.  When that footprint exceeds the VMEM
-    budget (``budget`` override, else REPRO_VMEM_BUDGET), the chain
-    falls back to a loop of bounded-footprint per-step ``sketch_step``
-    launches: max_power launches instead of one, but never an
-    over-budget kernel.
+    launch with no size bound.  When that footprint exceeds
+    ``VMEM_BUDGET``, the chain falls back to a loop of bounded-footprint
+    per-step ``sketch_step`` launches: max_power launches instead of one,
+    but never an over-budget kernel.
     """
     mode = _mode(R)
     if mode == "ref":
@@ -285,8 +276,7 @@ def sketch_traces(R, S, max_power: int, *, bn: int = 256,
     (Rb,) = _collapse(lead, R) if lead else (R[None],)
     t0 = jnp.sum(St.astype(jnp.float32) * St.astype(jnp.float32))
     n = R.shape[-1]
-    if _chain_vmem_bytes(n, St.shape[1], R.dtype, bn) <= \
-            vmem_budget(budget):
+    if _chain_vmem_bytes(n, St.shape[1], R.dtype, bn) <= VMEM_BUDGET:
         ts = _sk.sketch_chain(Rb, St, max_power, bn=bn, interpret=interp)
     else:
         V = jnp.broadcast_to(St, Rb.shape[:-2] + St.shape)

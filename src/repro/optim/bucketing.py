@@ -13,9 +13,9 @@ times per step.  This module collapses that dispatch layer:
      (leading scanned-layer dims of a view flatten into B, near-miss
      shapes zero-pad to the bucket shape);
   3. one batched PRISM call runs per bucket — a single residual, a single
-     shared-sketch alpha fit broadcast over B, and (with use_kernels) a
-     constant number of batch-grid Pallas launches per iteration,
-     independent of B and of the sketch chain length;
+     shared-sketch alpha fit broadcast over B, and a constant number of
+     batch-grid Pallas launches per iteration, independent of B and of
+     the sketch chain length;
   4. ``scatter_bucket`` splits, un-pads and reshapes the results back.
 
 Zero-padding is exact for the Newton-Schulz polar iterations (pad
@@ -49,14 +49,13 @@ that are dropped after the gather).
 """
 from __future__ import annotations
 
-import dataclasses
 from typing import Callable, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from repro import sharding_ctx
-from repro.config import OptimizerConfig, PrismConfig
+from repro.config import OptimizerConfig
 from repro.core import matfn
 from repro.scopes import scoped
 
@@ -192,42 +191,21 @@ def _gram_real_dims(bucket: Bucket) -> jax.Array:
     return jnp.asarray(reals, jnp.int32)
 
 
-def resolve_fused_tier(pcfg: PrismConfig, bucket: Bucket,
-                       coupled: bool = False) -> PrismConfig:
-    """Pin the fused-iteration tier (DESIGN.md §10) for one bucket.
-
-    The choice is made HERE, at trace time, from the bucket's static
-    matrix shape against the VMEM budget — it is batch-size independent
-    (the batch dim streams through the fused grids), so the same tier
-    serves the replicated bucket and every §8 per-device slice.  "auto"
-    resolves to an explicit "on"/"off" so the downstream newton_schulz
-    phase loop never re-derives it; forced values pass through.
-    """
-    if pcfg.fuse != "auto" or not pcfg.use_kernels:
-        return pcfg
-    from repro.kernels import ops as kops
-
-    m, n = bucket.shape
-    mshape = (max(m, n), min(m, n))  # polar transposes to m >= n
-    fits = kops.fused_fits(mshape, jnp.dtype(pcfg.dtype), coupled=coupled,
-                           budget=pcfg.vmem_budget)
-    return dataclasses.replace(pcfg, fuse="on" if fits else "off")
-
-
 def resolve_lowrank_tier(cfg: OptimizerConfig,
                          mshape: Tuple[int, int]) -> Optional[int]:
     """Sketch width l when a bucket routes the §14 lowrank tier, None for
     the cubic (§7/§10) tiers.
 
-    Like ``resolve_fused_tier`` this is a trace-time, batch-size-blind
-    choice from the bucket's static matrix shape.  The tier engages when
-    (a) it is enabled (``cfg.lowrank_rank > 0``) and the view's shape
-    crosses the size threshold — max dim above ``lowrank_max_dim`` or
-    aspect ratio at least ``lowrank_aspect`` — and (b) projection
-    actually wins: l = rank + oversample leaves a strict subspace
-    (l < min(m, n)) and the modeled projected-chain FLOPs beat the cubic
-    path (kernels/ops.py), so pathological knob choices degrade to the
-    exact tiers instead of a slower "acceleration".
+    Like the fused tier's ``ops.fused_fits`` this is a trace-time,
+    batch-size-blind choice from the bucket's static matrix shape.  The
+    tier engages when (a) it is enabled (``cfg.lowrank_rank > 0``) and
+    the view's shape crosses the size threshold — max dim above
+    ``lowrank_max_dim`` or aspect ratio at least ``lowrank_aspect`` —
+    and (b) projection actually wins: l = rank + oversample leaves a
+    strict subspace (l < min(m, n)) and the modeled projected-chain
+    FLOPs beat the cubic path (kernels/ops.py), so pathological knob
+    choices degrade to the exact tiers instead of a slower
+    "acceleration".
     """
     if not cfg.lowrank_rank:
         return None
@@ -262,10 +240,12 @@ def resolve_tier(cfg: OptimizerConfig, mshape: Tuple[int, int]) -> str:
     logic — usable from tests/telemetry without building a Bucket."""
     if resolve_lowrank_tier(cfg, mshape) is not None:
         return "lowrank"
-    pcfg = resolve_fused_tier(
-        cfg.resolved_prism,
-        Bucket((int(mshape[-2]), int(mshape[-1])), (), 0))
-    return "fused" if pcfg.use_kernels and pcfg.fuse == "on" else "grid"
+    from repro.kernels import ops as kops
+
+    m, n = int(mshape[-2]), int(mshape[-1])
+    fits = kops.fused_fits((max(m, n), min(m, n)),  # polar: m >= n
+                           jnp.dtype(cfg.resolved_prism.dtype))
+    return "fused" if fits else "grid"
 
 
 # ------------------------------------------------------------------ sharding
@@ -413,10 +393,10 @@ def polar_bucketed(views: Sequence[jax.Array], cfg: OptimizerConfig,
                 # which is never padded (plan_buckets pads the Gram side of
                 # the FULL view only, and zero pad rows/cols stay zero
                 # through the sketch -> project -> lift composition), so no
-                # n_real correction is threaded.  The §10 fuse choice
+                # n_real correction is threaded.  The §10 tier choice
                 # resolves inside the inner polar calls from the SMALL
-                # [m, l] / [l, n] shapes (newton_schulz._fused_tier), not
-                # from the full bucket shape.
+                # [m, l] / [l, n] shapes (ops.fused_fits), not from the
+                # full bucket shape.
                 from repro.core import lowrank as lr
 
                 def run(x, _kk=kk, _l=lowrank_l):
@@ -429,16 +409,15 @@ def polar_bucketed(views: Sequence[jax.Array], cfg: OptimizerConfig,
             else:
                 n_real = (_gram_real_dims(b)
                           if b.padded and method == "prism" else None)
-                pcfg_b = resolve_fused_tier(pcfg, b)
 
-                def run(x, *nr, _kk=kk, _pcfg=pcfg_b):
+                def run(x, *nr, _kk=kk):
                     if method == "svd":
                         return matfn.polar(x, method="svd")
                     kw = {"n_real": nr[0]} if nr else {}
                     if with_iters:  # NS family only (asserted above)
                         kw["return_iters"] = True
                         kw["return_status"] = True
-                    return matfn.polar(x, method=method, cfg=_pcfg, key=_kk,
+                    return matfn.polar(x, method=method, cfg=pcfg, key=_kk,
                                        **kw)
 
             if mesh is not None and not local_reshard:
@@ -525,8 +504,8 @@ def transform_bucketed(mats: Sequence[jax.Array], fn,
     folding), never to index companion arrays by entry offset.
 
     Fused tier: fn's inner matfn calls carry their own PrismConfig, so
-    the §10 tier resolves inside the iteration family (newton_schulz
-    ``_fused_tier``) from the same static bucket shape — callers pick it
+    the §10 tier resolves inside the iteration family (``ops.fused_fits``
+    in newton_schulz) from the same static bucket shape — callers pick it
     up with zero changes, exactly like ``polar_bucketed``.
     """
     n_aux = int(with_aux)

@@ -98,7 +98,7 @@ def build_pipeline_trainer(*, arch: str = "qwen3-14b", stages: int = 2,
                            checkpoint_every: int = 2, ckpt_dir: str,
                            async_precond: bool = False, seq_len: int = 32,
                            global_batch: int = 8, data: int = 2,
-                           model_ax: int = 2, use_kernels: bool = False,
+                           model_ax: int = 2,
                            num_layers: Optional[int] = None):
     """Construct (trainer, enter_ctx) for a smoke-scale 1F1B pipeline run
     on the host mesh: pod=stages slices the layer stack, (data, model)
@@ -135,7 +135,7 @@ def build_pipeline_trainer(*, arch: str = "qwen3-14b", stages: int = 2,
         name="muon", matfn_method="prism", precond_every=4,
         precond_async=async_precond, matfn_tol=1e-2,
         prism=PrismConfig(degree=2, iterations=3, warm_alpha_iters=3,
-                          sketch_dim=8, tol=1e-2, use_kernels=use_kernels))
+                          sketch_dim=8, tol=1e-2))
     tcfg = TrainConfig(steps=steps, checkpoint_dir=ckpt_dir,
                        checkpoint_every=checkpoint_every, log_every=100,
                        async_checkpoint=False, pipeline_stages=stages,

@@ -17,6 +17,7 @@ from repro.config import OptimizerConfig, PrismConfig
 from repro.core import matfn, prism, sketch
 from repro.core import polynomials as poly
 from repro.core import random_matrices as rm
+from repro.kernels import ops
 from repro.optim import bucketing, make_optimizer
 
 pytestmark = pytest.mark.tier1
@@ -59,7 +60,7 @@ def test_est_r_sketched_unbiased(key):
     ests = []
     for i in range(64):
         S = sketch.gaussian_sketch(jax.random.fold_in(key, i), 8, 16)
-        t = sketch.sketched_power_traces(R, S, poly.max_trace_power(apoly))
+        t = ops.sketch_traces(R, S, poly.max_trace_power(apoly))
         _, est = prism.fit_alpha_from_traces(t, apoly, 0.375, 1.45,
                                              return_est_r=True)
         ests.append(float(est) ** 2)
@@ -77,12 +78,11 @@ def test_est_r_pad_corrected(key):
     Rp = jnp.eye(npad).at[:n, :n].set(R)
     S = sketch.gaussian_sketch(jax.random.fold_in(key, 1), 8, npad)
     apoly = poly.newton_schulz_residual(2)
-    t = sketch.sketched_power_traces(Rp, S, poly.max_trace_power(apoly))
+    t = ops.sketch_traces(Rp, S, poly.max_trace_power(apoly))
     _, est = prism.fit_alpha_from_traces(
         t, apoly, 0.375, 1.45, S=S,
         n_real=jnp.asarray(n, jnp.int32)[None], return_est_r=True)
-    t_real = sketch.sketched_power_traces(R, S[:, :n],
-                                          poly.max_trace_power(apoly))
+    t_real = ops.sketch_traces(R, S[:, :n], poly.max_trace_power(apoly))
     _, est_real = prism.fit_alpha_from_traces(t_real, apoly, 0.375, 1.45,
                                               return_est_r=True)
     np.testing.assert_allclose(float(est[0]), float(est_real), rtol=1e-4)
@@ -274,8 +274,6 @@ def test_tol_none_bit_matches_pre_adaptive(key):
 # -------------------------- (c) launch contracts: tol- and dtype-blind (§10)
 
 def _count(fn, *args):
-    from repro.kernels import ops
-
     return ops.count_launches(fn, *args)
 
 
@@ -288,8 +286,7 @@ def test_launch_contract_adaptive_fused(monkeypatch, key, dtype):
     monkeypatch.setenv("REPRO_KERNEL_MODE", "interpret")
     for iters in (2, 5):
         for B in (1, 4):
-            cfg = _cfg(1e-2, dtype=dtype, iters=iters, warm=1,
-                       use_kernels=True, fuse="on")
+            cfg = _cfg(1e-2, dtype=dtype, iters=iters, warm=1)
             n = _count(lambda A: matfn.polar(A, method="prism", cfg=cfg,
                                              key=key),
                        jnp.zeros((B, 64, 48), jnp.dtype(dtype)))
@@ -298,13 +295,14 @@ def test_launch_contract_adaptive_fused(monkeypatch, key, dtype):
 
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 def test_launch_contract_adaptive_unfused(monkeypatch, key, dtype):
-    """§7 batch-grid tier (fuse=off): warm tail 1+d launches, fitted
-    body 2+d traced once — tol- and dtype-blind."""
+    """§7 batch-grid tier (under a VMEM budget that holds the sketch
+    chain but no fused iteration): warm tail 1+d launches, fitted body
+    2+d traced once — tol- and dtype-blind."""
     monkeypatch.setenv("REPRO_KERNEL_MODE", "interpret")
+    monkeypatch.setattr(ops, "VMEM_BUDGET", 200_000)
     d = 2
     for iters in (2, 5):
-        cfg = _cfg(1e-2, dtype=dtype, iters=iters, warm=1,
-                   use_kernels=True, fuse="off")
+        cfg = _cfg(1e-2, dtype=dtype, iters=iters, warm=1)
         n = _count(lambda A: matfn.polar(A, method="prism", cfg=cfg,
                                          key=key),
                    jnp.zeros((4, 64, 48), jnp.dtype(dtype)))
@@ -319,7 +317,7 @@ def test_launch_count_tol_blind(monkeypatch, key):
     monkeypatch.setenv("REPRO_KERNEL_MODE", "interpret")
 
     def n_launches(tol):
-        cfg = _cfg(tol, iters=3, warm=1, use_kernels=True, fuse="on")
+        cfg = _cfg(tol, iters=3, warm=1)
         return _count(lambda A: matfn.polar(A, method="prism", cfg=cfg,
                                             key=key),
                       jnp.zeros((4, 64, 64)))

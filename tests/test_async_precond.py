@@ -205,7 +205,7 @@ def test_steady_state_zero_matfn_launches(monkeypatch):
     ocfg = OptimizerConfig(
         name="muon", precond_every=4, precond_async=True,
         prism=PrismConfig(degree=2, iterations=2, warm_alpha_iters=1,
-                          sketch_dim=8, use_kernels=True))
+                          sketch_dim=8))
     opt = make_optimizer(ocfg, model.logical_axes())
     step_fn = make_train_step(model, opt, ocfg)
     params = master_params(model.init(key))

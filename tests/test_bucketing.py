@@ -8,6 +8,7 @@ import pytest
 from repro.config import OptimizerConfig, PrismConfig
 from repro.core import matfn, prism, sketch
 from repro.core import polynomials as poly
+from repro.kernels import ops
 from repro.optim import base, bucketing, make_optimizer
 
 pytestmark = pytest.mark.tier1
@@ -122,9 +123,9 @@ def test_pad_trace_correction_identity(key):
     R = 0.5 * (R + R.T)
     Rp = jnp.eye(padn).at[:n, :n].set(R)
     S = sketch.gaussian_sketch(jax.random.fold_in(key, 1), p, padn)
-    t_pad = sketch.sketched_power_traces(Rp, S, maxp)
+    t_pad = ops.sketch_traces(Rp, S, maxp)
     c = float(jnp.sum(jnp.square(S[:, n:])))
-    t_real = sketch.sketched_power_traces(R, S[:, :n], maxp)
+    t_real = ops.sketch_traces(R, S[:, :n], maxp)
     np.testing.assert_allclose(np.asarray(t_pad) - c, np.asarray(t_real),
                                rtol=1e-4, atol=1e-4)
 
@@ -168,9 +169,13 @@ def test_pad_invariance_sketched(key):
 # --------------------------------------------------- launch-count contract
 
 def _count_pallas_launches(fn, *args):
-    from repro.kernels import ops
-
     return ops.count_launches(fn, *args)
+
+
+# a VMEM budget that holds the whole sketch chain of a 64-wide slice but
+# no fused iteration: the §7 batch-grid tier, as the chip runs it at its
+# large buckets
+UNFUSED_BUDGET = 200_000
 
 
 @pytest.mark.parametrize("degree", [1, 2])
@@ -180,18 +185,19 @@ def test_constant_launch_count(monkeypatch, key, degree):
     chain length max_power = 4d+2: with the fused tier (the default for
     VMEM-fitting buckets, DESIGN.md §10) exactly 2 — residual+chain, then
     the fused Horner — independent of d as well; the §7 batch-grid tier
-    (fuse="off") keeps its 2 + d contract."""
+    (under a budget no fused iteration fits) keeps its 2 + d contract."""
     monkeypatch.setenv("REPRO_KERNEL_MODE", "interpret")
-    for fuse, want in (("auto", 2), ("off", 2 + degree)):
-        cfg = PrismConfig(degree=degree, iterations=1, warm_alpha_iters=0,
-                          sketch_dim=8, use_kernels=True, fuse=fuse)
+    cfg = PrismConfig(degree=degree, iterations=1, warm_alpha_iters=0,
+                      sketch_dim=8)
+    for budget, want in ((ops.VMEM_BUDGET, 2), (UNFUSED_BUDGET, 2 + degree)):
+        monkeypatch.setattr(ops, "VMEM_BUDGET", budget)
         counts = []
         for B in (1, 4, 16):
             A = jnp.zeros((B, 64, 48))
             counts.append(_count_pallas_launches(
                 lambda A: matfn.polar(A, method="prism", cfg=cfg, key=key),
                 A))
-        assert counts == [want] * 3, (fuse, counts)
+        assert counts == [want] * 3, (budget, counts)
 
 
 def test_trainer_skip_step_zero_matfn_launches(monkeypatch, key):
@@ -211,7 +217,7 @@ def test_trainer_skip_step_zero_matfn_launches(monkeypatch, key):
     ocfg = OptimizerConfig(
         name="muon", precond_every=4,
         prism=PrismConfig(degree=2, iterations=2, warm_alpha_iters=1,
-                          sketch_dim=8, use_kernels=True))
+                          sketch_dim=8))
     opt = make_optimizer(ocfg, model.logical_axes())
     step_fn = make_train_step(model, opt, ocfg)
     params = master_params(model.init(key))
@@ -230,9 +236,9 @@ def test_trainer_skip_step_zero_matfn_launches(monkeypatch, key):
 
 def test_fitted_iteration_launches_scale_with_iters_only(monkeypatch, key):
     monkeypatch.setenv("REPRO_KERNEL_MODE", "interpret")
-    def n_launches(iters, warm, fuse="auto"):
+    def n_launches(iters, warm):
         cfg = PrismConfig(degree=2, iterations=iters, warm_alpha_iters=warm,
-                          sketch_dim=8, use_kernels=True, fuse=fuse)
+                          sketch_dim=8)
         return _count_pallas_launches(
             lambda A: matfn.polar(A, method="prism", cfg=cfg, key=key),
             jnp.zeros((8, 64, 64)))
@@ -241,5 +247,6 @@ def test_fitted_iteration_launches_scale_with_iters_only(monkeypatch, key):
     assert n_launches(3, 1) == 1 + 2 * 2
     assert n_launches(3, 3) == 1
     # §7 batch-grid tier: fitted 2+d, warm skips the chain (1+d)
-    assert n_launches(3, 0, fuse="off") == 12
-    assert n_launches(3, 1, fuse="off") == 11
+    monkeypatch.setattr(ops, "VMEM_BUDGET", UNFUSED_BUDGET)
+    assert n_launches(3, 0) == 12
+    assert n_launches(3, 1) == 11

@@ -181,7 +181,7 @@ def test_fitted_iteration_two_launches(monkeypatch, key, degree, B):
     §7 tier still scaled as 2+d)."""
     monkeypatch.setenv("REPRO_KERNEL_MODE", "interpret")
     cfg = PrismConfig(degree=degree, iterations=1, warm_alpha_iters=0,
-                      sketch_dim=8, use_kernels=True, fuse="on")
+                      sketch_dim=8)
     A = jnp.zeros((B, 64, 48))
     n = _count(lambda A: matfn.polar(A, method="prism", cfg=cfg, key=key),
                A)
@@ -195,8 +195,7 @@ def test_warm_tail_single_launch(monkeypatch, key, warm, degree):
     length, of d, and of B."""
     monkeypatch.setenv("REPRO_KERNEL_MODE", "interpret")
     cfg = PrismConfig(degree=degree, iterations=warm,
-                      warm_alpha_iters=warm, sketch_dim=8,
-                      use_kernels=True, fuse="on")
+                      warm_alpha_iters=warm, sketch_dim=8)
     for B in (1, 8):
         A = jnp.zeros((B, 64, 48))
         n = _count(lambda A: matfn.polar(A, method="prism", cfg=cfg,
@@ -210,11 +209,11 @@ def test_whole_call_launches(monkeypatch, key):
     monkeypatch.setenv("REPRO_KERNEL_MODE", "interpret")
     A = jnp.zeros((4, 64, 64))
     cfg = PrismConfig(degree=2, iterations=5, warm_alpha_iters=2,
-                      sketch_dim=8, use_kernels=True, fuse="on")
+                      sketch_dim=8)
     n = _count(lambda A: matfn.polar(A, method="prism", cfg=cfg, key=key),
                A)
     assert n == 1 + 2 * 3, n
-    ccfg = PrismConfig(degree=2, iterations=8, use_kernels=True, fuse="on")
+    ccfg = PrismConfig(degree=2, iterations=8)
     n = _count(lambda A: matfn.polar(A, method="newton_schulz", cfg=ccfg),
                A)
     assert n == 1, n
@@ -226,8 +225,7 @@ def test_launches_dtype_blind(monkeypatch, key):
     counts = {}
     for dt in ("float32", "bfloat16"):
         cfg = PrismConfig(degree=2, iterations=4, warm_alpha_iters=2,
-                          sketch_dim=8, use_kernels=True, fuse="on",
-                          dtype=dt)
+                          sketch_dim=8, dtype=dt)
         A = jnp.zeros((3, 64, 48), jnp.dtype(dt))
         counts[dt] = _count(
             lambda A: matfn.polar(A, method="prism", cfg=cfg, key=key), A)
@@ -240,7 +238,7 @@ def test_coupled_sqrt_launch_contract(monkeypatch, key):
     monkeypatch.setenv("REPRO_KERNEL_MODE", "interpret")
     A = jnp.zeros((2, 48, 48)) + jnp.eye(48)
     cfg = PrismConfig(degree=2, iterations=4, warm_alpha_iters=2,
-                      sketch_dim=8, use_kernels=True, fuse="on")
+                      sketch_dim=8)
     n = _count(lambda A: matfn.sqrtm(A, cfg=cfg, key=key)[1], A)
     assert n == 1 + 2 * 2, n
 
@@ -251,15 +249,18 @@ def test_coupled_sqrt_launch_contract(monkeypatch, key):
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 def test_fused_vs_unfused_polar(monkeypatch, key, dtype):
     """Fused and unfused tiers agree at the dtype-principled tolerances
-    of tests/test_precision.py (fp32 fp-tight; bf16 O(u_bf16 kappa))."""
+    of tests/test_precision.py (fp32 fp-tight; bf16 O(u_bf16 kappa)).
+    A zero VMEM budget takes the unfused tier at the same shape."""
+    from repro.kernels import ops
+
     monkeypatch.setenv("REPRO_KERNEL_MODE", "interpret")
     A = jax.random.normal(key, (3, 72, 40)).astype(dtype)
+    cfg = PrismConfig(degree=2, iterations=8, warm_alpha_iters=2,
+                      sketch_dim=8, dtype=dtype)
     outs = {}
-    for fuse in ("on", "off"):
-        cfg = PrismConfig(degree=2, iterations=8, warm_alpha_iters=2,
-                          sketch_dim=8, use_kernels=True, fuse=fuse,
-                          dtype=dtype)
-        outs[fuse] = np.asarray(
+    for tier, budget in (("on", ops.VMEM_BUDGET), ("off", 0)):
+        monkeypatch.setattr(ops, "VMEM_BUDGET", budget)
+        outs[tier] = np.asarray(
             matfn.polar(A, method="prism", cfg=cfg, key=key), np.float32)
     err = np.linalg.norm(outs["on"] - outs["off"]) / \
         np.linalg.norm(outs["off"])
@@ -273,8 +274,7 @@ def test_fused_pad_to_bucket_invariance(key):
              for i, s in enumerate([(64, 64), (64, 56)])]
     ocfg = OptimizerConfig(prism=PrismConfig(degree=2, iterations=10,
                                              warm_alpha_iters=2,
-                                             sketch_dim=8,
-                                             use_kernels=True, fuse="on"),
+                                             sketch_dim=8),
                            bucket_pad=True)
     outs = bucketing.polar_bucketed(views, ocfg, key)
     for v, o in zip(views, outs):
@@ -284,34 +284,32 @@ def test_fused_pad_to_bucket_invariance(key):
 
 
 def test_tier_resolution_budget(monkeypatch):
-    """fused_fits is a pure trace-time shape test against the VMEM
-    budget: B never enters, tiny budgets force the §7 tier, and
-    bucketing pins auto -> on/off per bucket."""
+    """fused_fits is a pure trace-time shape test against VMEM_BUDGET:
+    B never enters, a tiny budget forces the §7 tier, and the planner's
+    tier telemetry reads the same test for either orientation."""
     from repro.kernels import ops
 
     assert ops.fused_fits((64, 48), jnp.float32)
     assert not ops.fused_fits((4096, 4096), jnp.float32)
-    monkeypatch.setenv("REPRO_VMEM_BUDGET", "1024")
-    assert not ops.fused_fits((64, 48), jnp.float32)
-    monkeypatch.delenv("REPRO_VMEM_BUDGET")
-    # config override beats the env default
-    assert not ops.fused_fits((64, 48), jnp.float32, budget=1024)
-
     b = bucketing.plan_buckets([(64, 48), (64, 48)])[0]
-    pc = PrismConfig(use_kernels=True)
-    assert bucketing.resolve_fused_tier(pc, b).fuse == "on"
-    assert bucketing.resolve_fused_tier(
-        PrismConfig(use_kernels=True, vmem_budget=1024), b).fuse == "off"
-    assert bucketing.resolve_fused_tier(
-        PrismConfig(use_kernels=True, fuse="off"), b).fuse == "off"
+    cfg = OptimizerConfig()
+    assert bucketing.resolve_tier(cfg, b.shape) == "fused"
+    assert bucketing.resolve_tier(cfg, (48, 64)) == "fused"
+    monkeypatch.setattr(ops, "VMEM_BUDGET", 1024)
+    assert not ops.fused_fits((64, 48), jnp.float32)
+    assert bucketing.resolve_tier(cfg, b.shape) == "grid"
+    assert bucketing.resolve_tier(cfg, (48, 64)) == "grid"
 
 
 def test_tier_auto_switches_launch_structure(monkeypatch, key):
-    """auto under a tiny budget falls back to the §7 per-launch tier
+    """Under a tiny budget the chain falls back to the §7 per-launch tier
     (2+d per fitted iteration); under the default budget it fuses."""
+    from repro.kernels import ops
+
     monkeypatch.setenv("REPRO_KERNEL_MODE", "interpret")
+    default = ops.VMEM_BUDGET
     cfg = PrismConfig(degree=2, iterations=1, warm_alpha_iters=0,
-                      sketch_dim=8, use_kernels=True)
+                      sketch_dim=8)
     A = jnp.zeros((2, 64, 48))
 
     def n_launches():
@@ -321,13 +319,13 @@ def test_tier_auto_switches_launch_structure(monkeypatch, key):
     assert n_launches() == 2
     # 200 KB: the whole-chain kernel still fits (so the §7 tier keeps its
     # single-launch chain) but the fused iteration working set does not
-    monkeypatch.setenv("REPRO_VMEM_BUDGET", "200000")
+    monkeypatch.setattr(ops, "VMEM_BUDGET", 200000)
     assert n_launches() == 2 + 2
     # 4 KB: the chain guard trips too — per-step fallback, max_power
     # launches for the chain alone (still bounded VMEM, never over budget)
-    monkeypatch.setenv("REPRO_VMEM_BUDGET", "4096")
+    monkeypatch.setattr(ops, "VMEM_BUDGET", 4096)
     assert n_launches() == 1 + 10 + 2
-    monkeypatch.delenv("REPRO_VMEM_BUDGET")
+    monkeypatch.setattr(ops, "VMEM_BUDGET", default)
     assert n_launches() == 2
 
 
@@ -337,7 +335,7 @@ def test_tier_auto_switches_launch_structure(monkeypatch, key):
 def test_alpha_enters_fp32(key):
     """The fitted fp32 alpha reaches the update unrounded: pre-rounding
     it to bf16 (the old `jnp.asarray(alpha, X.dtype)`) visibly changes
-    the result, and the jnp path matches the fused oracle for d=1 (where
+    the result, and the unfused path matches the fused oracle for d=1 (where
     the two accumulation orders coincide bit for bit)."""
     X = (jax.random.normal(key, (40, 32)) / 6).astype(jnp.bfloat16)
     R = ref._residual(X, family="polar")
@@ -382,19 +380,17 @@ def test_sketch_chain_vmem_guard(monkeypatch, key):
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-4, atol=2e-4)
 
-    monkeypatch.setenv("REPRO_VMEM_BUDGET", "4096")  # chain needs ~100KB
+    monkeypatch.setattr(ops, "VMEM_BUDGET", 4096)  # chain needs ~100KB
     assert _count(lambda R, S: ops.sketch_traces(R, S, maxp), R, S) == maxp
     got = ops.sketch_traces(R, S, maxp)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-4, atol=2e-4)
-    monkeypatch.delenv("REPRO_VMEM_BUDGET")
 
-    # the config knob reaches the guard too: a fitted unfused iteration
-    # with a tiny PrismConfig.vmem_budget falls back to the per-step
-    # chain — gram + max_power steps + d Horner launches
+    # a fitted iteration's chain obeys the same guard: under the tiny
+    # budget it runs unfused with the per-step chain — gram + max_power
+    # steps + the Gram side's 2 launches
     cfg = PrismConfig(degree=2, iterations=1, warm_alpha_iters=0,
-                      sketch_dim=8, use_kernels=True, fuse="off",
-                      vmem_budget=4096)
+                      sketch_dim=8)
     n = _count(lambda A: matfn.polar(A, method="prism", cfg=cfg, key=key),
                jnp.zeros((2, 64, 48)))
     assert n == 1 + 10 + 2, n

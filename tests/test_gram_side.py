@@ -8,8 +8,10 @@ power-product kernel (interpret mode) against its oracle, exact symmetry
 of its mirrored output, the Gram-side order against the Horner oracle
 on both paths, pad invariance of padded Gram sides, the trace-time
 order counter, and the launch contract (1 + d launches per unfused warm
-iteration; 19 for gpt2-paper's Muon buckets).
+iteration; 19 for gpt2-paper's Muon buckets, 64 for Moonlight-16B-A3B's).
 """
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -102,21 +104,21 @@ def _iterate(key, layout, dtype):
     return A.astype(dtype)
 
 
-@pytest.mark.parametrize("path", ["jnp", "interpret"])
+@pytest.mark.parametrize("mode", ["ref", "interpret"])
 @pytest.mark.parametrize("layout", ["tall", "square", "transposed"])
 @pytest.mark.parametrize("d", [2, 3])
 @pytest.mark.parametrize("dtype", DTYPES)
-def test_gram_side_matches_horner(monkeypatch, key, path, layout, d, dtype):
+def test_gram_side_matches_horner(monkeypatch, key, mode, layout, d, dtype):
     """X Q + f_0 X == the Horner oracle X g_d(R; alpha), per-slice fp32
     alpha: to fp32 rounding at fp32, to O(u_bf16) at bf16 (polar's
-    kappa ~ 1 on a normalized iterate)."""
-    if path == "interpret":
-        monkeypatch.setenv("REPRO_KERNEL_MODE", "interpret")
+    kappa ~ 1 on a normalized iterate), with the products run by the
+    oracles or by the kernel bodies."""
+    monkeypatch.setenv("REPRO_KERNEL_MODE", mode)
     X = _iterate(key, layout, dtype)
-    R = ops.gram(X) if path == "interpret" else ns._gram_residual(X, False)
+    R = ops.gram(X)
     alpha = jnp.asarray([0.87654321, 1.2345678], jnp.float32)
     before = ns.path_counts.copy()
-    got = ns.apply_g(X, R, alpha, d, "right", path == "interpret")
+    got = ns.apply_g(X, R, alpha, d, "right")
     assert ns.path_counts - before == {"gram_side": 1}
     want = ref.apply_g(X, R, alpha, coeffs=_coeffs(d))
     assert got.dtype == dtype
@@ -127,17 +129,17 @@ def test_gram_side_matches_horner(monkeypatch, key, path, layout, d, dtype):
         assert _fro_rel(got, want) < 2 * U_BF16
 
 
-@pytest.mark.parametrize("use_kernels", [False, True])
+@pytest.mark.parametrize("mode", ["ref", "interpret"])
 @pytest.mark.parametrize("wide", [False, True])
-def test_polar_padded_gram_side_unchanged(monkeypatch, key, use_kernels,
-                                          wide):
+def test_polar_padded_gram_side_unchanged(monkeypatch, key, mode, wide):
     """Zero-padding the Gram side (n_real) leaves the real block's polar
     factor as the unpadded call computes it: the pad block of Q is a
-    multiple of I and the pad columns of X stay zero."""
-    if use_kernels:
-        monkeypatch.setenv("REPRO_KERNEL_MODE", "interpret")
+    multiple of I and the pad columns of X stay zero.  A zero VMEM
+    budget pins the unfused tier, where the Gram side runs."""
+    monkeypatch.setenv("REPRO_KERNEL_MODE", mode)
+    monkeypatch.setattr(ops, "VMEM_BUDGET", 0)
     cfg = PrismConfig(degree=2, iterations=4, warm_alpha_iters=1,
-                      sketch_dim=0, use_kernels=use_kernels, fuse="off")
+                      sketch_dim=0)
     A = jax.random.normal(key, (2, 24, 40) if wide else (2, 40, 24))
     pad = ((0, 0), (0, 8), (0, 0)) if wide else ((0, 0), (0, 0), (0, 8))
     n_real = jnp.full((2,), 24, jnp.int32)
@@ -159,9 +161,11 @@ def test_polar_padded_gram_side_unchanged(monkeypatch, key, use_kernels,
     ("signm", 2, {"horner": 3}),
     ("sqrtm", 2, {"horner": 6}),   # X and Y each iteration
 ])
-def test_polar_apply_counter(key, fn, degree, want):
+def test_polar_apply_counter(monkeypatch, key, fn, degree, want):
     """Which order each unfused update took, counted at trace time
-    (classical Taylor alpha: PRISM's warm bounds stop at d = 2)."""
+    (classical Taylor alpha: PRISM's warm bounds stop at d = 2); a zero
+    VMEM budget pins the unfused tier at this small shape."""
+    monkeypatch.setattr(ops, "VMEM_BUDGET", 0)
     cfg = PrismConfig(degree=degree, iterations=3)
     A = _sym(key, 2, 32) + 2 * jnp.eye(32)
     before = ns.path_counts.copy()
@@ -173,8 +177,7 @@ def test_polar_apply_counter(key, fn, degree, want):
 def test_fused_tier_is_not_counted(key):
     """The fused tier keeps its VMEM-resident Horner and bypasses
     apply_g altogether."""
-    cfg = PrismConfig(degree=2, iterations=3, warm_alpha_iters=3,
-                      use_kernels=True)
+    cfg = PrismConfig(degree=2, iterations=3, warm_alpha_iters=3)
     assert ops.fused_fits((64, 16), jnp.dtype("float32"))
     before = ns.path_counts.copy()
     jax.eval_shape(lambda a: matfn.polar(a, cfg=cfg),
@@ -188,14 +191,20 @@ def test_fused_tier_is_not_counted(key):
 @pytest.mark.parametrize("degree", [2, 3])
 def test_unfused_warm_iteration_launches(monkeypatch, degree):
     """An unfused warm iteration stays 1 + d launches: the Gram kernel,
-    d - 1 power products and one apply."""
+    d - 1 power products and one apply (a zero VMEM budget pins the
+    unfused tier)."""
     monkeypatch.setenv("REPRO_KERNEL_MODE", "interpret")
+    monkeypatch.setattr(ops, "VMEM_BUDGET", 0)
     for iters in (1, 3):
-        cfg = PrismConfig(degree=degree, iterations=iters,
-                          use_kernels=True, fuse="off")
+        cfg = PrismConfig(degree=degree, iterations=iters)
         n = ops.count_launches(lambda a: matfn.polar(
             a, cfg=cfg, method="newton_schulz"), jnp.zeros((4, 64, 48)))
         assert n == iters * (1 + degree), (iters, n)
+
+
+# the benchmark cells' Muon chain: three warm iterations, float32
+_MUON = OptimizerConfig(name="muon", prism=PrismConfig(
+    degree=2, iterations=3, warm_alpha_iters=3, sketch_dim=8))
 
 
 def test_gpt2_paper_bucket_launches(monkeypatch, key):
@@ -207,12 +216,29 @@ def test_gpt2_paper_bucket_launches(monkeypatch, key):
     monkeypatch.setenv("REPRO_INTERPRET_MAX_ELEMS", "0")
     model = build(get_config("gpt2-paper"))
     shapes = matrix_view_shapes(model.param_shapes(), model.logical_axes())
-    ocfg = OptimizerConfig(name="muon", prism=PrismConfig(
-        degree=2, iterations=3, warm_alpha_iters=3, sketch_dim=8,
-        use_kernels=True))
     views = [jax.ShapeDtypeStruct(s, jnp.float32) for s in shapes]
     before = ns.path_counts.copy()
     n = ops.count_launches(
-        lambda *vs: bucketing.polar_bucketed(list(vs), ocfg, key), *views)
+        lambda *vs: bucketing.polar_bucketed(list(vs), _MUON, key), *views)
     assert n == 19
     assert dict(ns.path_counts - before) == {"gram_side": 6}
+
+
+def test_moonlight_bucket_launches(monkeypatch, key):
+    """Moonlight-16B-A3B's Muon buckets at the benchmark cell's cut (5
+    layers, a 20480-row vocabulary, 8 of 64 experts held): seven
+    unfused buckets in 3 x 3 launches each, plus one fused launch for
+    the routers' [4, 2048, 64] — 64."""
+    monkeypatch.setenv("REPRO_KERNEL_MODE", "interpret")
+    monkeypatch.setenv("REPRO_INTERPRET_MAX_ELEMS", "0")
+    cfg = get_config("moonlight-16b-a3b")
+    cfg = cfg.replace(num_layers=5, vocab_size=20480,
+                      moe=dataclasses.replace(cfg.moe, ep_size=8))
+    model = build(cfg)
+    shapes = matrix_view_shapes(model.param_shapes(), model.logical_axes())
+    views = [jax.ShapeDtypeStruct(s, jnp.float32) for s in shapes]
+    before = ns.path_counts.copy()
+    n = ops.count_launches(
+        lambda *vs: bucketing.polar_bucketed(list(vs), _MUON, key), *views)
+    assert n == 7 * 3 * 3 + 1
+    assert dict(ns.path_counts - before) == {"gram_side": 7 * 3}

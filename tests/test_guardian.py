@@ -157,7 +157,7 @@ def test_launch_contract_with_status(monkeypatch, key, dtype):
     for the status changes the traced launch count by ZERO (fused tier:
     warm tail 1 + fitted body 2, same as without the guard)."""
     monkeypatch.setenv("REPRO_KERNEL_MODE", "interpret")
-    cfg = _cfg(1e-2, dtype=dtype, iters=5, use_kernels=True, fuse="on")
+    cfg = _cfg(1e-2, dtype=dtype, iters=5)
     A = jnp.zeros((4, 64, 48), jnp.dtype(dtype))
     n_plain = _count(lambda a: matfn.polar(a, method="prism", cfg=cfg,
                                            key=key), A)
@@ -180,7 +180,7 @@ def test_skip_step_adds_zero_matfn_launches(monkeypatch, key):
         ocfg = OptimizerConfig(
             name="muon", precond_every=4, skip_nonfinite=skip,
             prism=PrismConfig(degree=2, iterations=2, warm_alpha_iters=1,
-                              sketch_dim=8, use_kernels=True))
+                              sketch_dim=8))
         opt = make_optimizer(ocfg, axes)
         state = opt.init(params)
         counts[skip] = _count(

@@ -294,18 +294,19 @@ def test_ops_dispatch_ref_on_cpu(key):
 
 
 def test_prism_with_interpret_kernels_end_to_end(key, monkeypatch):
-    """PRISM polar with use_kernels=True (interpret) == pure-jnp result."""
+    """PRISM polar with the kernel bodies (interpret) == with the ref.py
+    oracles."""
     monkeypatch.setenv("REPRO_KERNEL_MODE", "interpret")
     from repro.config import PrismConfig
     from repro.core import matfn
 
     A = jax.random.normal(key, (96, 48))
     Xk = matfn.polar(A, method="prism",
-                     cfg=PrismConfig(degree=2, sketch_dim=8, use_kernels=True),
+                     cfg=PrismConfig(degree=2, sketch_dim=8),
                      key=key, iters=6)
     monkeypatch.setenv("REPRO_KERNEL_MODE", "ref")
     Xr = matfn.polar(A, method="prism",
-                     cfg=PrismConfig(degree=2, sketch_dim=8, use_kernels=True),
+                     cfg=PrismConfig(degree=2, sketch_dim=8),
                      key=key, iters=6)
     np.testing.assert_allclose(np.asarray(Xk), np.asarray(Xr),
                                rtol=5e-3, atol=5e-3)

@@ -100,12 +100,15 @@ def test_planner_tier_selection():
     # over max_dim -> lowrank, l = rank + oversample
     assert bucketing.resolve_lowrank_tier(cfg, (768, 50257)) == 24
     assert bucketing.resolve_tier(cfg, (768, 50257)) == "lowrank"
-    # small square -> cubic tiers
+    # small square -> cubic tiers: fused, since the slice fits VMEM
     assert bucketing.resolve_lowrank_tier(cfg, (64, 64)) is None
-    assert bucketing.resolve_tier(cfg, (64, 64)) == "grid"
+    assert bucketing.resolve_tier(cfg, (64, 64)) == "fused"
     # aspect-ratio trigger below max_dim (256 == 4.0 * 64)
     assert bucketing.resolve_tier(cfg, (64, 256)) == "lowrank"
-    assert bucketing.resolve_tier(cfg, (64, 255)) == "grid"
+    assert bucketing.resolve_tier(cfg, (64, 255)) == "fused"
+    # a cubic slice too large for the fused tier takes the batch grid
+    assert bucketing.resolve_tier(_ocfg(lowrank_rank=0),
+                                  (2048, 2048)) == "grid"
 
 
 def test_planner_degrades_to_exact_tiers():
@@ -198,9 +201,9 @@ def test_muon_routes_embedding_through_lowrank():
     assert int(emb["tier"]) == bucketing.TIER_CODES["lowrank"]
     assert int(state["leaves"]["head"]["tier"]) == \
         bucketing.TIER_CODES["lowrank"]
-    # square-ish views stay on the cubic tiers
+    # square-ish views stay on the cubic tiers (fused at this small width)
     assert int(state["leaves"]["layers"]["mlp"]["w_up"]["tier"]) == \
-        bucketing.TIER_CODES["grid"]
+        bucketing.TIER_CODES["fused"]
 
     from repro.data import DataConfig, make_batch_fn
     batch_fn = make_batch_fn(cfg, DataConfig(vocab_size=cfg.vocab_size,
@@ -275,8 +278,7 @@ def test_steady_state_zero_launches_with_lowrank(monkeypatch):
     ocfg = _ocfg(lowrank_rank=8, lowrank_oversample=4,
                  lowrank_max_dim=128, precond_every=4, precond_async=True,
                  prism=PrismConfig(degree=2, iterations=2,
-                                   warm_alpha_iters=1, sketch_dim=8,
-                                   use_kernels=True))
+                                   warm_alpha_iters=1, sketch_dim=8))
     opt = make_optimizer(ocfg, model.logical_axes())
     step_fn = make_train_step(model, opt, ocfg)
     params = master_params(model.init(key))

@@ -27,6 +27,7 @@ from repro.core import matfn
 from repro.core import newton_schulz as ns
 from repro.core import random_matrices as rm
 from repro.core import sketch
+from repro.kernels import ops
 from repro.optim import bucketing, make_optimizer
 
 pytestmark = pytest.mark.tier1
@@ -173,9 +174,9 @@ def test_pad_trace_correction_exact_under_bf16(key):
     Rp = jnp.eye(padn, dtype=jnp.bfloat16).at[:n, :n].set(R)
     S = sketch.gaussian_sketch(jax.random.fold_in(key, 1), p, padn,
                                dtype=jnp.bfloat16)
-    t_pad = sketch.sketched_power_traces(Rp, S, maxp)
+    t_pad = ops.sketch_traces(Rp, S, maxp)
     c = jnp.sum(jnp.square(S[:, n:].astype(jnp.float32)))
-    t_real = sketch.sketched_power_traces(R, S[:, :n], maxp)
+    t_real = ops.sketch_traces(R, S[:, :n], maxp)
     # fp32-tight: the only difference is fp32 summation order
     np.testing.assert_allclose(np.asarray(t_pad) - float(c),
                                np.asarray(t_real), rtol=1e-4, atol=1e-4)
@@ -293,22 +294,21 @@ def test_launch_counts_dtype_independent(monkeypatch, key):
     """The launch-count contracts are precision-blind (bf16 changes tile
     CONTENTS, never dispatch structure): a fitted PRISM-NS iteration is 2
     launches on the fused tier (§10) and 2+d on the §7 batch-grid tier,
-    whether the operands are fp32 or bf16."""
+    whether the operands are fp32 or bf16.  The §7 tier is pinned by a
+    VMEM budget that holds the sketch chain but no fused iteration."""
     monkeypatch.setenv("REPRO_KERNEL_MODE", "interpret")
-    from repro.kernels import ops
-
-    for fuse, want in (("auto", 2), ("off", 4)):
+    for budget, want in ((ops.VMEM_BUDGET, 2), (200_000, 4)):
+        monkeypatch.setattr(ops, "VMEM_BUDGET", budget)
         counts = {}
         for dt in ("float32", "bfloat16"):
             cfg = PrismConfig(degree=2, iterations=1, warm_alpha_iters=0,
-                              sketch_dim=8, use_kernels=True, dtype=dt,
-                              fuse=fuse)
+                              sketch_dim=8, dtype=dt)
             A = jnp.zeros((4, 64, 48), jnp.dtype(dt))
             counts[dt] = ops.count_launches(
                 lambda A: matfn.polar(A, method="prism", cfg=cfg, key=key),
                 A)
         assert counts["float32"] == counts["bfloat16"] == want, \
-            (fuse, counts)
+            (budget, counts)
 
 
 def test_precision_policy_validation():

@@ -12,6 +12,7 @@ import pytest
 from repro.config import OptimizerConfig, PrismConfig, TrainConfig
 from repro.configs import get_smoke_config
 from repro.data import DataConfig
+from repro.kernels import ops
 from repro.models import build
 from repro.optim import bucketing
 from repro.optim.muon import matrix_view_shapes
@@ -140,12 +141,14 @@ def test_attention_path_is_counted_when_the_step_traces(tmp_path):
     assert not any("/attn_kernel/" in n for n in dots)
 
 
-def test_polar_apply_is_counted_when_the_step_traces(tmp_path):
+def test_polar_apply_is_counted_when_the_step_traces(tmp_path,
+                                                    monkeypatch):
+    # a zero VMEM budget runs every Muon bucket unfused: three warm
+    # updates a bucket, each on the Gram side (degree 2)
+    monkeypatch.setattr(ops, "VMEM_BUDGET", 0)
     tr = _trainer(tmp_path, steps=2)
     assert "polar_apply" not in tr.counters
     tr.run()
-    # the jnp chain runs every Muon bucket unfused: three warm updates
-    # a bucket, each on the Gram side (degree 2)
     shapes = matrix_view_shapes(tr.model.param_shapes(),
                                 tr.model.logical_axes())
     buckets = bucketing.plan_buckets(shapes, pad=OCFG.bucket_pad,
